@@ -217,8 +217,8 @@ class TPL002RecompileHazard:
     sync dressed as formatting). At call sites of compiled callables:
     time/random-derived scalars passed as arguments — every distinct
     value is a new signature, i.e. a recompile per step (the 138 s
-    compile in BENCH_r05 makes that a production outage, not a
-    slowdown)."""
+    compile of gpt13 in BENCH_NOTES_r05.json makes that a production
+    outage, not a slowdown)."""
 
     id = "TPL002"
 

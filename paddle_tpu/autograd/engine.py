@@ -214,7 +214,7 @@ def apply_op(fn: Callable, tensors: Sequence[Tensor], attrs: dict = None,
             _, inner_vjp = jax.vjp(_f, *vals)
             return inner_vjp(cotangents)
     elif not any(isinstance(a, jax.core.Tracer) for a in arrays):
-        # Deferred linearization (measured in BENCH_NOTES.md r3): eager-time
+        # Deferred linearization (measured in round 3, BENCH_NOTES_r03.json): eager-time
         # jax.vjp costs ~1.4ms/op vs ~36µs for the plain forward, so concrete
         # dispatches run the forward alone and linearize lazily at backward —
         # ops never reached by backward (eval forwards, pruned branches) pay

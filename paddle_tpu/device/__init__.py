@@ -62,7 +62,7 @@ def get_device() -> str:
 def synchronize(device=None):
     """Block until all dispatched work completes (reference:
     paddle.device.synchronize / cudaDeviceSynchronize)."""
-    (jax.effects_barrier if hasattr(jax, "effects_barrier") else lambda: None)()
+    jax.effects_barrier()
 
 
 def is_compiled_with_cuda() -> bool:

@@ -34,6 +34,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from .. import tensor as tensor_mod
 from ..generator import Generator, default_generator
@@ -567,11 +568,11 @@ class StaticFunction:
         self.__doc__ = getattr(function, "__doc__", None)
 
     # -- introspection -------------------------------------------------------
-    def cost_analysis(self, key=None) -> Optional[dict]:
-        """XLA cost analysis (flops / bytes accessed / ...) of a compiled
-        signature — the TPU answer to the reference auto_parallel cost model
-        (engine.py:1751, auto_parallel/cost/). ``key=None`` picks the most
-        recent signature. Returns None before any call compiled."""
+    def _lowered(self, key=None):
+        """``jax.stages.Lowered`` of a compiled signature from its recorded
+        abstract arguments (``key=None``: the most recent); None before
+        any call compiled. Lowering may re-trace the function, which
+        leaves tracers in the state slots — they are put back."""
         if not self._cache:
             return None
         if key is None:
@@ -581,12 +582,39 @@ class StaticFunction:
         abstract = self._abstract_args.get(key)
         if compiled is None or abstract is None:
             return None
-        state_s, lr_s, arr_s = abstract
-        lowered = compiled.jitted.lower(state_s, lr_s, arr_s)
+        saved = [slot.get() for slot in self._slots]
+        try:
+            return compiled.jitted.lower(*abstract)
+        finally:
+            for slot, v in zip(self._slots, saved):
+                slot.set(v)
+
+    def cost_analysis(self, key=None) -> Optional[dict]:
+        """XLA cost analysis (flops / bytes accessed / ...) of a compiled
+        signature — the TPU answer to the reference auto_parallel cost model
+        (engine.py:1751, auto_parallel/cost/). ``key=None`` picks the most
+        recent signature. Returns None before any call compiled."""
+        lowered = self._lowered(key)
+        if lowered is None:
+            return None
         cost = lowered.compile().cost_analysis()
         if isinstance(cost, (list, tuple)):
             cost = cost[0] if cost else {}
         return dict(cost) if cost else {}
+
+    def program_text(self, key=None, compiled: bool = False) -> Optional[str]:
+        """Text of a compiled signature (``key=None``: the most recent;
+        keys are those of the signature cache): the StableHLO it lowers
+        to, or with ``compiled=True`` the HLO after XLA's passes — SPMD
+        partitioning included, so collectives show only there; that costs
+        a compile unless JAX's persistent cache holds it. A Pallas TPU
+        kernel shows in both as ``tpu_custom_call`` — chip_smoke.py
+        asserts on that instead of trusting the dispatch. None before any
+        call compiled."""
+        lowered = self._lowered(key)
+        if lowered is None:
+            return None
+        return lowered.compile().as_text() if compiled else lowered.as_text()
 
     def lower(self, *args, **kwargs):
         """AOT trace + lower WITHOUT executing (reference counterpart: the
@@ -805,8 +833,13 @@ class StaticFunction:
                                    (state_vals, lr_vals, list(arrays)))
             self._cache[key] = compiled
         self._abstract_args.pop(key, None)  # move-to-end: dict order = recency
+        # mesh shardings are part of the program (a re-lowering without
+        # them is another program); single-device placement is not
         self._abstract_args[key] = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=(a.sharding if isinstance(
+                    getattr(a, "sharding", None), NamedSharding) else None)),
             (state_vals, lr_vals, list(arrays)))
         if compiled.aot is not None:
             try:

@@ -248,7 +248,7 @@ class GPTAttention(nn.Layer):
         def paged_step(qkv_v, kp, vp, bt, pos, *scales):
             pos = pos.astype(jnp.int32).reshape(B)
             bt = bt.astype(jnp.int32)
-            page_size = kp.shape[1]
+            page_size = kp.shape[2]
             qv, kv, vv = jnp.split(qkv_v, 3, axis=-1)
             nh_l = qv.shape[-1] // hd
             qh = qv.reshape(B, nh_l, hd)
@@ -260,16 +260,16 @@ class GPTAttention(nn.Layer):
                 ks, vs = scales
                 kq, ksc = quantize_kv(kh)
                 vq, vsc = quantize_kv(vh)
-                kp = kp.at[page_ids, offs].set(kq)
-                vp = vp.at[page_ids, offs].set(vq)
-                ks = ks.at[page_ids, offs].set(ksc)
-                vs = vs.at[page_ids, offs].set(vsc)
+                kp = kp.at[page_ids, :, offs].set(kq)
+                vp = vp.at[page_ids, :, offs].set(vq)
+                ks = ks.at[page_ids, :, offs].set(ksc)
+                vs = vs.at[page_ids, :, offs].set(vsc)
                 ctx = ragged_paged_attention(qh, kp, vp, bt, pos + 1,
                                              scale=scale, k_scale=ks,
                                              v_scale=vs)
                 return ctx.reshape(B, 1, nh_l * hd), kp, vp, ks, vs
-            kp = kp.at[page_ids, offs].set(kh.astype(kp.dtype))
-            vp = vp.at[page_ids, offs].set(vh.astype(vp.dtype))
+            kp = kp.at[page_ids, :, offs].set(kh.astype(kp.dtype))
+            vp = vp.at[page_ids, :, offs].set(vh.astype(vp.dtype))
             ctx = ragged_paged_attention(qh, kp, vp, bt, pos + 1,
                                          scale=scale)
             return ctx.reshape(B, 1, nh_l * hd), kp, vp

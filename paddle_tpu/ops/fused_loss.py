@@ -5,7 +5,7 @@ Reference parity: the fused softmax-CE family
 fused_softmax_mask ops) — but the TPU pain point is upstream of the softmax:
 the LM head materializes logits [B·S, V] (V≈50K ⇒ 0.8GB bf16 forward and a
 multi-GB fp32 softmax/grad footprint in backward), which is what capped the
-round-2 bench at B=8–16 per chip (BENCH_NOTES.md: B≥24 OOMs).
+round-2 bench at B=8–16 per chip (round-2 notes: B≥24 OOMs).
 
 TPU-native redesign: never materialize [N, V]. The vocab dim is scanned in
 chunks with an online logsumexp (the flash-attention trick applied to the

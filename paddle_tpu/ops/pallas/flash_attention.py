@@ -35,13 +35,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 # Measured blocks (v5e, r4 sweeps): every config beats XLA, but the two
 # r4 sweeps disagree on the best S=1024 blocks — quick sweep: (1024,1024)
@@ -65,17 +59,10 @@ def _compiler_params():
     parallel (no cross-iteration carries), the innermost axis is 'arbitrary'
     (the online-softmax / accumulator carry rides it). Without this Mosaic
     assumes every grid dim may carry state and serializes the whole grid."""
-    if _interpret() or not _HAS_PLTPU:
+    if _interpret():
         return {}
-    sem = ("parallel", "parallel", "arbitrary")
-    cp = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if cp is not None:
-        try:
-            return {"compiler_params": cp(dimension_semantics=sem)}
-        except TypeError:  # pragma: no cover - older ctor signature
-            pass
-    return {"compiler_params": dict(mosaic=dict(dimension_semantics=sem))}
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))}
 
 
 def _i32(x):
@@ -211,7 +198,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, seed_ref, kp_ref, o_ref, lse_ref,
 def _scalar_spec():
     """(1,1) scalar block: SMEM on the real TPU backend, plain VMEM-ish
     block under interpret (SMEM has no interpret support)."""
-    if _HAS_PLTPU and not _interpret():
+    if not _interpret():
         return pl.BlockSpec((1, 1), lambda *_: (_i32(0), _i32(0)),
                             memory_space=pltpu.SMEM)
     return pl.BlockSpec((1, 1), lambda *_: (_i32(0), _i32(0)))
@@ -645,14 +632,6 @@ def flash_attention_bshd(q, k, v, causal: bool = False, scale: float = None,
     a fresh per-step seed costs no retrace."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    if not _HAS_PLTPU:
-        if dropout_p > 0.0 or key_padding_mask is not None:
-            raise NotImplementedError(
-                "flash_attention_bshd dropout/key-padding requires the "
-                "pallas TPU backend (this build lacks "
-                "jax.experimental.pallas.tpu); silently ignoring them "
-                "would be worse")
-        return _ref_attention_bshd(q, k, v, causal, scale)
     seed_f = jnp.asarray(dropout_seed, jnp.float32)
     if key_padding_mask is not None:
         # [B, Sk] bool/0-1 keep mask — the kernels index row b // H, no

@@ -36,13 +36,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_adamw_flat"]
 
@@ -117,7 +111,7 @@ def fused_adamw_flat(w, m, v, g, lr, step, *, block_rows=None,
     blk = pl.BlockSpec((br, LANE), lambda i: (i, _z()))
     scal = pl.BlockSpec((1, 1), lambda i: (_z(), _z()),
                         memory_space=pltpu.SMEM) \
-        if (_HAS_PLTPU and not _interpret()) \
+        if not _interpret() \
         else pl.BlockSpec((1, 1), lambda i: (_z(), _z()))
     wo, mo, vo = pl.pallas_call(
         functools.partial(_adamw_kernel, beta1=beta1, beta2=beta2, eps=eps,

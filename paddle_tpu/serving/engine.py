@@ -977,6 +977,16 @@ class ServingEngine:
         n = len(self._step_prog._cache) if self._step_prog else 0
         return {"step": n, "step_buckets": len(self._grid_buckets_seen)}
 
+    def step_program_texts(self) -> List[str]:
+        """Lowered (StableHLO) text of every compiled step bucket — read
+        by chip_smoke.py to assert that the Pallas ragged kernel
+        (``tpu_custom_call``), not the gather fallback, is what the step
+        program holds."""
+        if self._step_prog is None:
+            return []
+        return [self._step_prog.program_text(k)
+                for k in self._step_prog._cache]
+
     # ---------------------------------------------------------------- step
     def step(self) -> List[RequestOutput]:
         """One engine iteration: admit → one unified ragged step (decode

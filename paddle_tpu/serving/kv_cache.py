@@ -3,7 +3,7 @@
 The serving engine's memory substrate (PAPERS.md: Ragged Paged Attention,
 arxiv 2604.15464 — vLLM-style paging on TPU): instead of one dense
 ``[B, max_len, nkv, hd]`` cache per request, every layer owns a fixed pool
-of ``[num_pages, page_size, n_kv_heads, head_dim]`` K and V blocks, and a
+of ``[num_pages, n_kv_heads, page_size, head_dim]`` K and V blocks, and a
 sequence is a *list of page ids* (its block table). Admission, retirement,
 and fork never move KV bytes — only page ids change hands — so the decode
 step's shapes stay fixed while the live batch churns.
@@ -32,16 +32,18 @@ worst case via ``reserve`` — the scheduler admits a request only if the
 pool can cover every live sequence's ``prompt + max_new_tokens`` tail, so
 a mid-decode out-of-pages abort is impossible without preemption.
 
-Sharding note (GSPMD, arxiv 2105.04663): the pool keeps the kv-head axis
-third, matching the dense cache layout the mp mesh shards today — a later
-multi-chip serving PR can shard ``n_kv_heads`` over 'mp' without touching
-the allocator or block tables (page ids are replicated host metadata).
+Layout note: the last two axes are ``(page_size, head_dim)`` because the
+TPU lowering of the paged kernel (ops/pallas/paged_attention.py) DMAs one
+head's page per grid step and needs that block's last two dims to be the
+array's own. The kv-head axis is second, so a later multi-chip serving PR
+can still shard ``n_kv_heads`` over 'mp' without touching the allocator
+or block tables (page ids are replicated host metadata).
 
 Page TIERS (docs/SERVING.md "KV page tiers & quantization"):
 
 - **int8 pages** — ``PagedKVCachePool(dtype="int8")`` stores pages as
   int8 with per-slot f32 absmax scales (``k_scales``/``v_scales``,
-  ``[num_pages, page_size, n_kv_heads]``; quantization/observers.py owns
+  ``[num_pages, n_kv_heads, page_size]``; quantization/observers.py owns
   the scale rule). Writes quantize inside the compiled step; reads
   dequantize in-kernel (ops/pallas/paged_attention.py) — a full-width
   page never exists in HBM. Every allocator semantic treats a scale row
@@ -170,7 +172,7 @@ class PagedKVCachePool:
     """Fixed K/V page pool per layer + block-table allocator.
 
     Device state: ``k_pools``/``v_pools`` — one framework Tensor per layer,
-    shape ``[num_pages, page_size, n_kv_heads, head_dim]``. The compiled
+    shape ``[num_pages, n_kv_heads, page_size, head_dim]``. The compiled
     decode step consumes and returns them functionally; the engine swaps
     the fresh arrays back in via :meth:`set_arrays`.
 
@@ -200,7 +202,7 @@ class PagedKVCachePool:
         # "Page TIERS"); every page-granular allocator path below mirrors
         # its byte operation onto the scale arrays
         self.quantized = jnp.dtype(self.dtype) == jnp.int8
-        shape = (self.num_pages, self.page_size, self.n_kv_heads,
+        shape = (self.num_pages, self.n_kv_heads, self.page_size,
                  self.head_dim)
         self.k_pools: List[Tensor] = [
             Tensor(jnp.zeros(shape, self.dtype), stop_gradient=True)
@@ -209,7 +211,7 @@ class PagedKVCachePool:
             Tensor(jnp.zeros(shape, self.dtype), stop_gradient=True)
             for _ in range(self.num_layers)]
         if self.quantized:
-            sshape = shape[:3]  # [num_pages, page_size, n_kv_heads]
+            sshape = shape[:3]  # [num_pages, n_kv_heads, page_size]
             self.k_scales: Optional[List[Tensor]] = [
                 Tensor(jnp.zeros(sshape, jnp.float32), stop_gradient=True)
                 for _ in range(self.num_layers)]
@@ -822,9 +824,9 @@ class PagedKVCachePool:
         n = 0
         for li in range(self.num_layers):
             n += int(jnp.sum(
-                self.k_scales[li]._value[pages, oo] <= floor))
+                self.k_scales[li]._value[pages, :, oo] <= floor))
             n += int(jnp.sum(
-                self.v_scales[li]._value[pages, oo] <= floor))
+                self.v_scales[li]._value[pages, :, oo] <= floor))
         if n:
             self._m_scale_clips.inc(n)
         return n
@@ -876,20 +878,20 @@ class PagedKVCachePool:
                 ks = self.k_scales[li]._value
                 vs = self.v_scales[li]._value
                 self.k_scales[li] = Tensor(
-                    ks.at[page_ids, offs].set(
+                    ks.at[page_ids, :, offs].set(
                         jnp.asarray(value, ks.dtype)), stop_gradient=True)
                 self.v_scales[li] = Tensor(
-                    vs.at[page_ids, offs].set(
+                    vs.at[page_ids, :, offs].set(
                         jnp.asarray(value, vs.dtype)), stop_gradient=True)
             return int(idx.size)
         for li in range(self.num_layers):
             kp = self.k_pools[li]._value
             vp = self.v_pools[li]._value
             self.k_pools[li] = Tensor(
-                kp.at[page_ids, offs].set(jnp.asarray(value, kp.dtype)),
+                kp.at[page_ids, :, offs].set(jnp.asarray(value, kp.dtype)),
                 stop_gradient=True)
             self.v_pools[li] = Tensor(
-                vp.at[page_ids, offs].set(jnp.asarray(value, vp.dtype)),
+                vp.at[page_ids, :, offs].set(jnp.asarray(value, vp.dtype)),
                 stop_gradient=True)
         return int(idx.size)
 
@@ -994,21 +996,21 @@ class PagedKVCachePool:
                 kq, ksc = quantize_kv(jnp.asarray(k))
                 vq, vsc = quantize_kv(jnp.asarray(v))
                 self.k_pools[li] = Tensor(
-                    kp.at[page_ids, offs].set(kq), stop_gradient=True)
+                    kp.at[page_ids, :, offs].set(kq), stop_gradient=True)
                 self.v_pools[li] = Tensor(
-                    vp.at[page_ids, offs].set(vq), stop_gradient=True)
+                    vp.at[page_ids, :, offs].set(vq), stop_gradient=True)
                 ks = self.k_scales[li]._value
                 vs = self.v_scales[li]._value
                 self.k_scales[li] = Tensor(
-                    ks.at[page_ids, offs].set(ksc), stop_gradient=True)
+                    ks.at[page_ids, :, offs].set(ksc), stop_gradient=True)
                 self.v_scales[li] = Tensor(
-                    vs.at[page_ids, offs].set(vsc), stop_gradient=True)
+                    vs.at[page_ids, :, offs].set(vsc), stop_gradient=True)
                 continue
             self.k_pools[li] = Tensor(
-                kp.at[page_ids, offs].set(
+                kp.at[page_ids, :, offs].set(
                     jnp.asarray(k).astype(kp.dtype)), stop_gradient=True)
             self.v_pools[li] = Tensor(
-                vp.at[page_ids, offs].set(
+                vp.at[page_ids, :, offs].set(
                     jnp.asarray(v).astype(vp.dtype)), stop_gradient=True)
         if self.quantized:
             self.record_scale_clips(np.asarray(page_ids),
@@ -1028,13 +1030,13 @@ class PagedKVCachePool:
         offs = jnp.asarray(idx % self.page_size)
         out = []
         for li in range(self.num_layers):
-            k = self.k_pools[li]._value[pages, offs]
-            v = self.v_pools[li]._value[pages, offs]
+            k = self.k_pools[li]._value[pages, :, offs]
+            v = self.v_pools[li]._value[pages, :, offs]
             if self.quantized:
                 k = (k.astype(jnp.float32)
-                     * self.k_scales[li]._value[pages, offs][..., None])
+                     * self.k_scales[li]._value[pages, :, offs][..., None])
                 v = (v.astype(jnp.float32)
-                     * self.v_scales[li]._value[pages, offs][..., None])
+                     * self.v_scales[li]._value[pages, :, offs][..., None])
             out.append((k, v))
         return out
 

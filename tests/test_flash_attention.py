@@ -351,3 +351,35 @@ def test_fully_masked_rows_emit_zero():
             ga = np.asarray(g)
             assert np.all(np.isfinite(ga))
             np.testing.assert_allclose(ga[1], 0.0, atol=1e-6)
+
+
+def test_kernel_runs_per_shard_under_a_mesh():
+    """Mosaic kernels cannot be partitioned by GSPMD, so under a mesh
+    nn.functional.attention runs the kernel inside a shard_map (batch over
+    'dp', heads over 'mp'). Forward and gradients through that wrapper
+    match the unsharded kernel, and the output stays sharded."""
+    from paddle_tpu.distributed import topology
+    from paddle_tpu.nn.functional import attention as A
+
+    mesh = topology.create_mesh({"dp": 4, "mp": 2})
+    b, s, h, d = 4, 128, 2, 64
+    q, k, v = (_rand((b, s, h, d), i) for i in (20, 21, 22))
+
+    def attn(q, k, v):
+        return fa.flash_attention_bshd(q, k, v, causal=True)
+
+    sharded = A._per_shard(attn, mesh, q.shape, has_seed=False,
+                           has_kpad=False)
+    out = jax.jit(sharded)(q, k, v)
+    assert tuple(out.sharding.spec)[:3] == ("dp", None, "mp")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(attn(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) ** 2)
+
+    g = jax.jit(jax.grad(loss(sharded), argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.grad(loss(attn), argnums=(0, 1, 2))(q, k, v)
+    for a, r in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4)

@@ -164,3 +164,30 @@ class TestSaveLoad:
             out = loaded(paddle.to_tensor(x[:n])).numpy()
             ref = model(paddle.to_tensor(x[:n])).numpy()
             np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+def test_program_text_and_cost_analysis_leave_state_usable():
+    """Both introspection paths re-lower a compiled signature from its
+    recorded abstract arguments; a re-trace must not leave tracers in the
+    state slots the next real call reads."""
+    model, x, y = _make_model_and_data()
+    opt = paddle.optimizer.SGD(learning_rate=0.1,
+                               parameters=model.parameters())
+
+    def train_fn(xb, yb):
+        loss = F.cross_entropy(model(xb), yb)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    step = jit.StaticFunction(train_fn, observe=[model, opt], warmup=False)
+    assert step.program_text() is None and step.cost_analysis() is None
+    xb, yb = paddle.to_tensor(x), paddle.to_tensor(y)
+    first = float(step(xb, yb).numpy())
+    text = step.program_text()
+    assert "func.func" in text and "tpu_custom_call" not in text
+    assert "HloModule" in step.program_text(compiled=True)
+    assert step.cost_analysis()["flops"] > 0
+    assert float(step(xb, yb).numpy()) < first  # still trains
+    assert len(step._cache) == 1

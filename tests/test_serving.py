@@ -54,28 +54,65 @@ _PROMPTS = [np.random.RandomState(7).randint(0, 128, (n,))
 
 
 class TestPagedAttentionKernel:
-    def test_kernel_matches_fallback(self, monkeypatch):
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["pages-as-q", "int8"])
+    @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                           ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("nh,nkv", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+    def test_kernel_matches_fallback(self, monkeypatch, nh, nkv, dtype,
+                                     tol, quantized):
         """The real pallas kernel (scalar-prefetched block tables, online
         softmax over the ragged page list) against the jnp gather
-        fallback, on CPU via interpret mode."""
+        fallback, on CPU via interpret mode — GQA and MHA (a one-row
+        query group), f32 and bf16 queries, plain and int8 pages with
+        the in-kernel dequant."""
         monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
         import jax.numpy as jnp
 
         from paddle_tpu.ops.pallas import paged_attention as pa
+        from paddle_tpu.quantization.observers import quantize_kv
 
         rng = np.random.default_rng(0)
-        B, nh, nkv, hd, page, pages, width = 3, 4, 2, 64, 8, 12, 4
-        q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((pages, page, nkv, hd)),
-                         jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((pages, page, nkv, hd)),
-                         jnp.float32)
+        B, hd, page, pages, width = 3, 64, 8, 12, 4
+        q = jnp.asarray(rng.standard_normal((B, nh, hd)), dtype)
+        kp = jnp.asarray(rng.standard_normal((pages, nkv, page, hd)), dtype)
+        vp = jnp.asarray(rng.standard_normal((pages, nkv, page, hd)), dtype)
         bt = jnp.asarray(rng.integers(1, pages, (B, width)), jnp.int32)
         sl = jnp.asarray([1, 17, 32], jnp.int32)  # ragged, incl. 1 token
-        ref = pa.ref_paged_attention(q, kp, vp, bt, sl)
-        out = pa.paged_attention(q, kp, vp, bt, sl, use_kernel=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
+        kw = {}
+        if quantized:
+            kp, ks = quantize_kv(kp)
+            vp, vs = quantize_kv(vp)
+            kw = dict(k_scale=ks, v_scale=vs)
+        ref = pa.ref_paged_attention(q, kp, vp, bt, sl, **kw)
+        out = pa.paged_attention(q, kp, vp, bt, sl, use_kernel=True, **kw)
+        assert out.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=tol, rtol=tol)
+
+    def test_block_table_over_smem_is_an_error_with_numbers(self):
+        """The scalar-prefetched table must fit the chip's SMEM whole; a
+        grid that cannot is refused at trace time with the sizes named,
+        not left to crash the TPU compiler."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        T, pages = 4096, 128
+        q = jax.ShapeDtypeStruct((T, 4, 64), jnp.bfloat16)
+        pool = jax.ShapeDtypeStruct((16, 4, 16, 64), jnp.bfloat16)
+        bt = jax.ShapeDtypeStruct((T, pages), jnp.int32)
+        sl = jax.ShapeDtypeStruct((T,), jnp.int32)
+        with pytest.raises(ValueError) as e:
+            jax.eval_shape(
+                lambda *a: pa.ragged_paged_attention(*a, use_kernel=True),
+                q, pool, pool, bt, sl)
+        msg = str(e.value)
+        assert f"{T} rows, {pages} pages" in msg
+        assert str(4 * T * (pages + 1)) in msg
+        assert str(pa.SMEM_PREFETCH_LIMIT_BYTES) in msg
 
     def test_ragged_flattened_rows_match_fallback(self, monkeypatch):
         """The unified-step contract (ISSUE 11): mixed per-slot query
@@ -104,9 +141,9 @@ class TestPagedAttentionKernel:
             np.arange(starts[i], starts[i] + q_lens[i]) + 1
             for i in range(3)]).astype(np.int32)
         q = jnp.asarray(rng.standard_normal((T, nh, hd)), jnp.float32)
-        kp = jnp.asarray(rng.standard_normal((pages, page, nkv, hd)),
+        kp = jnp.asarray(rng.standard_normal((pages, nkv, page, hd)),
                          jnp.float32)
-        vp = jnp.asarray(rng.standard_normal((pages, page, nkv, hd)),
+        vp = jnp.asarray(rng.standard_normal((pages, nkv, page, hd)),
                          jnp.float32)
         ref = pa.ref_paged_attention(q, kp, vp, jnp.asarray(row_bt),
                                      jnp.asarray(row_lens))

@@ -41,9 +41,9 @@ V5E_HBM_GB = 16.0
 GB = 1024 ** 3
 
 
-def _reexec_scrubbed() -> None:
-    from _budget_common import reexec_scrubbed
-    reexec_scrubbed("_GPT13_BUDGET_CHILD")
+def _reexec_cpu() -> None:
+    from _budget_common import reexec_cpu
+    reexec_cpu("_GPT13_BUDGET_CHILD")
 
 
 def _zero_init_parameters() -> None:
@@ -151,7 +151,7 @@ def main() -> int:
     ap.add_argument("--combo", help="run ONE combo by tag (child mode)")
     ap.add_argument("--no-write", action="store_true")
     args = ap.parse_args()
-    _reexec_scrubbed()
+    _reexec_cpu()
 
     if args.combo:  # child: measure one combo, print one JSON line
         combo = next(c for c in COMBOS if c["tag"] == args.combo)

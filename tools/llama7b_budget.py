@@ -16,7 +16,7 @@ Reference counterpart: the reference proves 7B feasibility by running it
 for the memory question because XLA's buffer assignment IS the runtime
 allocator (no dynamic allocation at step time).
 
-Usage (env is scrubbed + re-exec'd automatically):
+Usage (re-execs itself pinned to the CPU):
     python tools/llama7b_budget.py              # full 7B, ~8 virtual chips
     python tools/llama7b_budget.py --smoke      # tiny shapes, CI-speed
 Writes LLAMA7B_BUDGET.md + prints one JSON line; exits nonzero if the
@@ -36,9 +36,9 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 V5E_HBM_GB = 16.0
 
 
-def _reexec_scrubbed(n_devices: int) -> None:
-    from _budget_common import reexec_scrubbed
-    reexec_scrubbed("_LLAMA7B_BUDGET_CHILD", n_devices)
+def _reexec_cpu(n_devices: int) -> None:
+    from _budget_common import reexec_cpu
+    reexec_cpu("_LLAMA7B_BUDGET_CHILD", n_devices)
 
 
 def _zero_init_parameters() -> None:
@@ -77,7 +77,7 @@ def main() -> int:
     ap.add_argument("--no-write", action="store_true",
                     help="don't write LLAMA7B_BUDGET.md (smoke/CI)")
     args = ap.parse_args()
-    _reexec_scrubbed(args.devices)
+    _reexec_cpu(args.devices)
 
     import numpy as np
 
