@@ -30,13 +30,15 @@ Code grammar:
   ``faults.declare_point`` / ``faults.inject`` (or those names imported
   bare). Non-literal names (``faults.point(point_name)``) are skipped —
   the literal appears at the caller that chose the name.
-- A trace emit site (TPL010) is ``<tracer>.emit("name", ...)`` with a
+- A trace emit site (TPL010) is ``<tracer>.emit("name", ...)`` — or a
+  span site, ``.begin("name", ...)`` / ``.next("name", ...)`` — with a
   literal name where ``<tracer>`` looks like a tracer (``trace`` /
   ``_trace`` / ``tracer`` / ``_tracer`` tail, or a ``get_tracer()``
   call) — the receiver shape is the discriminator that keeps
   unrelated ``.emit(...)`` APIs (the ONNX node builder) out of the
   catalog. Doc side: a backtick span in the FIRST cell of an
-  OBSERVABILITY.md table row matching ``req.name`` / ``step.name``.
+  OBSERVABILITY.md table row matching ``req.name`` / ``step.name``, or
+  one of the bare span names ``step`` / ``sweep``.
 """
 from __future__ import annotations
 
@@ -62,7 +64,9 @@ _FAULT_TOKEN_RE = re.compile(r"^[a-z][a-z0-9_]*\.[a-z][a-z0-9_]*$")
 # fault tokens only by convention, so the event catalog lives in
 # OBSERVABILITY.md (TPL010) while fault points live in RESILIENCE.md
 # (TPL004)
-_EVENT_TOKEN_RE = re.compile(r"^(req|step|brownout)\.[a-z][a-z0-9_]*$")
+_EVENT_TOKEN_RE = re.compile(
+    r"^((req|step|brownout)\.[a-z][a-z0-9_]*|step|sweep)$")
+_TRACE_SITE_ATTRS = ("emit", "begin", "next")
 _TRACER_RECEIVER_RE = re.compile(r"^_?tracer?$")
 _BACKTICK_RE = re.compile(r"`([^`]+)`")
 _REGISTRY_RECEIVER_RE = re.compile(r"^_?reg(istry)?$", re.IGNORECASE)
@@ -315,13 +319,14 @@ def _is_tracer_receiver(node: ast.AST) -> bool:
 
 
 def collect_trace_emits(tree: ast.Module, relpath: str) -> List[TraceEmit]:
-    """Literal trace-event names at tracer ``.emit(...)`` call sites
-    (see the module docstring's trace-emit grammar)."""
+    """Literal trace-event names at tracer ``.emit(...)`` / ``.begin(...)``
+    / ``.next(...)`` call sites (see the module docstring's trace-emit
+    grammar)."""
     out = []
     for node in ast.walk(tree):
         if (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "emit"
+                and node.func.attr in _TRACE_SITE_ATTRS
                 and node.args
                 and _is_tracer_receiver(node.func.value)):
             first = node.args[0]

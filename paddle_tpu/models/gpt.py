@@ -325,7 +325,9 @@ class GPTMLP(nn.Layer):
 
 
 class GPTDecoderLayer(nn.Layer):
-    """Pre-LN block: x + attn(ln1(x)); x + mlp(ln2(x))."""
+    """Pre-LN block: x + attn(ln1(x)); x + mlp(ln2(x)). Each half runs
+    under a ``jax.named_scope`` (``attn``, ``mlp``), so a device trace's
+    operations say which half they belong to (PERF.md section 3)."""
 
     def __init__(self, config: GPTConfig):
         super().__init__()
@@ -338,31 +340,37 @@ class GPTDecoderLayer(nn.Layer):
 
     def forward(self, x, cache=None, cur_len=None):
         if cache is not None:
-            h, new_cache = self.attn(self.ln1(x), cache=cache,
-                                     cur_len=cur_len)
-            x = x + h
-            x = x + self.mlp(self.ln2(x))
+            with jax.named_scope("attn"):
+                h, new_cache = self.attn(self.ln1(x), cache=cache,
+                                         cur_len=cur_len)
+                x = x + h
+            with jax.named_scope("mlp"):
+                x = x + self.mlp(self.ln2(x))
             return x, new_cache
-        h = self.attn(self.ln1(x))
-        if self.drop_p and self.training:
-            h = F.dropout(h, self.drop_p)
-        x = x + h
-        h = self.mlp(self.ln2(x))
-        if self.drop_p and self.training:
-            h = F.dropout(h, self.drop_p)
-        return x + h
+        with jax.named_scope("attn"):
+            h = self.attn(self.ln1(x))
+            if self.drop_p and self.training:
+                h = F.dropout(h, self.drop_p)
+            x = x + h
+        with jax.named_scope("mlp"):
+            h = self.mlp(self.ln2(x))
+            if self.drop_p and self.training:
+                h = F.dropout(h, self.drop_p)
+            return x + h
 
     def forward_paged(self, x, positions, block_tables, k_pool, v_pool,
                       adapters=None, layer_idx=0, k_scale=None,
                       v_scale=None):
-        h, nc = self.attn.forward_paged(self.ln1(x), positions,
-                                        block_tables, k_pool, v_pool,
-                                        adapters=adapters,
-                                        layer_idx=layer_idx,
-                                        k_scale=k_scale, v_scale=v_scale)
-        x = x + h
-        return x + self.mlp(self.ln2(x), adapters=adapters,
-                            layer_idx=layer_idx), nc
+        with jax.named_scope("attn"):
+            h, nc = self.attn.forward_paged(self.ln1(x), positions,
+                                            block_tables, k_pool, v_pool,
+                                            adapters=adapters,
+                                            layer_idx=layer_idx,
+                                            k_scale=k_scale, v_scale=v_scale)
+            x = x + h
+        with jax.named_scope("mlp"):
+            return x + self.mlp(self.ln2(x), adapters=adapters,
+                                layer_idx=layer_idx), nc
 
 
 class GPTModel(nn.Layer):
@@ -408,6 +416,11 @@ class GPTModel(nn.Layer):
                                  epsilon=config.layer_norm_epsilon)
         self.drop_p = config.hidden_dropout_prob
 
+    def _embed(self, ids, position_ids):
+        with jax.named_scope("embed"):
+            return (self.embeddings(ids)
+                    + self.position_embeddings(position_ids))
+
     def _seq_parallel(self, x):
         mesh = topology.get_mesh()
         if (not self.config.sequence_parallel or mesh is None
@@ -435,7 +448,7 @@ class GPTModel(nn.Layer):
                 lambda cl: (jnp.arange(S, dtype=jnp.int32)[None, :]
                             + cl.astype(jnp.int32)).repeat(B, axis=0),
                 [ensure_tensor(cur_len)], name="decode_positions")
-            x = self.embeddings(ids) + self.position_embeddings(position_ids)
+            x = self._embed(ids, position_ids)
             new_caches = []
             for layer, cache in zip(self.layers, caches):
                 x, nc = layer(x, cache=cache, cur_len=cur_len)
@@ -444,7 +457,7 @@ class GPTModel(nn.Layer):
         if position_ids is None:
             pos_val = jnp.arange(S, dtype=jnp.int32)[None, :].repeat(B, axis=0)
             position_ids = Tensor(pos_val, stop_gradient=True)
-        x = self.embeddings(ids) + self.position_embeddings(position_ids)
+        x = self._embed(ids, position_ids)
         if self.drop_p and self.training:
             x = F.dropout(x, self.drop_p)
         x = self._seq_parallel(x)
@@ -487,7 +500,7 @@ class GPTModel(nn.Layer):
         pos_ids = apply_op(
             lambda p: p.astype(jnp.int32).reshape(-1, 1),
             [ensure_tensor(positions)], name="paged_positions")
-        x = self.embeddings(ids) + self.position_embeddings(pos_ids)
+        x = self._embed(ids, pos_ids)
         new_caches = []
         for li, (layer, cache) in enumerate(zip(self.layers, caches)):
             kp, vp = cache[0], cache[1]
@@ -530,12 +543,13 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
             from ..ops.fused_loss import fused_linear_cross_entropy
 
             H = self.config.hidden_size
-            loss = apply_op(
-                lambda h, w, y: fused_linear_cross_entropy(
-                    h.reshape(-1, H), w, y.reshape(-1)),
-                [ensure_tensor(hidden), self.gpt.embeddings.weight,
-                 ensure_tensor(labels)],
-                name="fused_linear_cross_entropy")
+            with jax.named_scope("head_loss"):
+                loss = apply_op(
+                    lambda h, w, y: fused_linear_cross_entropy(
+                        h.reshape(-1, H), w, y.reshape(-1)),
+                    [ensure_tensor(hidden), self.gpt.embeddings.weight,
+                     ensure_tensor(labels)],
+                    name="fused_linear_cross_entropy")
             return None, loss
         logits = self.logits(hidden)
         if labels is None:

@@ -266,6 +266,7 @@ def _flash_fwd_bhsd(q, k, v, causal: bool, scale: float,
             pltpu.VMEM((bq, LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_fwd",
         **_compiler_params(),
     )(qp, kp, vp, seed2, kp2)
     return out[:, :sq], lse[:, :sq, 0]
@@ -480,6 +481,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, causal: bool, scale: float,
         out_shape=jax.ShapeDtypeStruct((bh, qp.shape[1], d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_attention_bwd_dq",
         **_compiler_params(),
     )(qp, kp, vp, dop, lse_b, dlt_b, seed2, kp2)
 
@@ -509,6 +511,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, do, causal: bool, scale: float,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_bwd_dkv",
         **_compiler_params(),
     )(kp, vp, qp, dop, lse_b, dlt_b, seed2, kp2)
 

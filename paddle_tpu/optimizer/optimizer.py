@@ -18,6 +18,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Iterable, Optional, Sequence, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -240,7 +241,10 @@ class Optimizer:
             if reg is not None:
                 gv = reg(pv, gv)
             plr = self._param_lr(p)
-            new_val, new_accs = self._update(pv, gv, accs, lr * plr)
+            # "adamw_update" etc.: a device trace can then tell the
+            # optimizer's fusions from the model's
+            with jax.named_scope(type(self).__name__.lower() + "_update"):
+                new_val, new_accs = self._update(pv, gv, accs, lr * plr)
             if found_inf is not None:
                 new_val = jnp.where(found_inf, pv, new_val)
                 new_accs = {

@@ -58,7 +58,8 @@ Telemetry (docs/OBSERVABILITY.md): every step feeds the always-on
 / step-time histograms, the per-step prefill/decode token mix and chunk
 sizes, request lifecycle counters, and page/queue gauges (the latter via
 ``profiler.record_counter``, which ALSO lands them in the chrome trace
-next to the ``engine_step`` spans whenever a profiler is recording).
+whenever a profiler is recording). Each step is a ``step`` span with its
+phases and grid counters on the request-trace ring (``tracing.py``).
 ``engine.stats`` stays a thin per-step dict view over the same numbers.
 """
 from __future__ import annotations
@@ -88,6 +89,9 @@ __all__ = ["ServingEngine"]
 
 _MIN_GRID_TOKENS = 16
 _engine_counter = itertools.count()
+# the grid counters of a step that ran no rows (tracing.COUNTERS["step"]
+# less its last, ``landed``)
+_NO_GRID = (0, 0, 0, 0, 0, 0, 0, 0)
 
 faults.declare_point(
     "serving.step", "top of ServingEngine.step(), before the deadline "
@@ -257,6 +261,8 @@ class ServingEngine:
         # seqs live THERE, so a migrated request's timeline stays one
         # contiguous stream across engines
         self._trace = tracing.get_tracer()
+        self._phase = None          # the open step.* span (tracing.Span)
+        self._grid_counts = _NO_GRID
         self.trunk = model._decode_trunk()
         n_layers, n_kv, head_dim = model._cache_spec()
         self.n_layers = n_layers
@@ -991,17 +997,28 @@ class ServingEngine:
     def step(self) -> List[RequestOutput]:
         """One engine iteration: admit → one unified ragged step (decode
         tokens + prompt chunks under the token budget) → retire. Returns
-        requests that finished during this step."""
-        from ..profiler import RecordEvent, record_counter
+        requests that finished during this step.
 
+        On the trace ring (and, under ``jax.profiler.start_trace``, on
+        the host plane of the device trace) the call is one ``step`` span
+        tiled by ``step.plan`` → ``step.pack`` → ``step.dispatch`` →
+        ``step.wait`` → ``step.land``; a step that runs no rows is
+        ``step.plan`` alone."""
+        from ..profiler import record_counter
+
+        trace = self._trace
+        span = trace.begin("step", self.engine_id)
+        self._phase = trace.begin("step.plan", self.engine_id)
+        self._grid_counts = _NO_GRID
+        counts = None
         t0 = time.perf_counter()
         if self.watchdog is not None:
             self.watchdog.begin_step()
         tokens_before = self.stats["generated_tokens"]
         finished: List[RequestOutput] = []
         try:
-            faults.point("serving.step")
-            with RecordEvent("engine_step"):
+            try:
+                faults.point("serving.step")
                 finished.extend(self._sweep_deadlines())
                 if self._overload is not None:
                     # brownout level >= 3: preempt batch-tier decode
@@ -1040,42 +1057,51 @@ class ServingEngine:
                     self._unpark_ready()
                 if any(s is not None for s in self.slots):
                     finished.extend(self._step_once())
+            finally:
+                # the watchdog bracket must close even when the step body
+                # raises (an armed fault, an unhandled bug) — otherwise
+                # _in_step_since stays set and an IDLE engine reads as
+                # live-hung on /healthz forever
+                dt = time.perf_counter() - t0
+                self._avg_step_s = 0.8 * self._avg_step_s + 0.2 * dt
+                if self.watchdog is not None:
+                    if self.watchdog.end_step(dt):
+                        self._m_wd_trips.inc()
+                    self._m_degraded.set(
+                        0.0 if self.watchdog.status() == "ok" else 1.0)
+            self._m_step.observe(dt)
+            self.stats["steps"] += 1
+            self.stats["queue_depth"] = self.scheduler.queue_depth
+            self.stats["running_seqs"] = sum(
+                1 for s in self.slots if s is not None)
+            # zero-duration guard: a clock with coarse resolution can
+            # report dt == 0 for an idle step — a rate of 0 beats a
+            # ZeroDivisionError (or the absurd spike 1e-9 used to produce)
+            tokens_this_step = (self.stats["generated_tokens"]
+                                - tokens_before)
+            self.stats["tokens_per_sec"] = (
+                tokens_this_step / dt if dt > 0.0 else 0.0)
+            self.stats["page_utilization"] = self.pool.utilization()
+            self.stats["peak_pages"] = self.pool.peak_used
+            record_counter("serving.queue_depth", self.stats["queue_depth"])
+            record_counter("serving.running_seqs",
+                           self.stats["running_seqs"])
+            record_counter("serving.tokens_per_sec",
+                           self.stats["tokens_per_sec"])
+            record_counter("serving.page_utilization",
+                           self.stats["page_utilization"])
+            # engine-scoped trace event: step.tokens keys on the
+            # engine_id, so trace_dump renders engine throughput as a
+            # counter track next to the per-request tracks
+            trace.emit("step.tokens", self.engine_id,
+                       arg=float(tokens_this_step))
+            counts = self._grid_counts + (tokens_this_step,)
         finally:
-            # the watchdog bracket must close even when the step body
-            # raises (an armed fault, an unhandled bug) — otherwise
-            # _in_step_since stays set and an IDLE engine reads as
-            # live-hung on /healthz forever
-            dt = time.perf_counter() - t0
-            self._avg_step_s = 0.8 * self._avg_step_s + 0.2 * dt
-            if self.watchdog is not None:
-                if self.watchdog.end_step(dt):
-                    self._m_wd_trips.inc()
-                self._m_degraded.set(
-                    0.0 if self.watchdog.status() == "ok" else 1.0)
-        self._m_step.observe(dt)
-        self.stats["steps"] += 1
-        self.stats["queue_depth"] = self.scheduler.queue_depth
-        self.stats["running_seqs"] = sum(
-            1 for s in self.slots if s is not None)
-        # zero-duration guard: a clock with coarse resolution can report
-        # dt == 0 for an idle step — a rate of 0 beats a ZeroDivisionError
-        # (or the absurd spike 1e-9 used to produce)
-        tokens_this_step = self.stats["generated_tokens"] - tokens_before
-        self.stats["tokens_per_sec"] = (
-            tokens_this_step / dt if dt > 0.0 else 0.0)
-        self.stats["page_utilization"] = self.pool.utilization()
-        self.stats["peak_pages"] = self.pool.peak_used
-        record_counter("serving.queue_depth", self.stats["queue_depth"])
-        record_counter("serving.running_seqs", self.stats["running_seqs"])
-        record_counter("serving.tokens_per_sec",
-                       self.stats["tokens_per_sec"])
-        record_counter("serving.page_utilization",
-                       self.stats["page_utilization"])
-        # engine-scoped trace event: step.tokens keys on the engine_id,
-        # so trace_dump renders engine throughput as a counter track
-        # next to the per-request tracks
-        self._trace.emit("step.tokens", self.engine_id,
-                         arg=float(tokens_this_step))
+            # a step that raised still closes its spans (without counts):
+            # an open one would adopt every later span as its child
+            trace.end(self._phase)
+            self._phase = None
+            trace.end(span, counts)
         # outputs were registered in self._outputs eagerly at retirement
         return finished
 
@@ -1777,6 +1803,8 @@ class ServingEngine:
         faults.point("serving.decode_step")
         if not rows:
             return finished
+        trace = self._trace
+        self._phase = trace.next("step.pack", self._phase)
         total = sum(r[1].size for r in rows)
         T = self._grid_tokens(total)
         # a bucket this engine never ran compiles (or deserializes from
@@ -1797,9 +1825,16 @@ class ServingEngine:
         # entries stay 0 = the all-True identity row (mask is a no-op)
         fsm_state = np.zeros((B, S), np.int32)
         cur = 0
+        kv_walked = kv_held = 0
         for i, toks, poss, is_chunk, d in rows:
             st = self.slots[i]
             c = toks.size
+            # the keys the kernel walks: row at position p attends p + 1
+            # of them, so a slot's c consecutive rows walk the sum; the
+            # keys that exist for the slot are its last row's
+            first, last = int(poss[0]), int(poss[-1])
+            kv_walked += c * (first + last + 2) // 2
+            kv_held += last + 1
             tok[cur:cur + c, 0] = toks
             tok_pos[cur:cur + c] = poss
             table = self.pool.block_table(st.req.req_id)
@@ -1835,6 +1870,11 @@ class ServingEngine:
             temps[i] = st.req.temperature
             seeds[i] = st.req.seed
             cur += c
+        self._grid_counts = (
+            total, T, n_decode_tokens,
+            total - n_decode_tokens - n_draft_tokens, n_draft_tokens,
+            len(rows), kv_walked, kv_held)
+        self._phase = trace.next("step.dispatch", self._phase)
         if self._step_prog is None:
             fresh_bucket = True
             self._step_prog = self._compile_with_retry(
@@ -1862,8 +1902,10 @@ class ServingEngine:
             if live.any():
                 self.pool.record_scale_clips(
                     w_pages[live], (tok_pos[:total] % self.page_size)[live])
+        self._phase = trace.next("step.wait", self._phase)
         nxt_host = np.asarray(nxt.numpy()).reshape(B, S)
         fin_host = np.asarray(fin.numpy()).reshape(B, S).astype(bool)
+        self._phase = trace.next("step.land", self._phase)
         now = time.perf_counter()
         self._m_decode.observe(now - t0)
         self._m_mix_decode.observe(n_decode_tokens)

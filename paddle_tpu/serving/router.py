@@ -717,31 +717,37 @@ class Router:
         requeue and its in-flight requests migrate by token journal,
         and the sweep continues with the next engine. A single engine
         death is invisible to every other tenant of the fleet."""
-        if self._retry_budget is not None:
-            self._retry_budget.refill()  # one sweep's worth of tokens
-        self._refresh_health()
-        for h in list(self._handles.values()):
-            if h.state == DOWN:
-                continue
-            try:
-                if not h.engine.has_work:
+        # the sweep span encloses its engines' ``step`` spans; what is
+        # left of it is the router's own (health refresh, reaping, WAL)
+        sweep = self._trace.begin("sweep", "router")
+        try:
+            if self._retry_budget is not None:
+                self._retry_budget.refill()  # one sweep's worth of tokens
+            self._refresh_health()
+            for h in list(self._handles.values()):
+                if h.state == DOWN:
                     continue
-                faults.point("router.engine_step")
-                h.engine.step()
-            except Exception as e:
-                self._contain(h, e)
-        # reap move-once marks of moved requests that retired on their
-        # adoptive engine: a step()-driven server (never calling run())
-        # must not grow _requeued forever across incidents. Free in the
-        # steady state (the set is empty unless a failover happened);
-        # after one, a single guarded pass keeps only ids still live
-        # somewhere in the fleet.
-        if self._requeued:
-            live = self._live_req_ids()
-            if live is not None:
-                self._requeued &= live
-        if self._wal is not None:
-            self._wal_commit_and_flush()
+                try:
+                    if not h.engine.has_work:
+                        continue
+                    faults.point("router.engine_step")
+                    h.engine.step()
+                except Exception as e:
+                    self._contain(h, e)
+            # reap move-once marks of moved requests that retired on their
+            # adoptive engine: a step()-driven server (never calling run())
+            # must not grow _requeued forever across incidents. Free in the
+            # steady state (the set is empty unless a failover happened);
+            # after one, a single guarded pass keeps only ids still live
+            # somewhere in the fleet.
+            if self._requeued:
+                live = self._live_req_ids()
+                if live is not None:
+                    self._requeued &= live
+            if self._wal is not None:
+                self._wal_commit_and_flush()
+        finally:
+            self._trace.end(sweep)
 
     def _live_req_ids(self) -> Optional[set]:
         """Every req_id currently queued or in-flight on any non-down

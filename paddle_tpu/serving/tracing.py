@@ -29,6 +29,17 @@ Design (docs/OBSERVABILITY.md "Request tracing & flight recorder"):
   disabled-registry contract; pinned by tests/test_tracing.py). Enabled,
   ``emit`` mutates a preallocated slot in place — no metric calls, no
   locks, no allocation beyond the interned floats Python itself makes.
+- **Spans.** ``begin`` / ``next`` / ``end`` record an interval into the
+  same ring, in the same slot shape: ``t`` is the span's END and ``arg``
+  its duration in seconds (the ``req.compile`` convention), plus a
+  tracer-wide span id and the id of the span that was open when it
+  began — an engine's ``step`` under the router's ``sweep``, the five
+  ``step.*`` phases under their ``step``. The same call opens a
+  ``jax.profiler.TraceAnnotation`` of the same name for the span's life,
+  so under ``jax.profiler.start_trace`` every program span also lies on
+  the ``/host:CPU`` plane, on the device trace's clock: one call site,
+  two sinks, one name. Counters that belong to a span (``COUNTERS``)
+  ride on it as one tuple, counted where the work happens.
 - **Flight recorder.** The ring is always armed; ``dump_flight`` writes
   the last ``window_s`` seconds of fleet timeline to disk as JSON. The
   Router calls it from crash containment and on the /healthz ok→degraded
@@ -48,13 +59,15 @@ import os
 import re
 import tempfile
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from .. import faults, metrics
 
 __all__ = [
-    "EVENTS", "RequestTracer", "TTFT_BUCKETS", "attribute_ttft",
-    "get_tracer", "set_tracer", "validate_events",
+    "COUNTERS", "EVENTS", "RequestTracer", "Span", "TTFT_BUCKETS",
+    "attribute_ttft", "get_tracer", "set_tracer", "validate_events",
 ]
 
 faults.declare_point(
@@ -67,8 +80,10 @@ faults.declare_point(
 # The event-name catalog: every literal ``tracer.emit("<name>", ...)``
 # site in the package uses one of these, and docs/OBSERVABILITY.md
 # tables them — tpulint TPL010 pins both directions. ``req.*`` events
-# key on the request id; ``step.*`` events are engine-scoped (their
-# req_id is the engine_id string) and render as counter tracks.
+# key on the request id; ``step`` / ``step.*`` are engine-scoped (their
+# req_id is the engine_id string): ``step.tokens`` renders as a counter
+# track, the rest are spans (``begin``/``next``), as is the router's
+# ``sweep`` (req_id: "router").
 EVENTS: Dict[str, str] = {
     "req.enqueue": "request entered an engine queue (arg: prompt tokens)",
     "req.dispatch": "router placed the request (label: engine_id)",
@@ -88,6 +103,10 @@ EVENTS: Dict[str, str] = {
                        "(arg: rejected drafts)",
     "req.grammar_mask": "constrained token landed, DFA advanced "
                         "(arg: new FSM state)",
+    "req.park": "stream parked on the host KV tier (arg: pages "
+                "offloaded)",
+    "req.unpark": "parked stream's pages restored to HBM (arg: pages "
+                  "prefetched)",
     "req.token": "stream chunk emitted (arg: stream seq)",
     "req.retire": "terminal (label: finish_reason)",
     "req.export": "in-flight journal exported off a dying engine "
@@ -113,6 +132,33 @@ EVENTS: Dict[str, str] = {
                    "landed this step)",
     "brownout.level": "brownout ladder transition (req_id: model_id; "
                       "arg: new level; label: level name)",
+    # spans: t = end, arg = seconds, span/parent ids (see ``begin``)
+    "sweep": "span: one Router.step() (req_id: \"router\"); its engine "
+             "steps are its children, the rest is the router's own",
+    "step": "span: one ServingEngine.step() (req_id: engine_id; parent: "
+            "the sweep; counts: COUNTERS[\"step\"]); tiled by step.*",
+    "step.plan": "span: deadline sweep, brownout/park hooks, admission, "
+                 "plan_chunks, drafting, pool reservations",
+    "step.pack": "span: the numpy token grid, sample rows, FSM states",
+    "step.dispatch": "span: host->device transfers and the call of the "
+                     "step program until it returns (a fresh bucket "
+                     "compiles here)",
+    "step.wait": "span: blocked on the step's read-back: the device "
+                 "runs the step",
+    "step.land": "span: acceptance, token landing, stream callbacks, "
+                 "prefix-cache insert, retirement, stats and gauges",
+}
+
+# Counters carried by a span, in the order its ``end(counts=...)`` tuple
+# gives them; ``events()`` renders them as a dict under ``"counts"``.
+COUNTERS: Dict[str, Tuple[str, ...]] = {
+    # rows: real rows of the token grid; bucket: its padded length T;
+    # seqs: slots with a row; kv_walked: sum over real rows of (row
+    # position + 1), the keys the paged kernel's grid walks this step;
+    # kv_held: sum over those slots of (last row's position + 1), the
+    # keys that exist to be read once; landed: tokens landed
+    "step": ("rows", "bucket", "decode_rows", "chunk_rows", "draft_rows",
+             "seqs", "kv_walked", "kv_held", "landed"),
 }
 
 # TTFT attribution buckets (docs/OBSERVABILITY.md "TTFT attribution"):
@@ -131,6 +177,12 @@ _QUEUE_EVENTS = frozenset(("req.admit", "req.prefix_hit"))
 _REASON_SAFE_RE = re.compile(r"[^a-zA-Z0-9_.-]+")
 
 
+class Span:
+    """One open span: what ``begin`` hands out and ``end`` closes."""
+
+    __slots__ = ("name", "key", "sid", "outer", "t0", "ann")
+
+
 class RequestTracer:
     """Always-on bounded event journal keyed ``(req_id, seq)``.
 
@@ -147,10 +199,13 @@ class RequestTracer:
                  window_s: float = 30.0):
         cap = max(int(capacity), 16)
         self._cap = cap
-        # preallocated mutable slots [t, req_id, seq, name, arg, label]
-        # — emit() writes fields in place, so a full ring never grows
-        self._ring: List[list] = [[0.0, None, 0, "", 0.0, ""]
+        # preallocated mutable slots [t, req_id, seq, name, arg, label,
+        # span id, parent span id, counts] — written in place, so a full
+        # ring never grows. A point event's span id is 0.
+        self._ring: List[list] = [[0.0, None, 0, "", 0.0, "", 0, 0, None]
                                   for _ in range(cap)]
+        self._sid = 0           # last span id handed out
+        self._open: Optional[Span] = None   # innermost open span
         self._head = 0          # next slot to write
         self._count = 0         # filled slots (== cap once wrapped)
         self._seq: Dict[object, int] = {}
@@ -165,12 +220,16 @@ class RequestTracer:
     def emit(self, name: str, req_id, arg: float = 0.0, label: str = "",
              t: Optional[float] = None) -> None:
         """Journal one event. Disabled = this flag check; enabled = a
-        dict get/set (the per-request seq) plus six in-place slot
-        writes. Never raises, never locks, never touches a metric."""
+        dict get/set (the per-request seq) plus in-place slot writes.
+        Never raises, never locks, never touches a metric."""
         if not self.enabled:
             return
         if t is None:
             t = self._clock()
+        self._write(t, req_id, name, arg, label, 0, 0, None)
+
+    def _write(self, t, req_id, name, arg, label, sid, parent,
+               counts) -> None:
         seq = self._seq.get(req_id, 0)
         self._seq[req_id] = seq + 1
         i = self._head
@@ -185,7 +244,59 @@ class RequestTracer:
         slot[3] = name
         slot[4] = arg
         slot[5] = label
+        slot[6] = sid
+        slot[7] = parent
+        slot[8] = counts
         self._head = 0 if i + 1 == self._cap else i + 1
+
+    # ---------------------------------------------------------------- spans
+    def begin(self, name: str, key) -> Optional[Span]:
+        """Open a span keyed ``key`` (an engine_id, "router", or a
+        req_id) under whatever span is open now, and enter a
+        ``TraceAnnotation`` of the same name. Disabled = this flag check
+        (returns None, which ``next`` and ``end`` pass through). Spans
+        close in the order they nest: the step path is one thread."""
+        if not self.enabled:
+            return None
+        sp = Span()
+        sp.name, sp.key = name, key
+        self._sid = sp.sid = self._sid + 1
+        sp.outer = self._open
+        self._open = sp
+        sp.ann = TraceAnnotation(name)   # the annotation starts here
+        sp.t0 = self._clock()
+        return sp
+
+    def _close(self, sp: Span, counts) -> float:
+        """One ring slot for ``sp``: ``t`` = now, ``arg`` = its seconds,
+        its id and its parent's (0: none). Returns ``t``."""
+        sp.ann.__exit__(None, None, None)
+        t = self._clock()
+        outer = sp.outer
+        self._write(t, sp.key, sp.name, t - sp.t0, "", sp.sid,
+                    outer.sid if outer is not None else 0, counts)
+        return t
+
+    def next(self, name: str, sp: Optional[Span]) -> Optional[Span]:
+        """Close ``sp`` and open its sibling ``name`` (same key, same
+        parent) on ONE clock read, so successive phases tile their
+        parent with no gap between them."""
+        if sp is None:
+            return None
+        sp.t0 = self._close(sp, None)
+        self._sid = sp.sid = self._sid + 1
+        sp.name = name
+        sp.ann = TraceAnnotation(name)
+        return sp
+
+    def end(self, sp: Optional[Span],
+            counts: Optional[Tuple[int, ...]] = None) -> None:
+        """Close ``sp``, with ``counts`` (a tuple in ``COUNTERS[name]``
+        order) where the span carries counters."""
+        if sp is None:
+            return
+        self._close(sp, counts)
+        self._open = sp.outer
 
     # ------------------------------------------------------------ snapshots
     def events(self) -> List[dict]:
@@ -196,8 +307,16 @@ class RequestTracer:
             raw = self._ring[:self._count]
         else:
             raw = self._ring[self._head:] + self._ring[:self._head]
-        return [{"t": s[0], "req_id": s[1], "seq": s[2], "name": s[3],
-                 "arg": s[4], "label": s[5]} for s in raw]
+        out = []
+        for s in raw:
+            e = {"t": s[0], "req_id": s[1], "seq": s[2], "name": s[3],
+                 "arg": s[4], "label": s[5]}
+            if s[6]:   # a span: t is its end, arg its seconds
+                e["span"], e["parent"] = s[6], s[7]
+                if s[8] is not None:
+                    e["counts"] = dict(zip(COUNTERS.get(s[3], ()), s[8]))
+            out.append(e)
+        return out
 
     def events_for(self, req_id) -> List[dict]:
         """This request's timeline in seq order — contiguous across any
@@ -218,6 +337,7 @@ class RequestTracer:
         self._count = 0
         self._seq.clear()
         self._dropped = 0
+        self._open = None
 
     # -------------------------------------------------------------- metrics
     def flush_metrics(self) -> None:

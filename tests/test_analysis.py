@@ -921,6 +921,29 @@ class TestTPL010TraceEventParity:
         """}, obs_doc=_OBS_WITH_EVENT, metric_doc_scope="")
         assert "TPL010" not in rules_fired(res), res.findings
 
+    @pytest.mark.parametrize("site, name", [
+        ('sp = self._trace.begin("step", "e")', "step"),
+        ('sp = self._trace.next("step.fixture", sp)', "step.fixture"),
+        ('sp = self._trace.begin("sweep", "router")', "sweep")])
+    def test_span_sites_are_cataloged_like_emit_sites(self, tmp_path,
+                                                      site, name):
+        # spans (tracer.begin / tracer.next) name themselves at the call
+        # site as events do, bare ``step`` / ``sweep`` included
+        src = f"""
+            class Engine:
+                def step(self, sp=None):
+                    {site}
+        """
+        res = run_lint(tmp_path, {"mod.py": src}, metric_doc_scope="")
+        msgs = [f.message for f in res.findings if f.rule == "TPL010"]
+        assert any(f"`{name}`" in m and "not cataloged" in m
+                   for m in msgs), res.findings
+        obs = ("# O\n\n| event | when |\n|---|---|\n"
+               f"| `{name}` | span |\n")
+        res = run_lint(tmp_path, {"mod.py": src}, obs_doc=obs,
+                       metric_doc_scope="")
+        assert "TPL010" not in rules_fired(res), res.findings
+
     def test_unrelated_emit_api_is_ignored(self, tmp_path):
         # the ONNX node builder's self.emit("Sqrt", ...) must not be
         # mistaken for a trace site: the receiver is not tracer-shaped

@@ -81,6 +81,28 @@ def test_cross_attention_backward():
                                    atol=5e-4, rtol=5e-4)
 
 
+def test_the_three_kernels_carry_their_names():
+    """A device trace tells the forward, dQ and dK/dV kernels apart by the
+    ``name=`` of their ``pallas_call`` (PERF.md section 3)."""
+    q, k, v = (_rand((1, 256, 1, 64), i) for i in (3, 4, 5))
+    jaxpr = jax.make_jaxpr(
+        jax.grad(lambda q, k, v: jnp.sum(
+            fa._flash_attention(q, k, v, jnp.float32(0), True, 0.125,
+                                fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K))),
+    )(q, k, v)
+
+    def names(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from names(sub)
+
+    assert sorted(names(jaxpr.jaxpr)) == [
+        "flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+        "flash_attention_fwd"]
+
+
 def test_backward_jaxpr_has_no_SxS_intermediate():
     """The grad jaxpr must contain no [S,S]-shaped dense intermediates
     outside the pallas kernels (VERDICT weak #3: bwd used to re-run

@@ -67,6 +67,36 @@ class TestGPTSingleDevice:
         assert losses[-1] < losses[0] * 0.9
         assert len(step._cache) == 1
 
+    def test_train_step_operations_carry_their_scopes(self):
+        """The compiled train step's operations say which part of the
+        model they belong to (``jax.named_scope``): what a device trace's
+        operation names are split by (PERF.md section 3)."""
+        import re
+
+        paddle.seed(1)
+        cfg = gpt_tiny(num_layers=1, vocab_size=128, fused_loss=True,
+                       hidden_dropout_prob=0.0, attention_dropout_prob=0.0)
+        model = GPTForCausalLM(cfg)
+        opt = paddle.optimizer.AdamW(learning_rate=3e-3,
+                                     parameters=model.parameters())
+
+        @jit.to_static
+        def step(ids, labels):
+            _, loss = model(ids, labels=labels)
+            loss.backward()
+            opt.step()
+            opt.clear_grad()
+            return loss
+
+        ids, labels = _batch(cfg, B=2, S=16, seed=2)
+        for _ in range(2):
+            step(ids, labels)
+        text = step._lowered().as_text(debug_info=True)
+        scopes = {part for loc in re.findall(r'loc\("([^"]+)"', text)
+                  for part in loc.split("/")}
+        assert {"embed", "attn", "mlp", "head_loss",
+                "adamw_update"} <= scopes
+
 
 class TestGPTTensorParallel:
     def test_tp_matches_single_device(self):

@@ -148,39 +148,62 @@ class TestProfiler:
         assert exports == [["work1"], ["work3"]]
 
     def test_engine_step_spans_and_counters_in_trace(self, tmp_path):
-        """Serving steps appear in chrome traces: engine.step() wraps in a
-        RecordEvent('engine_step') span and pushes the engine gauges
-        through record_counter (ph 'C' events + summary table)."""
+        """Serving steps appear in chrome traces: engine.step() pushes
+        the engine gauges through record_counter (ph 'C' events + summary
+        table) into the profiler's trace, and is a ``step`` span on the
+        request-trace ring, which tools/trace_dump.py renders as ph 'X'
+        slices (the span replaced RecordEvent('engine_step'), PR 25)."""
+        import importlib.util
+
         from paddle_tpu.models import LlamaForCausalLM, llama_tiny
-        from paddle_tpu.serving import ServingEngine
+        from paddle_tpu.serving import ServingEngine, tracing
+
+        tools = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "trace_dump.py")
+        spec = importlib.util.spec_from_file_location("td_prof", tools)
+        trace_dump = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(trace_dump)
 
         paddle.seed(0)
         model = LlamaForCausalLM(llama_tiny(
             vocab_size=64, hidden_size=32, num_layers=1, num_heads=2,
             num_key_value_heads=2, max_position_embeddings=32))
-        engine = ServingEngine(model, page_size=4, max_batch_slots=1)
-        engine.add_request(np.arange(4), max_new_tokens=2)
-        p = Profiler(targets=[ProfilerTarget.CPU],
-                     on_trace_ready=export_chrome_tracing(str(tmp_path)),
-                     trace_dir=str(tmp_path))
-        p.start()
-        while engine.has_work:
-            engine.step()
-            p.step()
-        p.stop()
+        old = tracing.set_tracer(tracing.RequestTracer(capacity=1024))
+        try:
+            engine = ServingEngine(model, page_size=4, max_batch_slots=1)
+            engine.add_request(np.arange(4), max_new_tokens=2)
+            p = Profiler(targets=[ProfilerTarget.CPU],
+                         on_trace_ready=export_chrome_tracing(str(tmp_path)),
+                         trace_dir=str(tmp_path))
+            p.start()
+            n_steps = 0
+            while engine.has_work:
+                engine.step()
+                p.step()
+                n_steps += 1
+            p.stop()
+            ring = tracing.get_tracer().events()
+        finally:
+            tracing.set_tracer(old)
         files = [f for f in os.listdir(tmp_path)
                  if f.endswith(".paddle_trace.json")]
         assert files
         trace = load_profiler_result(os.path.join(tmp_path, files[0]))
-        spans = [e for e in trace["traceEvents"]
-                 if e["name"] == "engine_step" and e["ph"] == "X"]
-        assert spans, "no engine_step spans in the chrome trace"
+        assert not [e for e in trace["traceEvents"]
+                    if e["name"] == "engine_step"]
         counters = {e["name"] for e in trace["traceEvents"]
                     if e["ph"] == "C"}
         assert "serving.queue_depth" in counters
         assert "serving.tokens_per_sec" in counters
-        out = p.summary()
-        assert "engine_step" in out and "serving.queue_depth" in out
+        assert "serving.queue_depth" in p.summary()
+        doc, problems = trace_dump.chrome_trace(ring)
+        assert problems == []
+        spans = [e for e in doc["traceEvents"]
+                 if e["name"] == "step" and e["ph"] == "X"]
+        assert len(spans) == n_steps, "one step span per engine.step()"
+        assert all(e["dur"] > 0 and e["args"]["key"] == engine.engine_id
+                   for e in spans)
+        assert sum(e["args"]["landed"] for e in spans) == 2
 
     def test_record_counter_noop_without_profiler(self):
         from paddle_tpu.profiler import record_counter

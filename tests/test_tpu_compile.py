@@ -65,6 +65,12 @@ def test_flash_attention_compiles_for_v5e(one_chip, B, S, H, D, grad):
     text = _compiled_text(fn, x, x, x)
     # forward alone is one kernel; the backward adds the dq and dkv kernels
     assert text.count("tpu_custom_call") >= (3 if grad else 1)
+    # each under its own name, which is how a device trace tells them apart
+    names = ["flash_attention_fwd"] + (
+        ["flash_attention_bwd_dq", "flash_attention_bwd_dkv"] if grad else [])
+    calls = [line.split(" = ")[0] for line in text.split("\n")
+             if "tpu_custom_call" in line]
+    assert all(any(n in c for c in calls) for n in names), calls
 
 
 def _paged_shapes(one_chip, T, nh, nkv, hd, page, pages, quantized):

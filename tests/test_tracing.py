@@ -14,6 +14,11 @@ loadgen driver's per-tier ``ttft_breakdown`` means match the measured
 mean TTFT within the ±1 ms acceptance bound; and overhead mirrors the
 metrics disabled-registry contract — disabled emit is a flag check,
 enabled emit is allocation-free in steady state.
+
+Spans (ISSUE 25): every engine step is one ``step`` span tiled by its five
+phases under the router's ``sweep``; the ``step`` span's counters are
+those of the grid that was built; the same spans lie on the host plane of
+a ``jax.profiler`` trace; a disabled tracer enters no annotation.
 """
 import importlib.util
 import json
@@ -502,6 +507,257 @@ def _hist_count(name, tier):
     return fam.labels(tier=tier).count if fam is not None else 0
 
 
+# ─────────────────────────────── spans ───────────────────────────────
+
+PHASES = ("step.plan", "step.pack", "step.dispatch", "step.wait",
+          "step.land")
+
+
+def _model64(seed=0):
+    paddle.seed(seed)
+    return LlamaForCausalLM(llama_tiny(
+        vocab_size=32, hidden_size=16, num_layers=1, num_heads=1,
+        num_key_value_heads=1, max_position_embeddings=64))
+
+
+def _spans(events):
+    return [e for e in events if "span" in e]
+
+
+def _check_step_family(step, children):
+    """``children`` (in ring order) are the phases in order, nested in
+    their ``step``, disjoint, and no longer than it together."""
+    names = [c["name"] for c in children]
+    assert names == list(PHASES[:len(names)]), names
+    assert len(names) in (1, 5)   # a step without rows is its plan alone
+    lo, hi = step["t"] - step["arg"], step["t"]
+    prev_end = lo
+    for c in children:
+        c0, c1 = c["t"] - c["arg"], c["t"]
+        assert c["req_id"] == step["req_id"]
+        assert lo <= c0 <= c1 <= hi, (c["name"], lo, c0, c1, hi)
+        assert c0 >= prev_end, f"{c['name']} overlaps its predecessor"
+        prev_end = c1
+    assert sum(c["arg"] for c in children) <= step["arg"] + 1e-9
+
+
+class TestSpans:
+    def test_mixed_schedule_one_step_span_a_step_tiled_by_phases(self):
+        """(a) a decode + chunk schedule through a router: one ``step``
+        span per engine step, its phases in order, nested, disjoint, sum
+        <= parent; every ``sweep`` encloses its ``step``."""
+        with _fresh(capacity=8192) as tr:
+            r = Router()
+            r.add_model("m", _model64(), replicas=1, page_size=4,
+                        num_pages=64, max_batch_slots=3, max_model_len=64,
+                        token_budget=8)
+            eng = r.engines("m")[0]
+            rng = np.random.RandomState(3)
+            r.submit(rng.randint(1, 32, (5,)), model="m", max_new_tokens=6)
+            n_sweeps = 0
+            for i in range(40):
+                if i == 2:    # a 19-token prompt chunks under budget 8
+                    r.submit(rng.randint(1, 32, (19,)), model="m",
+                             max_new_tokens=3)
+                if not r.has_work:
+                    break
+                r.step()
+                n_sweeps += 1
+            assert not r.has_work
+            r.step()          # an idle sweep steps no engine
+            evs = tr.events()
+        assert validate_events(evs) == []
+        spans = _spans(evs)
+        steps = [e for e in spans if e["name"] == "step"]
+        sweeps = {e["span"]: e for e in spans if e["name"] == "sweep"}
+        assert len(steps) == eng.stats["steps"] == n_sweeps
+        assert len(sweeps) == n_sweeps + 1
+        kinds = set()
+        for st in steps:
+            kids = [e for e in spans if e["parent"] == st["span"]]
+            _check_step_family(st, kids)
+            sw = sweeps[st["parent"]]
+            assert sw["t"] - sw["arg"] <= st["t"] - st["arg"]
+            assert st["t"] <= sw["t"]
+            c = st["counts"]
+            assert c["rows"] == (c["decode_rows"] + c["chunk_rows"]
+                                 + c["draft_rows"]) <= c["bucket"]
+            assert c["kv_walked"] >= c["kv_held"] >= c["seqs"]
+            kinds.add((c["decode_rows"] > 0, c["chunk_rows"] > 0))
+        # the schedule really mixed: chunk-only, decode-only, both
+        assert {(False, True), (True, False), (True, True)} <= kinds
+        assert sum(st["counts"]["landed"] for st in steps) == 6 + 3
+        idle = [sw for sid, sw in sweeps.items()
+                if not any(st["parent"] == sid for st in steps)]
+        assert len(idle) == 1
+
+    def test_step_counters_on_a_hand_built_grid(self):
+        """(b) two decode rows at contexts 20 and 31 and one 5-token
+        chunk at positions 12-16 (its first 12 tokens come from the
+        prefix cache): rows 7, kv_walked 21 + 32 + (13+..+17), kv_held
+        21 + 32 + 17."""
+        with _fresh(capacity=4096) as tr:
+            eng = ServingEngine(_model64(), page_size=4, num_pages=64,
+                                max_batch_slots=3, max_model_len=64,
+                                token_budget=64, prefix_cache=True)
+            rng = np.random.RandomState(5)
+            shared = rng.randint(1, 32, (12,))
+            eng.add_request(np.append(shared, 7), max_new_tokens=1)
+            eng.run()      # its three full pages now sit in the cache
+            eng.add_request(rng.randint(1, 32, (20,)), max_new_tokens=8)
+            eng.add_request(rng.randint(1, 32, (31,)), max_new_tokens=8)
+            eng.step()     # both prompts land whole: decode at 20 and 31
+            assert sorted(s.pos for s in eng.slots if s) == [20, 31]
+            eng.add_request(np.concatenate([shared, rng.randint(1, 32, (5,))]),
+                            max_new_tokens=2)
+            eng.step()
+            last = [e for e in tr.events() if e["name"] == "step"][-1]
+        assert last["counts"] == {
+            "rows": 7, "bucket": eng._grid_tokens(7), "decode_rows": 2,
+            "chunk_rows": 5, "draft_rows": 0, "seqs": 3,
+            "kv_walked": 21 + 32 + (13 + 14 + 15 + 16 + 17),
+            "kv_held": 21 + 32 + 17, "landed": 3}
+        assert last["parent"] == 0     # no router, no sweep
+
+    def test_spans_lie_on_the_profiler_trace_host_plane(self, tmp_path):
+        """(c) under jax.profiler.start_trace the .xplane.pb's host
+        plane holds ``sweep``, ``step`` and the five phases, each child
+        inside its parent on that clock."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        with _fresh(capacity=4096):
+            r = Router()
+            r.add_model("m", _model64(), replicas=1, page_size=4,
+                        num_pages=64, max_batch_slots=2, max_model_len=64)
+            r.submit(P5, model="m", max_new_tokens=4)
+            r.step()       # compile outside the trace
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                for _ in range(3):
+                    r.step()
+            finally:
+                jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(
+            str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+        wanted = ("sweep", "step") + PHASES
+        got = {n: [] for n in wanted}
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in got:
+                        got[e.name].append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
+        assert all(len(got[n]) == 3 for n in wanted), \
+            {n: len(v) for n, v in got.items()}
+        for i, (s0, s1) in enumerate(sorted(got["step"])):
+            w0, w1 = sorted(got["sweep"])[i]
+            assert w0 <= s0 and s1 <= w1
+            prev = s0
+            for name in PHASES:
+                c0, c1 = sorted(got[name])[i]
+                assert prev <= c0 <= c1 <= s1, (name, prev, c0, c1, s1)
+                prev = c1
+
+    def test_disabled_tracer_records_nothing_and_enters_no_annotation(
+            self, monkeypatch):
+        """(d) disabled: begin/next/end are a flag check — no slot, no
+        TraceAnnotation. Enabled: one annotation per span, and a span
+        costs under twice the per-event ceiling of the emit guard."""
+        entered = []
+
+        class _Ann:
+            def __init__(self, name):
+                entered.append(name)
+
+            def __exit__(self, *exc):
+                pass
+
+        monkeypatch.setattr(tracing, "TraceAnnotation", _Ann)
+        tr = RequestTracer(capacity=64, enabled=False)
+        sp = tr.begin("step", "e")
+        ph = tr.next("step.pack", tr.begin("step.plan", "e"))
+        tr.end(ph)
+        tr.end(sp, (0,) * 9)
+        assert sp is None and ph is None
+        assert tr.events() == [] and entered == []
+        tr.enabled = True
+        sp = tr.begin("step", "e")
+        ph = tr.next("step.pack", tr.begin("step.plan", "e"))
+        tr.end(ph)
+        tr.end(sp, (0,) * 9)
+        assert entered == ["step", "step.plan", "step.pack"]
+        assert [e["name"] for e in tr.events()] \
+            == ["step.plan", "step.pack", "step"]
+        monkeypatch.undo()
+
+        tr = RequestTracer(capacity=4096)
+        N = 20000
+
+        def loop():
+            t0 = time.perf_counter()
+            for _ in range(N):
+                tr.end(tr.begin("step", "e"))
+            return time.perf_counter() - t0
+
+        loop()  # warm
+        enabled = min(loop() for _ in range(3))
+        tr.enabled = False
+        disabled = min(loop() for _ in range(3))
+        assert enabled / N < 2 * 5e-6   # ~1.3µs measured
+        assert disabled / N < 5e-6      # ~0.10µs measured
+        assert disabled < enabled
+
+    def test_a_raising_step_closes_its_spans(self):
+        """A step body that raises still closes ``step`` and its open
+        phase, so the next step's spans are not adopted by a stale one."""
+        with _fresh(capacity=1024) as tr:
+            eng = ServingEngine(_model(), **_ENGINE_KW)
+            eng.add_request(P3, max_new_tokens=2)
+            with faults.inject("serving.step", raise_=RuntimeError("x"),
+                               times=1, seed=0):
+                with pytest.raises(RuntimeError):
+                    eng.step()
+            eng.run()
+            spans = _spans(tr.events())
+        assert tr._open is None
+        assert [e["name"] for e in spans[:2]] == ["step.plan", "step"]
+        assert "counts" not in spans[1]
+        assert all(e["parent"] == 0 for e in spans if e["name"] == "step")
+
+    def test_trace_dump_renders_spans_as_complete_events(self):
+        td = _trace_dump_mod()
+        step = dict(_ev(2.0, "m/0", 2, "step", arg=0.5), span=2, parent=1,
+                    counts={"rows": 3, "bucket": 8})
+        evs = [
+            dict(_ev(1.6, "m/0", 0, "step.plan", arg=0.1), span=3, parent=2),
+            _ev(1.9, "m/0", 1, "step.tokens", arg=2.0),
+            step,
+            dict(_ev(2.1, "router", 0, "sweep", arg=0.7), span=1, parent=0),
+            _ev(1.0, "a", 0, "req.enqueue"),
+        ]
+        doc, problems = td.chrome_trace(evs, pid=7)
+        assert problems == []
+        tracks = {e["tid"]: e["args"]["name"]
+                  for e in doc["traceEvents"] if e["ph"] == "M"}
+        assert sorted(tracks.values()) == ["req a", "spans m/0",
+                                           "spans router"]
+        xs = {e["name"]: e for e in doc["traceEvents"]
+              if e.get("cat") == "span"}
+        assert set(xs) == {"step.plan", "step", "sweep"}
+        assert xs["step"]["ts"] == pytest.approx(1.5e6)
+        assert xs["step"]["dur"] == pytest.approx(0.5e6)
+        assert xs["step"]["args"] == {"key": "m/0", "span": 2, "parent": 1,
+                                      "rows": 3, "bucket": 8}
+        assert tracks[xs["step"]["tid"]] == "spans m/0"
+        assert xs["step"]["tid"] == xs["step.plan"]["tid"] \
+            != xs["sweep"]["tid"]
+
+
 # ─────────────────────────── overhead guard (CI) ───────────────────────────
 
 
@@ -531,22 +787,31 @@ class TestOverheadGuard:
             "flag check, not work")
         assert disabled / N < 5e-6  # ~0.15µs measured; 5µs CI ceiling
 
-    def test_enabled_steady_state_is_allocation_free(self):
+    @pytest.mark.parametrize("kind", ["emit", "span"])
+    def test_enabled_steady_state_is_allocation_free(self, kind):
         """Once the ring has wrapped (every slot's fields already rebound
         under tracemalloc), further emits must not grow the heap — the
         28-byte measured delta over 8192 emits is float/int churn, not
-        growth. Bound: under half a KiB per thousand events."""
+        growth. Bound: under half a KiB per thousand events. A span's
+        handle and annotation die with it: the same bound holds."""
         tr = RequestTracer(capacity=1024)
+        counts = (1, 2, 3, 4, 5, 6, 7, 8, 9)
+        if kind == "emit":
+            def one():
+                tr.emit("req.token", "warm", arg=1.0)
+        else:
+            def one():
+                tr.end(tr.begin("step", "warm"), counts)
         tracemalloc.start()
         try:
             for _ in range(2048):   # wrap fully UNDER tracemalloc: the
-                tr.emit("req.token", "warm", arg=1.0)  # live slot values
-            before = tracemalloc.get_traced_memory()[0]  # are now traced
+                one()               # live slot values are now traced
+            before = tracemalloc.get_traced_memory()[0]
             for _ in range(8192):
-                tr.emit("req.token", "warm", arg=1.0)
+                one()
             delta = tracemalloc.get_traced_memory()[0] - before
         finally:
             tracemalloc.stop()
         assert delta < 4096, (
-            f"{delta} bytes retained over 8192 emits — the wrapped ring "
+            f"{delta} bytes retained over 8192 {kind}s — the wrapped ring "
             "must mutate slots in place, never allocate")
