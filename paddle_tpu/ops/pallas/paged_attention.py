@@ -15,12 +15,23 @@ Layout (serving/kv_cache.py owns the pool):
   is the pool's reserved null page, never allocated to a sequence)
 - ``seq_lens``     [B] int32                     — tokens written so far
 
-Kernel shape (style of ops/pallas/flash_attention.py): grid
-``(B, num_kv_heads, pages_per_seq)`` with the page axis innermost carrying
-the online-softmax state in VMEM scratch; the block table and seq lens ride
-in as SCALAR-PREFETCH operands (``pltpu.PrefetchScalarGridSpec``) so the
-k/v BlockSpec index maps can DMA exactly the pages each sequence names —
-the "ragged" part: no dense [B, max_len] gather ever materializes.
+Kernel shape: grid ``(B, pages_per_seq / P)`` — one grid step holds one
+row, EVERY kv head and a block of ``P`` pages, the block axis innermost
+carrying the online-softmax state of all heads in VMEM scratch. In the
+pool's layout a whole page, all heads, is one contiguous run (64 KB at
+GPT-3 1.3B's 16 × 16 × 128 bf16), so a page is one DMA: the block table and
+seq lens ride in as SCALAR-PREFETCH operands
+(``pltpu.PrefetchScalarGridSpec``), the pools stay in HBM, and the kernel
+copies exactly the pages each sequence names (``pltpu.make_async_copy``)
+into one of two VMEM slots, the next live block's copies in flight while
+this block computes — the "ragged" part: no dense [B, max_len] gather ever
+materializes, and a block past a row's last live page starts no copy.
+``P`` is what a fixed VMEM budget for the two slots buys at the pool's
+shapes and dtype (``_pages_per_block``: 8 pages at GPT-3 1.3B, 16 at the
+Llama trunk's 8 kv heads or at int8 pages); a table that is no multiple of
+``P`` is padded with the null page. Scores and PV are products batched over
+the head axis, operands in the pool's dtype, accumulation and softmax in
+float32.
 
 The pure-jnp fallback (``ref_paged_attention``) is the same math as the
 dense decode path (models/llama.py cached_attn): softmax in f32 over the
@@ -67,6 +78,9 @@ LANES = 128
 # live there whole, beside ~1.1 KiB the kernel itself uses. Compiled for
 # the described v5e: 2024 × 128 fits, 2040 × 128 does not.
 SMEM_PREFETCH_LIMIT_BYTES = (1 << 20) - 4096
+# VMEM for the kernel's double buffer of K and V page blocks; what it buys
+# of whole pages is a grid step's block (_pages_per_block)
+KV_BLOCK_VMEM_BYTES = 2 << 20
 
 
 def _interpret() -> bool:
@@ -120,37 +134,74 @@ def ref_paged_attention(q, k_pool, v_pool, block_tables, seq_lens,
 # ───────────────────────── pallas kernel ─────────────────────────
 
 
-def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                       scale: float, page_size: int,
+def _pages_per_block(pages_per_seq: int, nkv: int, page_size: int, hd: int,
+                     itemsize: int) -> int:
+    """Pages one grid step holds: what KV_BLOCK_VMEM_BYTES buys of whole
+    pages (every kv head) for K and V, two slots each — rounded down to a
+    power of two so the block's key axis stays lane-aligned, and never
+    wider than the table. int8 pages' scale rows (4/hd of the bytes
+    again) ride outside the budget."""
+    page_bytes = nkv * page_size * hd * itemsize
+    fit = max(1, KV_BLOCK_VMEM_BYTES // (4 * page_bytes))
+    return min(1 << (fit.bit_length() - 1), pages_per_seq)
+
+
+def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
+                       scale: float, page_size: int, pages_per_block: int,
                        quantized: bool = False):
-    """One (row b, kv head h, page j) step of online-softmax decode.
+    """One (row b, page block j) step of online-softmax decode over EVERY
+    kv head: the block's ``pages_per_block`` pages — whole pages, all heads,
+    one contiguous run of the pool each — arrive by ``make_async_copy`` in
+    one of two VMEM slots, the next live block's copies started before this
+    block's compute (across rows too, so only the call's first block waits
+    unhidden).
 
-    bt_ref/len_ref are the scalar-prefetched (flattened) block table and
-    row lens — the table already consumed by the k/v index maps; len_ref
-    masks the tail of the last live page here. q block is the head group
-    [groups, hd], k/v blocks one head's page [page, hd]; scratch carries
-    (acc, m, l) across the page axis (innermost, 'arbitrary').
+    bt_ref/len_ref are the scalar-prefetched (flattened, block-padded) table
+    and row lens. q block is every head group [nkv, groups, hd]; scratch
+    carries (acc, m, l) for all heads across the block axis (innermost,
+    'arbitrary') and the slot in use across the whole grid. A row's block 0
+    is always live (a length-0 row masks all of it), so the chain of
+    prefetches never has to search for the next row that has one.
 
-    ``quantized`` (a Python-time flag) threads two extra per-page scale
-    blocks (``ks_ref``/``vs_ref``, [nkv, page] — every head's row; this
-    head's is picked by a dynamic sublane slice) and applies the dequant
-    to the SCORE and PROBABILITY columns instead of the k/v rows: a
-    slot's scale is one number per key, so ``(q·k_int)·ks == q·(k_int·ks)``
-    — the same sums with ``groups`` instead of ``hd`` multiplies per key,
-    and the scale row stays in its lane-major [1, page] layout.
+    ``quantized`` (a Python-time flag) opens ``rest`` with the block's scale
+    pages, K's then V's ([nkv, page] each: too narrow for a hand-written
+    copy, so they come as blocks), and applies the dequant to the SCORE and
+    PROBABILITY columns instead of the k/v rows: a slot's scale is one
+    number per key, so ``(q·k_int)·ks == q·(k_int·ks)`` — the same sums with
+    ``groups`` instead of ``hd`` multiplies per key.
     """
-    if quantized:
-        ks_ref, vs_ref = rest[0], rest[1]
-        o_ref, acc_ref, m_ref, l_ref = rest[2:]
-    else:
-        ks_ref = vs_ref = None
-        o_ref, acc_ref, m_ref, l_ref = rest
+    n_scale_refs = 2 * pages_per_block if quantized else 0
+    ks_refs = rest[:pages_per_block]
+    vs_refs = rest[pages_per_block:n_scale_refs]
+    (o_ref, k_buf, v_buf, sems, slot_ref,
+     acc_ref, m_ref, l_ref) = rest[n_scale_refs:]
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
-    npages = pl.num_programs(2)
+    j = pl.program_id(1)
+    nrows = pl.num_programs(0)
+    nblocks = pl.num_programs(1)
+    block_keys = pages_per_block * page_size
 
     neg_inf = jnp.float32(NEG_INF)
+
+    def copies(hbm, buf, kv, row, blk, slot):
+        """The copies of block ``blk`` of ``row`` from K's pool (``kv`` 0) or
+        V's (1) into ``slot``, as started and as waited for."""
+        base = (row * nblocks + blk) * pages_per_block
+        return [pltpu.make_async_copy(hbm.at[bt_ref[base + p]],
+                                      buf.at[slot, _i32(p)],
+                                      sems.at[slot, _i32(kv)])
+                for p in range(pages_per_block)]
+
+    def start(row, blk, slot):
+        # K's pages first: the scores want them first
+        for cp in (copies(k_hbm, k_buf, 0, row, blk, slot)
+                   + copies(v_hbm, v_buf, 1, row, blk, slot)):
+            cp.start()
+
+    def block(page):
+        # the block's pages side by side along the key axis
+        return jnp.concatenate(
+            [page(p) for p in range(pages_per_block)], axis=1)
 
     @pl.when(j == 0)
     def _init():
@@ -158,53 +209,72 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref[...], neg_inf)
         l_ref[...] = jnp.zeros_like(l_ref[...])
 
+    @pl.when((b == 0) & (j == 0))
+    def _first_block():
+        slot_ref[0] = _i32(0)
+        start(b, j, _i32(0))
+
     seq_len = len_ref[b]
-    # ragged early-out: pages past the sequence's length are dead weight
-    # (their block-table entries are the null page) — skip the whole block
-    @pl.when(j * page_size < seq_len)
+    # ragged early-out: a block past the row's last live page does nothing
+    # and starts no copy (its table entries are the null page)
+    @pl.when((j == 0) | (j * block_keys < seq_len))
     def _body():
-        q = q_ref[...]  # [groups, hd]
-        k = k_ref[...]  # [page, hd]
-        v = v_ref[...]
+        slot = slot_ref[0]
+        slot_ref[0] = 1 - slot
+        same_row = (j + 1 < nblocks) & ((j + 1) * block_keys < seq_len)
+        next_row = jnp.where(same_row, b, b + 1)
+
+        @pl.when(next_row < nrows)
+        def _prefetch():
+            start(next_row, jnp.where(same_row, j + 1, _i32(0)), 1 - slot)
+
+        q = q_ref[...]  # [nkv, groups, hd]
+        for cp in copies(k_hbm, k_buf, 0, b, j, slot):
+            cp.wait()
+        k = block(lambda p: k_buf[slot, _i32(p)])  # [nkv, block_keys, hd]
         if quantized:  # int8 codes are exact in bf16 and f32 alike
             k = k.astype(jnp.float32).astype(q.dtype)
-            v = v.astype(jnp.float32).astype(q.dtype)
         # the package default ("highest") asks Mosaic for an fp32 contract,
         # which it refuses on bf16 operands; f32 operands keep it
         prec = (jax.lax.Precision.DEFAULT if q.dtype == jnp.bfloat16
                 else None)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), precision=prec,
+        s = jax.lax.dot_general(  # [nkv, groups, block_keys]
+            q, k, (((2,), (2,)), ((0,), (0,))), precision=prec,
             preferred_element_type=jnp.float32) * jnp.float32(scale)
         if quantized:
-            s = s * ks_ref[pl.ds(h, 1), :]
-        # mask the tail of the last live page
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (q.shape[0], page_size), 1)
+            s = s * block(lambda p: ks_refs[p][...])[:, None, :]
+        # mask the tail of the row's last live page, and the pages after it
+        pos = j * block_keys + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 2)
         mask = pos < seq_len
         s = jnp.where(mask, s, neg_inf)
 
-        m_prev = m_ref[...]  # [groups, LANES] replicated
+        m_prev = m_ref[...]  # [nkv, groups, LANES] replicated
         l_prev = l_ref[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_cur = jnp.max(s, axis=2, keepdims=True)
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, :1])
+        p = jnp.exp(s - m_new[:, :, :1])
         p = jnp.where(mask, p, 0.0)
         l_ref[...] = alpha * l_prev + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_prev.shape)
+            jnp.sum(p, axis=2, keepdims=True), l_prev.shape)
         if quantized:
-            p = p * vs_ref[pl.ds(h, 1), :]
+            p = p * block(lambda p: vs_refs[p][...])[:, None, :]
+        for cp in copies(v_hbm, v_buf, 1, b, j, slot):
+            cp.wait()
+        v = block(lambda p: v_buf[slot, _i32(p)])
+        if quantized:
+            v = v.astype(jnp.float32).astype(q.dtype)
         pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), precision=prec,
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + pv
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            precision=prec, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha[:, :, :1] + pv
         m_ref[...] = m_new
 
-    @pl.when(j == npages - 1)
+    @pl.when(j == nblocks - 1)
     def _finish():
         l_fin = jnp.maximum(l_ref[...], jnp.float32(1e-30))
-        o_ref[...] = (acc_ref[...] / l_fin[:, :1]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l_fin[:, :, :1]).astype(o_ref.dtype)
 
 
 def _paged_attention_pallas(q, k_pool, v_pool, block_tables, seq_lens,
@@ -214,7 +284,11 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, seq_lens,
     groups = nh // nkv
     pages_per_seq = block_tables.shape[1]
     quantized = k_scale is not None
-    smem_bytes = 4 * B * (pages_per_seq + 1)
+    ppb = _pages_per_block(pages_per_seq, nkv, page_size, hd,
+                           k_pool.dtype.itemsize)
+    nblocks = pl.cdiv(pages_per_seq, ppb)
+    width = nblocks * ppb
+    smem_bytes = 4 * B * (width + 1)
     if smem_bytes > SMEM_PREFETCH_LIMIT_BYTES:
         raise ValueError(
             f"paged attention: the scalar-prefetched block table "
@@ -226,49 +300,67 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, seq_lens,
     # q regrouped so each kv head's query group is one contiguous block
     qg = q.reshape(B, nkv, groups, hd)
 
-    # flat 1-D table: a 2-D SMEM array pads its minor dim to 128 words
-    bt = block_tables.astype(jnp.int32).reshape(-1)
+    # flat 1-D table: a 2-D SMEM array pads its minor dim to 128 words. A
+    # last block that overhangs the table names the null page, which the
+    # row's length masks like any page past its end
+    bt = jnp.pad(block_tables.astype(jnp.int32),
+                 ((0, 0), (0, width - pages_per_seq))).reshape(-1)
     sl = seq_lens.astype(jnp.int32)
 
-    def q_map(b, h, j, bt_ref, len_ref):
-        return (b, h, _i32(0), _i32(0))
+    def q_map(b, j, bt_ref, len_ref):
+        return (b, _i32(0), _i32(0), _i32(0))
 
-    def kv_map(b, h, j, bt_ref, len_ref):
-        return (bt_ref[b * pages_per_seq + j], h, _i32(0), _i32(0))
-
-    # the block's last two dims ARE the pool's last two (page_size, hd):
-    # the shape the TPU lowering accepts at any page_size
-    kv_spec = pl.BlockSpec((None, None, page_size, hd), kv_map)
-    q_spec = pl.BlockSpec((None, None, groups, hd), q_map)
-    in_specs = [q_spec, kv_spec, kv_spec]
+    q_spec = pl.BlockSpec((None, nkv, groups, hd), q_map)
+    # the pools stay where they are; the kernel copies the pages it names.
+    # A slot holds a block as the pool does, page by page: each copy's two
+    # ends have one shape, [nkv, page_size, hd], at any page_size and dtype
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    kv_buf = pltpu.VMEM((2, ppb, nkv, page_size, hd), k_pool.dtype)
+    in_specs = [q_spec, pool_spec, pool_spec]
     operands = [qg, k_pool, v_pool]
     if quantized:
-        # per-slot scale blocks ride the same page-indexed DMA pattern;
-        # the block spans every head's row (last two dims = the array's)
-        def sc_map(b, h, j, bt_ref, len_ref):
-            return (bt_ref[b * pages_per_seq + j], _i32(0), _i32(0))
+        # a page's scales [nkv, page_size] are narrower than a lane tile,
+        # which a hand-written copy cannot slice: they come as blocks whose
+        # last two dims ARE the array's, one per page of the block
+        def scale_map(p):
+            def index_map(b, j, bt_ref, len_ref):
+                # a block past the row's last live one names that one's
+                # pages again: an unchanged block is not copied again
+                live = jax.lax.div(jnp.maximum(len_ref[b] - 1, 0),
+                                   _i32(ppb * page_size))
+                return (bt_ref[b * width + jnp.minimum(j, live) * ppb + p],
+                        _i32(0), _i32(0))
+            return index_map
 
-        sc_spec = pl.BlockSpec((None, nkv, page_size), sc_map)
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        scale_specs = [pl.BlockSpec((None, nkv, page_size), scale_map(p))
+                       for p in range(ppb)]
+        in_specs += 2 * scale_specs
+        operands += ppb * [k_scale.astype(jnp.float32)]
+        operands += ppb * [v_scale.astype(jnp.float32)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, seq_lens
-        grid=(B, nkv, pages_per_seq),
+        grid=(B, nblocks),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((groups, hd), jnp.float32),
-            pltpu.VMEM((groups, LANES), jnp.float32),
-            pltpu.VMEM((groups, LANES), jnp.float32),
+            kv_buf, kv_buf,
+            pltpu.SemaphoreType.DMA((2, 2)),  # [slot, K | V]
+            pltpu.SMEM((1,), jnp.int32),      # the slot in use
+            pltpu.VMEM((nkv, groups, hd), jnp.float32),
+            pltpu.VMEM((nkv, groups, LANES), jnp.float32),
+            pltpu.VMEM((nkv, groups, LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, scale=scale,
-                          page_size=page_size, quantized=quantized),
+                          page_size=page_size, pages_per_block=ppb,
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nkv, groups, hd), q.dtype),
+        # a block's copies are started by the block before it, in grid order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
         name="paged_attention",
     )(bt, sl, *operands)
@@ -320,7 +412,7 @@ def ragged_paged_attention(q, k_pool, v_pool, row_block_tables, row_lens,
     row into the pool (the unified step writes first, attends second —
     the decode step's own idiom, generalized). Each row then reduces
     over its named pages exactly like a decode query, so the kernel grid
-    (``(T, kv_heads, pages)``, scalar-prefetched tables, online-softmax
+    (``(T, page blocks)``, scalar-prefetched tables, online-softmax
     scratch) serves the ragged batch unchanged — per-row early-out over
     ``row_lens`` is what keeps a 1-token decode row from paying a long
     prompt's page walk."""

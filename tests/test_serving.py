@@ -91,6 +91,69 @@ class TestPagedAttentionKernel:
                                    np.asarray(ref, np.float32),
                                    atol=tol, rtol=tol)
 
+    # what the (rows, page blocks) grid can get wrong. Each case is the
+    # rows' lengths over a table `width` pages wide (page 8), with the
+    # block budget cut so that a grid step holds `ppb` pages
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["pages-as-q", "int8"])
+    @pytest.mark.parametrize("nh,nkv", [(4, 2), (4, 4)], ids=["gqa", "mha"])
+    @pytest.mark.parametrize("ppb,width,lens", [
+        (4, 7, [56, 29, 3]),      # table wider than a block, not a multiple
+        (2, 7, [9, 56, 17]),      # three and a half blocks
+        (2, 8, [8, 16, 32, 64]),  # ends on a page and on a block boundary
+        (4, 8, [1, 64]),          # length 1 beside a row that fills its table
+        (1, 5, [40, 1, 24]),      # one page a block: the page-at-a-time walk
+        (8, 3, [24, 10]),         # the budget buys more than the table holds
+    ], ids=["ragged-blocks", "half-block", "boundaries", "one-and-full",
+            "page-blocks", "one-block"])
+    def test_kernel_matches_fallback_over_page_blocks(
+            self, monkeypatch, ppb, width, lens, nh, nkv, quantized):
+        monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        from paddle_tpu.quantization.observers import quantize_kv
+
+        rng = np.random.default_rng(3)
+        B, hd, page, pages = len(lens), 64, 8, 24
+        kp = jnp.asarray(rng.standard_normal((pages, nkv, page, hd)),
+                         jnp.float32)
+        vp = jnp.asarray(rng.standard_normal((pages, nkv, page, hd)),
+                         jnp.float32)
+        kw = {}
+        if quantized:
+            kp, ks = quantize_kv(kp)
+            vp, vs = quantize_kv(vp)
+            kw = dict(k_scale=ks, v_scale=vs)
+        # the budget of `ppb` pages: two slots each for K and for V
+        monkeypatch.setattr(pa, "KV_BLOCK_VMEM_BYTES",
+                            4 * ppb * nkv * page * hd * kp.dtype.itemsize)
+        assert pa._pages_per_block(width, nkv, page, hd,
+                                   kp.dtype.itemsize) == min(ppb, width)
+        q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
+        # pages past a row's length are the null page, as the engine pads
+        bt = rng.integers(1, pages, (B, width)).astype(np.int32)
+        for r, n in enumerate(lens):
+            bt[r, -(-n // page):] = 0
+        bt, sl = jnp.asarray(bt), jnp.asarray(lens, jnp.int32)
+        ref = pa.ref_paged_attention(q, kp, vp, bt, sl, **kw)
+        out = pa.paged_attention(q, kp, vp, bt, sl, use_kernel=True, **kw)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("shape,ppb", [
+        ((128, 16, 16, 128, 2), 8),   # GPT-3 1.3B, bf16 pages: 64 KB each
+        ((128, 8, 16, 128, 2), 16),   # the Llama trunk's 8 kv heads
+        ((128, 16, 16, 128, 1), 16),  # int8 pages
+        ((128, 32, 16, 128, 4), 2),   # f32 pages of 32 heads
+        ((128, 64, 32, 256, 4), 1),   # a page over the budget: still one
+        ((4, 16, 16, 128, 2), 4),     # never wider than the table
+    ], ids=["gpt3-1.3b", "gqa-8", "int8", "f32-32", "huge-page", "narrow"])
+    def test_pages_per_block_follows_the_shapes(self, shape, ppb):
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        assert pa._pages_per_block(*shape) == ppb
+
     def test_block_table_over_smem_is_an_error_with_numbers(self):
         """The scalar-prefetched table must fit the chip's SMEM whole; a
         grid that cannot is refused at trace time with the sizes named,
@@ -114,7 +177,9 @@ class TestPagedAttentionKernel:
         assert str(4 * T * (pages + 1)) in msg
         assert str(pa.SMEM_PREFETCH_LIMIT_BYTES) in msg
 
-    def test_ragged_flattened_rows_match_fallback(self, monkeypatch):
+    @pytest.mark.parametrize("ppb", [None, 1, 2],
+                             ids=["one-block", "page-blocks", "two-pages"])
+    def test_ragged_flattened_rows_match_fallback(self, monkeypatch, ppb):
         """The unified-step contract (ISSUE 11): mixed per-slot query
         lengths ride as FLATTENED rows — a decode slot contributes one
         row, a chunk slot one row per token, each with its slot's block
@@ -128,6 +193,10 @@ class TestPagedAttentionKernel:
 
         rng = np.random.default_rng(1)
         nh, nkv, hd, page, pages, width = 4, 2, 64, 8, 20, 4
+        if ppb is not None:  # a chunk's rows name the same pages, block
+            # after block, each masked at its own length
+            monkeypatch.setattr(pa, "KV_BLOCK_VMEM_BYTES",
+                                4 * ppb * nkv * page * hd * 4)
         # slot A: decode (q_len 1 at pos 12); slot B: a 5-token chunk at
         # positions 7..11; slot C: decode at pos 0 (first decode step)
         q_lens = [1, 5, 1]
