@@ -33,6 +33,7 @@ import argparse
 import gc
 import json
 import os
+import re
 import statistics
 import sys
 import time
@@ -322,6 +323,36 @@ def serve_phase(model, *, kv_pool_bytes: int, page_size: int,
     _check(has_kernel == on_tpu,
            f"serve: Pallas ragged kernel in every step program = "
            f"{has_kernel}, expected {on_tpu}")
+    # the step updates the pool where it lies: every pool parameter of every
+    # bucket aliases its own output (`nxt, fin, k0', v0', ...`); on the chip
+    # the compiled program holds no copy of a pool-shaped array and updates
+    # at least the pool's bytes in place. PADDLE_TPU_NO_DONATE=1 is the
+    # bisect axis that turns all of it off.
+    donating = os.environ.get("PADDLE_TPU_NO_DONATE") != "1"
+    pool = engine.pool
+    n_pool = pool.step_stride * pool.num_layers
+    want_alias = [f"tf.aliasing_output = {2 + i} : i32" for i in range(n_pool)]
+    for t in texts:
+        sig = t[t.index("func.func public @main("):].split(") -> (", 1)[0]
+        params = re.split(r", (?=%arg\d+:)", sig)[-n_pool:]
+        aliases = all(w in p for w, p in zip(want_alias, params))
+        _check(aliases == donating,
+               f"serve: every pool parameter of the step aliases its own "
+               f"output = {aliases}, expected {donating}")
+    if on_tpu and donating:
+        pool_bytes = sum(t._value.nbytes for li in range(pool.num_layers)
+                         for t in pool.step_arrays(li))
+        aliased = engine.step_aliased_bytes()
+        _check(all(a is not None and a >= pool_bytes for a in aliased),
+               f"serve: step buckets alias {aliased} bytes, the pool holds "
+               f"{pool_bytes}")
+        dims = ",".join(str(d) for d in pool.k_pools[0].shape)
+        pool_copy = re.compile(r"= \w+\[%s\]\S* copy\(" % re.escape(dims))
+        n_copies = [len(pool_copy.findall(t))
+                    for t in engine.step_program_texts(compiled=True)]
+        _check(not any(n_copies),
+               f"serve: pool-shaped copies per compiled step bucket: "
+               f"{n_copies}")
     _check(engine.pool.used_pages == 0,
            f"serve: {engine.pool.used_pages} pool pages leaked")
 

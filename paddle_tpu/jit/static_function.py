@@ -355,6 +355,19 @@ def _rebuild_out(spec, arrays):
     raise AssertionError(kind)
 
 
+def _donation_off() -> bool:
+    return os.environ.get("PADDLE_TPU_NO_DONATE") == "1"
+
+
+def _aliased_bytes(executable) -> int:
+    """Bytes of argument buffers a compiled executable writes its outputs
+    into; 0 where the backend has no memory analysis."""
+    try:
+        return int(executable.memory_analysis().alias_size_in_bytes)
+    except Exception:
+        return 0
+
+
 def _buffer_ptr(v):
     try:
         return v.unsafe_buffer_pointer()
@@ -362,31 +375,53 @@ def _buffer_ptr(v):
         return id(v)
 
 
-def _unalias(state_vals, protected):
-    """State buffers are donated to the compiled step; XLA rejects a donated
-    buffer that aliases another argument (e.g. two accumulators both produced
-    by one CSE'd zeros_like, or a Parameter also passed as a data input).
-    Copy any such duplicate so every donated buffer is unique."""
-    seen = {_buffer_ptr(v) for v in protected}
-    out = []
-    for v in state_vals:
+def _leaf_indices(spec):
+    """Positions in the flat array list of every traced leaf under ``spec``
+    (a node of :func:`_flatten_args`' structure)."""
+    kind, payload = spec
+    if kind in ("T", "A"):
+        return [payload]
+    if kind in ("list", "tuple"):
+        return [i for s in payload for i in _leaf_indices(s)]
+    if kind == "dict":
+        return [i for _, s in payload for i in _leaf_indices(s)]
+    return []
+
+
+def _unalias(state_vals, arrays, given=()):
+    """State buffers, and the argument leaves ``given`` (indices into
+    ``arrays``) that the caller gives up, are donated to the compiled step;
+    XLA rejects a donated buffer that aliases another argument (e.g. two
+    accumulators both produced by one CSE'd zeros_like, a Parameter also
+    passed as a data input, one array passed both given-up and kept). Copy
+    any such duplicate so every donated buffer is unique. Returns the state
+    values and the argument list, with copies in the duplicates' places."""
+    given = set(given)
+    seen = {_buffer_ptr(v) for i, v in enumerate(arrays) if i not in given}
+
+    def unique(v):
         ptr = _buffer_ptr(v)
         if ptr in seen:
-            v = jnp.array(v, copy=True)
-        else:
-            seen.add(ptr)
-        out.append(v)
-    return out
+            return jnp.array(v, copy=True)
+        seen.add(ptr)
+        return v
+
+    state_vals = [unique(v) for v in state_vals]
+    if given:
+        arrays = [unique(v) if i in given else v
+                  for i, v in enumerate(arrays)]
+    return state_vals, arrays
 
 
 # -------------------------------------------------- persistent compile cache
 # Executable reuse across processes (and across StaticFunction instances in
 # one process): `_build` consults a process-wide memory layer, then an
 # on-disk layer of serialized XLA executables, before paying a fresh trace +
-# XLA compile. Fully disabled unless a cache directory is configured — via
-# the StaticFunction ``cache_dir=`` ctor arg, :func:`set_compile_cache_dir`,
-# or the ``PADDLE_TPU_COMPILE_CACHE`` env var — so default behavior (and the
-# jax.jit execution path) is untouched. Every materialization increments
+# XLA compile. Both layers are off unless a cache directory is configured —
+# via the StaticFunction ``cache_dir=`` ctor arg, :func:`set_compile_cache_dir`,
+# or the ``PADDLE_TPU_COMPILE_CACHE`` env var; the fresh build compiles ahead
+# of time either way, so `_build` has the executable in hand (its memory
+# analysis is published there). Every materialization increments
 # paddle_tpu_jit_compiles_total{fn, source="memory|disk|fresh"} exactly
 # once: the per-fn SUM keeps the old one-inc-per-build meaning, while the
 # source split makes warm restarts and rolling reloads monitorable
@@ -514,15 +549,17 @@ def _store_disk_entry(path: str, full_key: str, aot, out_spec) -> None:
 
 # ------------------------------------------------------------ StaticFunction
 class _Compiled:
-    __slots__ = ("jitted", "out_spec", "aot")
+    __slots__ = ("jitted", "out_spec", "aot", "given")
 
-    def __init__(self, jitted, out_spec=None, aot=None):
+    def __init__(self, jitted, out_spec=None, aot=None, given=()):
         self.jitted = jitted
         self.out_spec = out_spec
-        # AOT executable (persistent-cache path): used for calls when
-        # set; `jitted` stays alive regardless so cost_analysis/lower
-        # keep working on disk-cache hits
+        # the executable `_build` compiled (or the persistent cache
+        # held): calls run it; `jitted` stays alive regardless, for
+        # cost_analysis/lower and as the path a call degrades to
         self.aot = aot
+        # flat argument leaves this program consumes (donate_argnums)
+        self.given = given
 
 
 class StaticFunction:
@@ -534,7 +571,8 @@ class StaticFunction:
                  property=False, full_graph=True, observe: Sequence[Any] = (),
                  warmup: bool = True, dy2static: bool = True,
                  cache_dir: Optional[str] = None,
-                 cache_key_extra: Optional[str] = None):
+                 cache_key_extra: Optional[str] = None,
+                 donate_argnums: Sequence[int] = ()):
         if dy2static and os.environ.get("PADDLE_TPU_DY2STATIC") != "0":
             # AST pass rewriting Python if/while on tensor values into
             # static.nn control flow (jit/dy2static.py — reference:
@@ -564,20 +602,31 @@ class StaticFunction:
         self._cache_dir = None if cache_dir is None else str(cache_dir)
         self._cache_key_extra = ("" if cache_key_extra is None
                                  else str(cache_key_extra))
+        # positional arguments of `function` whose arrays the caller gives
+        # up: the compiled program consumes their buffers (XLA writes its
+        # outputs into them), and after a call the caller's references
+        # are deleted arrays. Ownership is a fact of the call site, so it
+        # is declared where the StaticFunction is constructed.
+        self._donate_argnums = tuple(sorted({int(i) for i in donate_argnums}))
         self.__name__ = getattr(function, "__name__", "static_fn")
         self.__doc__ = getattr(function, "__doc__", None)
 
     # -- introspection -------------------------------------------------------
+    def _latest_key(self):
+        return next(reversed(self._abstract_args), None)
+
+    def _compiled(self, key=None) -> Optional[_Compiled]:
+        """The program of a called signature (``key=None``: the most
+        recent call's)."""
+        return self._cache.get(self._latest_key() if key is None else key)
+
     def _lowered(self, key=None):
         """``jax.stages.Lowered`` of a compiled signature from its recorded
         abstract arguments (``key=None``: the most recent); None before
         any call compiled. Lowering may re-trace the function, which
         leaves tracers in the state slots — they are put back."""
-        if not self._cache:
-            return None
         if key is None:
-            key = next(reversed(self._abstract_args)) \
-                if self._abstract_args else None
+            key = self._latest_key()
         compiled = self._cache.get(key)
         abstract = self._abstract_args.get(key)
         if compiled is None or abstract is None:
@@ -611,10 +660,23 @@ class StaticFunction:
         kernel shows in both as ``tpu_custom_call`` — chip_smoke.py
         asserts on that instead of trusting the dispatch. None before any
         call compiled."""
+        built = self._compiled(key)
+        if compiled and built is not None and built.aot is not None:
+            return built.aot.as_text()  # the executable the calls run
         lowered = self._lowered(key)
         if lowered is None:
             return None
         return lowered.compile().as_text() if compiled else lowered.as_text()
+
+    def aliased_bytes(self, key=None) -> Optional[int]:
+        """Bytes of argument buffers that a compiled signature's executable
+        writes its outputs into (``key=None``: the most recent) — what the
+        ``paddle_tpu_jit_aliased_bytes`` gauge read when it was built. None
+        before any call compiled, or where the call runs ``jitted``."""
+        compiled = self._compiled(key)
+        if compiled is None or compiled.aot is None:
+            return None
+        return _aliased_bytes(compiled.aot)
 
     def lower(self, *args, **kwargs):
         """AOT trace + lower WITHOUT executing (reference counterpart: the
@@ -636,19 +698,8 @@ class StaticFunction:
                     "the function once first, or construct with "
                     "warmup=False and list state in observe=")
             self._setup_no_warmup()
-        arrays, meta, spec = _flatten_args((args, kwargs))
-        key = (
-            _spec_key(spec, arrays, meta),
-            tuple(l.training for l in self._layers),
-        )
-        state_vals = _unalias([s.get() for s in self._slots], arrays)
-        lr_vals = [jnp.asarray(o.get_lr(), jnp.float32) for o in self._opts]
-        compiled = self._cache.get(key)
-        if compiled is None:
-            compiled = self._build(spec, tuple(meta), key,
-                                   (state_vals, lr_vals, list(arrays)))
-            self._cache[key] = compiled
-        return compiled.jitted.lower(state_vals, lr_vals, list(arrays))
+        _, compiled, operands = self._prepare(args, kwargs, compile=False)
+        return compiled.jitted.lower(*operands)
 
     # -- paddle API surface --------------------------------------------------
     @property
@@ -694,14 +745,14 @@ class StaticFunction:
         return (self._cache_dir if self._cache_dir is not None
                 else get_compile_cache_dir())
 
-    def _persistent_key(self, key, example) -> str:
+    def _persistent_key(self, key, example, given) -> str:
         """The FULL persistent-cache key, as a stable string: everything
         that shapes the executable's bytes or its calling convention.
         Signature key (shapes/dtypes/weak_type of args, training flags),
         state/lr avals, the function's code fingerprint and caller-
         supplied extra, the donation policy, and the jax + device
         fingerprint (a different jaxlib or device kind must miss)."""
-        state_vals, lr_vals, arrays = example
+        state_vals, lr_vals = example[:2]
         dev = jax.devices()[0]
         state_avals = tuple((tuple(v.shape), str(v.dtype),
                              bool(getattr(v, "weak_type", False)))
@@ -709,12 +760,21 @@ class StaticFunction:
         return repr((
             self.__name__, _code_fingerprint(self._fn),
             self._cache_key_extra, key, state_avals, len(lr_vals),
-            os.environ.get("PADDLE_TPU_NO_DONATE") == "1",
+            _donation_off(), given,
             jax.__version__, jax.lib.__version__,
             dev.platform, dev.device_kind,
         ))
 
-    def _build(self, spec, meta, key=None, example=None):
+    def _given_up(self, spec) -> tuple:
+        """Flat leaf indices of the positional arguments named in
+        ``donate_argnums``; nothing under ``PADDLE_TPU_NO_DONATE=1``."""
+        if not self._donate_argnums or _donation_off():
+            return ()
+        positional = spec[1][0][1]  # spec of (args, kwargs) -> args' specs
+        return tuple(i for n in self._donate_argnums
+                     for i in _leaf_indices(positional[n]))
+
+    def _build(self, spec, meta, key=None, example=None, given=()):
         # every signature-cache miss materializes ONE program, counted
         # exactly once with its source: "fresh" paid a trace + XLA
         # compile, "disk" deserialized a persisted executable (warm
@@ -724,17 +784,23 @@ class StaticFunction:
         # "decode compiles exactly once" invariant stays a monitorable
         # metric (paddle_tpu_jit_compiles_total{fn,source}), and a
         # recompile storm shows up on /metrics before it shows up as a
-        # latency cliff
+        # latency cliff. ``example`` (the first call's operands) is what
+        # the executable is compiled for; without it (``lower()``) nothing
+        # compiles here and a call runs ``jitted``.
         from ..metrics import get_registry
 
         slots, opts, fn = self._slots, self._opts, self._fn
-        holder = _Compiled(None)
+        holder = _Compiled(None, given=given)
 
-        def _functional(state_vals, lr_vals, arg_arrays):
+        def _functional(state_vals, lr_vals, arg_arrays, given_arrays):
             for slot, v in zip(slots, state_vals):
                 slot.set(v)
             for opt, lr in zip(opts, lr_vals):
                 opt._lr_override = lr
+            if given:
+                arg_arrays = list(arg_arrays)
+                for i, v in zip(given, given_arrays):
+                    arg_arrays[i] = v
             try:
                 args, kwargs = _rebuild_args(spec, arg_arrays, meta)
                 out = fn(*args, **kwargs)
@@ -747,47 +813,63 @@ class StaticFunction:
             return out_arrays, new_state
 
         # State buffers are donated so XLA reuses them for the updated state
-        # (in-place optimizer semantics, reference: inplace op pass). CPU
-        # silently ignores donation, so a donation-induced wrongness would be
-        # TPU-only — PADDLE_TPU_NO_DONATE=1 disables it as a bisect axis.
-        donate = () if os.environ.get("PADDLE_TPU_NO_DONATE") == "1" else (0,)
+        # (in-place optimizer semantics, reference: inplace op pass), and so
+        # are the argument leaves the constructor's caller gave up
+        # (``given_arrays``; empty unless declared). A donation-induced
+        # wrongness would be TPU-only in effect — PADDLE_TPU_NO_DONATE=1
+        # disables both as a bisect axis.
+        donate = () if _donation_off() else (0, 3)
         holder.jitted = jax.jit(_functional, donate_argnums=donate)
         source = "fresh"
-        cache_dir = self._resolve_cache_dir()
-        if cache_dir is not None and example is not None:
-            full_key = self._persistent_key(key, example)
-            path = os.path.join(
-                cache_dir,
-                f"{self.__name__}-"
-                f"{hashlib.sha256(full_key.encode()).hexdigest()[:32]}"
-                ".jitcache")
-            ent = _MEMORY_CACHE.get(full_key)
+        registry = get_registry()
+        if example is not None:
+            cache_dir = self._resolve_cache_dir()
+            full_key = path = ent = None
+            if cache_dir is not None:
+                full_key = self._persistent_key(key, example, given)
+                path = os.path.join(
+                    cache_dir,
+                    f"{self.__name__}-"
+                    f"{hashlib.sha256(full_key.encode()).hexdigest()[:32]}"
+                    ".jitcache")
+                ent = _MEMORY_CACHE.get(full_key)
+                if ent is not None:
+                    source = "memory"
+                else:
+                    ent = _load_disk_entry(path, full_key)
+                    if ent is not None:
+                        _MEMORY_CACHE[full_key] = ent
+                        source = "disk"
             if ent is not None:
                 holder.aot, holder.out_spec = ent
-                source = "memory"
             else:
-                ent = _load_disk_entry(path, full_key)
-                if ent is not None:
-                    holder.aot, holder.out_spec = ent
-                    _MEMORY_CACHE[full_key] = ent
-                    source = "disk"
-                else:
-                    try:
-                        # AOT build so the executable is serializable;
-                        # the trace fires _functional, which captures
-                        # out_spec on `holder` as a side effect
-                        lowered = holder.jitted.lower(*example)
-                        holder.aot = lowered.compile()
-                        _MEMORY_CACHE[full_key] = (holder.aot,
-                                                   holder.out_spec)
-                        _store_disk_entry(path, full_key, holder.aot,
-                                          holder.out_spec)
-                    except Exception:
-                        # an unlowerable corner falls back to the plain
-                        # jax.jit path — correctness never depends on
-                        # the cache
-                        holder.aot = None
-        get_registry().counter(
+                try:
+                    # the trace fires _functional, which captures
+                    # out_spec on `holder` as a side effect
+                    holder.aot = holder.jitted.lower(*example).compile()
+                except Exception:
+                    # an unlowerable corner falls back to the plain
+                    # jax.jit path — correctness never depends on the
+                    # ahead-of-time build
+                    holder.aot = None
+                if holder.aot is not None and full_key is not None:
+                    _MEMORY_CACHE[full_key] = (holder.aot, holder.out_spec)
+                    _store_disk_entry(path, full_key, holder.aot,
+                                      holder.out_spec)
+            if holder.aot is not None:
+                # what donation bought, as the executable has it: bytes of
+                # arguments whose buffers the outputs are written into. A
+                # step that should update a pool in place reads the pool's
+                # bytes here; 0 means every donated buffer was copied.
+                registry.gauge(
+                    "paddle_tpu_jit_aliased_bytes",
+                    "Bytes of argument buffers that the StaticFunction "
+                    "program built last for this fn writes its outputs "
+                    "into (XLA memory analysis): donated state and "
+                    "donate_argnums leaves updated in place",
+                    labels=("fn",),
+                ).labels(fn=self.__name__).set(float(_aliased_bytes(holder.aot)))
+        registry.counter(
             "paddle_tpu_jit_compiles_total",
             "XLA programs materialized into a StaticFunction signature "
             "cache, by source: \"fresh\" paid an XLA compile, \"disk\" "
@@ -814,24 +896,38 @@ class StaticFunction:
         self._slot_ids = slot_ids
         self._warmed_up = True
 
+    def _prepare(self, args, kwargs, compile=True):
+        """One call's signature key, its program (built on a miss; compiled
+        too unless ``compile=False``) and the operands of ``_functional``:
+        state, learning rates, the argument leaves with None where a leaf
+        is given up, and the given-up leaves."""
+        arrays, meta, spec = _flatten_args((args, kwargs))
+        key = (
+            _spec_key(spec, arrays, meta),
+            tuple(l.training for l in self._layers),
+        )
+        compiled = self._cache.get(key)
+        given = self._given_up(spec) if compiled is None else compiled.given
+        state_vals, arrays = _unalias([s.get() for s in self._slots],
+                                      arrays, given)
+        lr_vals = [jnp.asarray(o.get_lr(), jnp.float32) for o in self._opts]
+        kept = list(arrays)
+        for i in given:
+            kept[i] = None
+        operands = (state_vals, lr_vals, kept, [arrays[i] for i in given])
+        if compiled is None:
+            compiled = self._build(spec, tuple(meta), key,
+                                   operands if compile else None, given)
+            self._cache[key] = compiled
+        return key, compiled, operands
+
     def __call__(self, *args, **kwargs):
         if not self._warmed_up:
             if not self._do_warmup:
                 self._setup_no_warmup()
             else:
                 return self._warmup(args, kwargs)
-        arrays, meta, spec = _flatten_args((args, kwargs))
-        key = (
-            _spec_key(spec, arrays, meta),
-            tuple(l.training for l in self._layers),
-        )
-        state_vals = _unalias([s.get() for s in self._slots], arrays)
-        lr_vals = [jnp.asarray(o.get_lr(), jnp.float32) for o in self._opts]
-        compiled = self._cache.get(key)
-        if compiled is None:
-            compiled = self._build(spec, tuple(meta), key,
-                                   (state_vals, lr_vals, list(arrays)))
-            self._cache[key] = compiled
+        key, compiled, operands = self._prepare(args, kwargs)
         self._abstract_args.pop(key, None)  # move-to-end: dict order = recency
         # mesh shardings are part of the program (a re-lowering without
         # them is another program); single-device placement is not
@@ -840,22 +936,20 @@ class StaticFunction:
                 a.shape, a.dtype,
                 sharding=(a.sharding if isinstance(
                     getattr(a, "sharding", None), NamedSharding) else None)),
-            (state_vals, lr_vals, list(arrays)))
+            operands)
         if compiled.aot is not None:
             try:
-                out_arrays, new_state = compiled.aot(
-                    state_vals, lr_vals, arrays)
+                out_arrays, new_state = compiled.aot(*operands)
             except Exception:
-                # an AOT calling-convention mismatch (aval drift the key
-                # missed) degrades to the jax.jit path for good — the
-                # signature check fails BEFORE execution, so the donated
-                # buffers are still intact for the retry
+                # a calling-convention mismatch (aval drift the key
+                # missed, a sharding the executable was not compiled for)
+                # degrades to the jax.jit path for good — the signature
+                # check fails BEFORE execution, so the donated buffers
+                # are still intact for the retry
                 compiled.aot = None
-                out_arrays, new_state = compiled.jitted(
-                    state_vals, lr_vals, arrays)
+                out_arrays, new_state = compiled.jitted(*operands)
         else:
-            out_arrays, new_state = compiled.jitted(
-                state_vals, lr_vals, arrays)
+            out_arrays, new_state = compiled.jitted(*operands)
         for slot, v in zip(self._slots, new_state):
             slot.set(v)
             slot.sanitize()

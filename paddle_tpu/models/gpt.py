@@ -233,7 +233,7 @@ class GPTAttention(nn.Layer):
         ``(k, v, k_scale, v_scale)`` — a static Python branch, not a new
         program."""
         from ..ops.pallas.paged_attention import ragged_paged_attention
-        from ..quantization.observers import quantize_kv
+        from ..serving.kv_cache import write_step_kv
 
         B = x.shape[0]
         nh, hd = self.cfg.num_heads, self.head_dim
@@ -248,31 +248,17 @@ class GPTAttention(nn.Layer):
         def paged_step(qkv_v, kp, vp, bt, pos, *scales):
             pos = pos.astype(jnp.int32).reshape(B)
             bt = bt.astype(jnp.int32)
-            page_size = kp.shape[2]
             qv, kv, vv = jnp.split(qkv_v, 3, axis=-1)
             nh_l = qv.shape[-1] // hd
             qh = qv.reshape(B, nh_l, hd)
             kh = kv.reshape(B, nh_l, hd)
             vh = vv.reshape(B, nh_l, hd)
-            page_ids = bt[jnp.arange(B), pos // page_size]
-            offs = pos % page_size
-            if scales:
-                ks, vs = scales
-                kq, ksc = quantize_kv(kh)
-                vq, vsc = quantize_kv(vh)
-                kp = kp.at[page_ids, :, offs].set(kq)
-                vp = vp.at[page_ids, :, offs].set(vq)
-                ks = ks.at[page_ids, :, offs].set(ksc)
-                vs = vs.at[page_ids, :, offs].set(vsc)
-                ctx = ragged_paged_attention(qh, kp, vp, bt, pos + 1,
-                                             scale=scale, k_scale=ks,
-                                             v_scale=vs)
-                return ctx.reshape(B, 1, nh_l * hd), kp, vp, ks, vs
-            kp = kp.at[page_ids, :, offs].set(kh.astype(kp.dtype))
-            vp = vp.at[page_ids, :, offs].set(vh.astype(vp.dtype))
-            ctx = ragged_paged_attention(qh, kp, vp, bt, pos + 1,
-                                         scale=scale)
-            return ctx.reshape(B, 1, nh_l * hd), kp, vp
+            cache = write_step_kv((kp, vp, *scales), kh, vh, bt, pos)
+            k_sc, v_sc = cache[2:] if scales else (None, None)
+            ctx = ragged_paged_attention(qh, cache[0], cache[1], bt, pos + 1,
+                                         scale=scale, k_scale=k_sc,
+                                         v_scale=v_sc)
+            return (ctx.reshape(B, 1, nh_l * hd), *cache)
 
         operands = [ensure_tensor(qkv), ensure_tensor(k_pool),
                     ensure_tensor(v_pool), ensure_tensor(block_tables),

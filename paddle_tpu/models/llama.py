@@ -194,7 +194,7 @@ class LlamaAttention(nn.Layer):
         extra operands, never a new program.
         """
         from ..ops.pallas.paged_attention import ragged_paged_attention
-        from ..quantization.observers import quantize_kv
+        from ..serving.kv_cache import write_step_kv
 
         B = x.shape[0]
         cfg = self.cfg
@@ -216,7 +216,6 @@ class LlamaAttention(nn.Layer):
         def paged_step(qv, kv, vv, kp, vp, bt, pos, *scales):
             pos = pos.astype(jnp.int32).reshape(B)
             bt = bt.astype(jnp.int32)
-            page_size = kp.shape[2]
             nh_l = qv.shape[-1] // hd
             nkv_l = kv.shape[-1] // hd
             qh = qv.reshape(B, nh_l, hd)
@@ -234,28 +233,12 @@ class LlamaAttention(nn.Layer):
 
             qh = rope_rows(qh)
             kh = rope_rows(kh)
-            # KV write hook: page = block_table[pos // page_size], slot =
-            # pos % page_size. Inactive slots carry all-zero block tables,
-            # landing their writes on the pool's reserved null page 0.
-            page_ids = bt[jnp.arange(B), pos // page_size]
-            offs = pos % page_size
-            if scales:
-                ks, vs = scales
-                kq, ksc = quantize_kv(kh)
-                vq, vsc = quantize_kv(vh)
-                kp = kp.at[page_ids, :, offs].set(kq)
-                vp = vp.at[page_ids, :, offs].set(vq)
-                ks = ks.at[page_ids, :, offs].set(ksc)
-                vs = vs.at[page_ids, :, offs].set(vsc)
-                ctx = ragged_paged_attention(qh, kp, vp, bt, pos + 1,
-                                             scale=scale, k_scale=ks,
-                                             v_scale=vs)
-                return ctx.reshape(B, 1, nh_l * hd), kp, vp, ks, vs
-            kp = kp.at[page_ids, :, offs].set(kh.astype(kp.dtype))
-            vp = vp.at[page_ids, :, offs].set(vh.astype(vp.dtype))
-            ctx = ragged_paged_attention(qh, kp, vp, bt, pos + 1,
-                                         scale=scale)
-            return ctx.reshape(B, 1, nh_l * hd), kp, vp
+            cache = write_step_kv((kp, vp, *scales), kh, vh, bt, pos)
+            k_sc, v_sc = cache[2:] if scales else (None, None)
+            ctx = ragged_paged_attention(qh, cache[0], cache[1], bt, pos + 1,
+                                         scale=scale, k_scale=k_sc,
+                                         v_scale=v_sc)
+            return (ctx.reshape(B, 1, nh_l * hd), *cache)
 
         operands = [ensure_tensor(q), ensure_tensor(k), ensure_tensor(v),
                     ensure_tensor(k_pool), ensure_tensor(v_pool),

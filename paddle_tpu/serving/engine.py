@@ -983,14 +983,26 @@ class ServingEngine:
         n = len(self._step_prog._cache) if self._step_prog else 0
         return {"step": n, "step_buckets": len(self._grid_buckets_seen)}
 
-    def step_program_texts(self) -> List[str]:
-        """Lowered (StableHLO) text of every compiled step bucket — read
-        by chip_smoke.py to assert that the Pallas ragged kernel
+    def step_program_texts(self, compiled: bool = False) -> List[str]:
+        """Text of every compiled step bucket — read by chip_smoke.py. The
+        lowered (StableHLO) text shows that the Pallas ragged kernel
         (``tpu_custom_call``), not the gather fallback, is what the step
-        program holds."""
+        program holds, and which parameters alias which outputs;
+        ``compiled=True`` is the HLO after XLA's passes, where a
+        pool-shaped ``copy`` would show (it costs a compile per bucket
+        unless JAX's persistent cache holds them)."""
         if self._step_prog is None:
             return []
-        return [self._step_prog.program_text(k)
+        return [self._step_prog.program_text(k, compiled=compiled)
+                for k in self._step_prog._cache]
+
+    def step_aliased_bytes(self) -> List[Optional[int]]:
+        """Per compiled step bucket, the bytes of argument buffers its
+        executable updates in place: at least the pool's, or the step
+        copies a pool array it was given to consume."""
+        if self._step_prog is None:
+            return []
+        return [self._step_prog.aliased_bytes(k)
                 for k in self._step_prog._cache]
 
     # ---------------------------------------------------------------- step
@@ -1644,10 +1656,18 @@ class ServingEngine:
             self.page_size, self.pages_per_seq, self._spec_rows,
             self.adapters.capacity, self.adapters.rank,
             self._grammar_cap, str(jnp.dtype(self.pool.dtype))))
-        return jit.StaticFunction(step_fn, observe=[self.model],
-                                  warmup=False, dy2static=False,
-                                  cache_dir=self._compile_cache_dir,
-                                  cache_key_extra=extra)
+        # the step CONSUMES the pool: its arrays (the trailing positional
+        # arguments, after the ten grids and the adapter stacks) are given
+        # up to the program, which writes this step's rows into the same
+        # buffers and hands them back as `flat` — `set_step_flat` swaps
+        # them in, and what the pool held before the call is deleted.
+        # jax pairs a donated input with the first output of equal aval
+        # in order, which `flat` (k0, v0, k1, ...) keeps.
+        first_pool = 10 + n_adp
+        return jit.StaticFunction(
+            step_fn, observe=[self.model], warmup=False, dy2static=False,
+            cache_dir=self._compile_cache_dir, cache_key_extra=extra,
+            donate_argnums=range(first_pool, first_pool + stride * n_layers))
 
     def _step_once(self) -> List[RequestOutput]:
         t0 = time.perf_counter()
