@@ -31,6 +31,8 @@ from ..nn import functional as F
 from ..distributed import topology
 from ..distributed.sharding_api import shard_tensor
 from ..ops._apply import apply_op, ensure_tensor
+from ..ops.lora import lora_delta
+from ..ops.paged_cache import paged_attend
 from .generation import GenerationMixin
 from ..tensor import Tensor
 
@@ -211,69 +213,40 @@ class GPTAttention(nn.Layer):
                           [ensure_tensor(ctx)], name="merge_heads")
         return self.out_proj(merged)
 
-    def forward_paged(self, x, positions, block_tables, k_pool, v_pool,
-                      adapters=None, layer_idx=0, k_scale=None,
-                      v_scale=None):
+    def forward_paged(self, x, positions, block_tables, cache,
+                      adapters=None, layer_idx=0):
         """Paged-KV ragged step (serving engine): one QUERY TOKEN per
         row — decode tokens and prompt-chunk tokens alike (the unified
-        step's flattened grid; ops/pallas/paged_attention.py "Ragged
-        form") — KV write hook scattering into the page pool at per-row
-        positions, then ragged paged attention over each row's block
-        table masked at the row's own position. Position embeddings were
-        already added at the trunk level (GPTModel.forward_paged).
+        step's flattened grid). What is this trunk's own stays here: the
+        fused QKV projection and its split into rows ``[T, heads, hd]``
+        (position embeddings were added at the trunk level,
+        GPTModel.forward_paged), the output projection and the LoRA
+        deltas. The rest is ``paged_attend``: ``cache`` — this layer's
+        paged cache, an opaque value from the pool — goes to it unopened
+        and what it returns is returned. Returns (out [T, 1, H], cache).
 
         ``adapters`` (docs/SERVING.md "Multi-LoRA adapters"): per-row
         gathered LoRA stacks ``{site: (A, B)}``; GPT's fused QKV takes
-        ONE delta on the concatenated [B, 1, 3H] output (the delta
-        splits with it), out_proj one on the merged context.
-
-        ``k_scale``/``v_scale`` arm int8 KV pages exactly as in
-        LlamaAttention.forward_paged: quantize-on-write in the scatter,
-        in-kernel dequant in attention, cache tuple grows to
-        ``(k, v, k_scale, v_scale)`` — a static Python branch, not a new
-        program."""
-        from ..ops.pallas.paged_attention import ragged_paged_attention
-        from ..serving.kv_cache import write_step_kv
-
-        B = x.shape[0]
-        nh, hd = self.cfg.num_heads, self.head_dim
-        scale = 1.0 / math.sqrt(hd)
-        quantized = k_scale is not None
-        qkv = self.qkv_proj(x)  # [B, 1, 3H]
+        ONE delta on the concatenated [T, 1, 3H] output (the delta
+        splits with it), out_proj one on the merged context."""
+        hd = self.head_dim
+        qkv = self.qkv_proj(x)  # [T, 1, 3H]
         if adapters is not None:
-            from ..serving.adapters import lora_delta
-
             qkv = qkv + lora_delta(x, *adapters["qkv_proj"], layer_idx)
-
-        def paged_step(qkv_v, kp, vp, bt, pos, *scales):
-            pos = pos.astype(jnp.int32).reshape(B)
-            bt = bt.astype(jnp.int32)
-            qv, kv, vv = jnp.split(qkv_v, 3, axis=-1)
-            nh_l = qv.shape[-1] // hd
-            qh = qv.reshape(B, nh_l, hd)
-            kh = kv.reshape(B, nh_l, hd)
-            vh = vv.reshape(B, nh_l, hd)
-            cache = write_step_kv((kp, vp, *scales), kh, vh, bt, pos)
-            k_sc, v_sc = cache[2:] if scales else (None, None)
-            ctx = ragged_paged_attention(qh, cache[0], cache[1], bt, pos + 1,
-                                         scale=scale, k_scale=k_sc,
-                                         v_scale=v_sc)
-            return (ctx.reshape(B, 1, nh_l * hd), *cache)
-
-        operands = [ensure_tensor(qkv), ensure_tensor(k_pool),
-                    ensure_tensor(v_pool), ensure_tensor(block_tables),
-                    ensure_tensor(positions)]
-        if quantized:
-            operands += [ensure_tensor(k_scale), ensure_tensor(v_scale)]
-        merged, *new_cache = apply_op(
-            paged_step, operands, name="gpt_paged_attention")
+        # [T, 1, 3H] -> 3 x [T, heads, hd] (heads of this mp shard)
+        q, k, v = apply_op(
+            lambda t: tuple(r.reshape(r.shape[0], -1, hd)
+                            for r in jnp.split(t, 3, axis=-1)),
+            [ensure_tensor(qkv)], name="split_heads")
+        ctx, cache = paged_attend(cache, q, k, v, block_tables, positions,
+                                  1.0 / math.sqrt(hd))
+        merged = apply_op(lambda t: t.reshape(t.shape[0], 1, -1), [ctx],
+                          name="merge_heads")
         out = self.out_proj(merged)
         if adapters is not None:
-            from ..serving.adapters import lora_delta
-
             out = out + lora_delta(merged, *adapters["out_proj"],
                                    layer_idx)
-        return out, tuple(new_cache)
+        return out, cache
 
 
 class GPTMLP(nn.Layer):
@@ -303,8 +276,6 @@ class GPTMLP(nn.Layer):
         if adapters is None:
             return self.fc2(F.gelu(self.fc1(x),
                                    approximate=self._gelu_approx))
-        from ..serving.adapters import lora_delta
-
         h = self.fc1(x) + lora_delta(x, *adapters["fc1"], layer_idx)
         a = F.gelu(h, approximate=self._gelu_approx)
         return self.fc2(a) + lora_delta(a, *adapters["fc2"], layer_idx)
@@ -344,19 +315,16 @@ class GPTDecoderLayer(nn.Layer):
                 h = F.dropout(h, self.drop_p)
             return x + h
 
-    def forward_paged(self, x, positions, block_tables, k_pool, v_pool,
-                      adapters=None, layer_idx=0, k_scale=None,
-                      v_scale=None):
+    def forward_paged(self, x, positions, block_tables, cache,
+                      adapters=None, layer_idx=0):
         with jax.named_scope("attn"):
-            h, nc = self.attn.forward_paged(self.ln1(x), positions,
-                                            block_tables, k_pool, v_pool,
-                                            adapters=adapters,
-                                            layer_idx=layer_idx,
-                                            k_scale=k_scale, v_scale=v_scale)
+            h, cache = self.attn.forward_paged(
+                self.ln1(x), positions, block_tables, cache,
+                adapters=adapters, layer_idx=layer_idx)
             x = x + h
         with jax.named_scope("mlp"):
             return x + self.mlp(self.ln2(x), adapters=adapters,
-                                layer_idx=layer_idx), nc
+                                layer_idx=layer_idx), cache
 
 
 class GPTModel(nn.Layer):
@@ -473,11 +441,11 @@ class GPTModel(nn.Layer):
         """Paged decode trunk (serving engine): ``input_ids`` [B, 1],
         ``positions`` [B] per-row absolute positions (the learned position
         embedding is gathered per row — the paged counterpart of the
-        cur_len-offset decode_positions), ``caches`` a per-layer list of
-        (k_pool, v_pool) page pools — or (k_pool, v_pool, k_scales,
-        v_scales) for int8 pages. ``adapters``: per-row gathered LoRA
-        stacks ``{site: (A, B)}`` applied at every projection per layer
-        (zero for slot-0 rows). Returns (hidden, new_caches)."""
+        cur_len-offset decode_positions), ``caches`` one paged cache per
+        layer (``PagedKVCachePool.layer_caches``), each handed to its
+        layer unopened. ``adapters``: per-row gathered LoRA stacks
+        ``{site: (A, B)}`` applied at every projection per layer (zero
+        for slot-0 rows). Returns (hidden, new_caches)."""
         if self._pp > 1:
             raise NotImplementedError(
                 "paged decode requires pp=1 (same single-program scope as "
@@ -489,13 +457,9 @@ class GPTModel(nn.Layer):
         x = self._embed(ids, pos_ids)
         new_caches = []
         for li, (layer, cache) in enumerate(zip(self.layers, caches)):
-            kp, vp = cache[0], cache[1]
-            ks = cache[2] if len(cache) > 2 else None
-            vs = cache[3] if len(cache) > 2 else None
-            x, nc = layer.forward_paged(x, positions, block_tables, kp, vp,
-                                        adapters=adapters, layer_idx=li,
-                                        k_scale=ks, v_scale=vs)
-            new_caches.append(nc)
+            x, cache = layer.forward_paged(x, positions, block_tables, cache,
+                                           adapters=adapters, layer_idx=li)
+            new_caches.append(cache)
         return self.ln_f(x), new_caches
 
 

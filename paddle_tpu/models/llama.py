@@ -26,6 +26,8 @@ from ..autograd.engine import apply_op
 from ..distributed import topology
 from ..nn import functional as F
 from ..ops._apply import ensure_tensor
+from ..ops.lora import lora_delta
+from ..ops.paged_cache import paged_attend
 from ..tensor import Tensor
 from .generation import GenerationMixin
 
@@ -161,98 +163,69 @@ class LlamaAttention(nn.Layer):
                                     weight_attr=nn.ParamAttr(
                                         initializer=_normal_init(proj_std)))
 
-    def forward_paged(self, x, positions, block_tables, k_pool, v_pool,
-                      adapters=None, layer_idx=0, k_scale=None,
-                      v_scale=None):
+    def forward_paged(self, x, positions, block_tables, cache,
+                      adapters=None, layer_idx=0):
         """Paged-KV ragged step (serving engine): one QUERY TOKEN per
         row — a decode slot's next token, or one token of a prompt
         chunk (the unified step flattens mixed per-slot query lengths
-        into rows; ops/pallas/paged_attention.py "Ragged form").
+        into rows).
 
-        ``x`` [T, 1, H]; ``positions`` [T] per-row absolute positions;
-        the KV write hook scatters every row's rope'd k/v into the page
-        its block-table row names at ``positions``, then ragged paged
-        attention runs each row over its page list masked at the row's
-        own position — which is what makes chunk rows causal over their
-        freshly written chunk-mates. Returns (out [T, 1, H], new_k_pool,
-        new_v_pool) — same rope tables and masked-softmax math as the
-        dense cached_attn path, so paged serving is token-compatible
-        with ``generate()``.
+        ``x`` [T, 1, H]; ``positions`` [T] per-row absolute positions.
+        What is this trunk's own stays here: the q/k/v projections, the
+        rotary embedding at each row's own position (same tables and
+        math as the dense cached_attn path, so paged serving is
+        token-compatible with ``generate()``), the output projection and
+        the LoRA deltas. The rest is ``paged_attend``: ``cache`` — this
+        layer's paged cache, an opaque value from the pool — goes to it
+        unopened and what it returns is returned. Returns
+        (out [T, 1, H], cache).
 
         ``adapters`` (docs/SERVING.md "Multi-LoRA adapters"): per-row
         gathered LoRA stacks ``{site: (A, B)}`` — each projection adds
         its ``lora_delta`` at ``layer_idx``; rows on adapter slot 0 add
         an exact zero, keeping non-adapter tenants bit-identical.
-
-        ``k_scale``/``v_scale`` (both or neither — int8 pages,
-        docs/SERVING.md "KV page tiers & quantization"): the write hook
-        quantizes each row's k/v per-slot (quantization/observers.py
-        absmax rule) and scatters codes + scales; attention dequantizes
-        in-kernel. The cache tuple returned grows to
-        ``(k, v, k_scale, v_scale)`` — a static Python branch, so the
-        unquantized trace is unchanged and quantization rides as dtype +
-        extra operands, never a new program.
         """
-        from ..ops.pallas.paged_attention import ragged_paged_attention
-        from ..serving.kv_cache import write_step_kv
-
-        B = x.shape[0]
         cfg = self.cfg
         hd = self.head_dim
-        scale = 1.0 / math.sqrt(hd)
-        max_pos = cfg.max_position_embeddings
-        quantized = k_scale is not None
 
         q = self.q_proj(x)
         k = self.k_proj(x)
         v = self.v_proj(x)
         if adapters is not None:
-            from ..serving.adapters import lora_delta
-
             q = q + lora_delta(x, *adapters["q_proj"], layer_idx)
             k = k + lora_delta(x, *adapters["k_proj"], layer_idx)
             v = v + lora_delta(x, *adapters["v_proj"], layer_idx)
 
-        def paged_step(qv, kv, vv, kp, vp, bt, pos, *scales):
-            pos = pos.astype(jnp.int32).reshape(B)
-            bt = bt.astype(jnp.int32)
-            nh_l = qv.shape[-1] // hd
-            nkv_l = kv.shape[-1] // hd
-            qh = qv.reshape(B, nh_l, hd)
-            kh = kv.reshape(B, nkv_l, hd)
-            vh = vv.reshape(B, nkv_l, hd)
-            cos_f, sin_f = _rope_tables(max_pos, hd, cfg.rope_theta)
-            cos = cos_f[pos][:, None, :]  # [B, 1, hd/2] per-row positions
+        def rope_rows(qv, kv, vv, pos):
+            # [T, 1, heads * hd] -> [T, heads, hd] (heads of this mp
+            # shard), q and k rotated at the row's own position
+            pos = pos.astype(jnp.int32).reshape(-1)
+            qh, kh, vh = (t.reshape(t.shape[0], -1, hd)
+                          for t in (qv, kv, vv))
+            cos_f, sin_f = _rope_tables(cfg.max_position_embeddings, hd,
+                                        cfg.rope_theta)
+            cos = cos_f[pos][:, None, :]  # [T, 1, hd/2] per-row positions
             sin = sin_f[pos][:, None, :]
 
-            def rope_rows(t):
+            def rope(t):
                 t1, t2 = t[..., 0::2], t[..., 1::2]
                 return jnp.stack([t1 * cos - t2 * sin,
                                   t1 * sin + t2 * cos],
                                  axis=-1).reshape(t.shape)
 
-            qh = rope_rows(qh)
-            kh = rope_rows(kh)
-            cache = write_step_kv((kp, vp, *scales), kh, vh, bt, pos)
-            k_sc, v_sc = cache[2:] if scales else (None, None)
-            ctx = ragged_paged_attention(qh, cache[0], cache[1], bt, pos + 1,
-                                         scale=scale, k_scale=k_sc,
-                                         v_scale=v_sc)
-            return (ctx.reshape(B, 1, nh_l * hd), *cache)
+            return rope(qh), rope(kh), vh
 
-        operands = [ensure_tensor(q), ensure_tensor(k), ensure_tensor(v),
-                    ensure_tensor(k_pool), ensure_tensor(v_pool),
-                    ensure_tensor(block_tables), ensure_tensor(positions)]
-        if quantized:
-            operands += [ensure_tensor(k_scale), ensure_tensor(v_scale)]
-        merged, *new_cache = apply_op(
-            paged_step, operands, name="llama_paged_attention")
+        q, k, v = apply_op(
+            rope_rows, [ensure_tensor(q), ensure_tensor(k), ensure_tensor(v),
+                        ensure_tensor(positions)], name="llama_rope_rows")
+        ctx, cache = paged_attend(cache, q, k, v, block_tables, positions,
+                                  1.0 / math.sqrt(hd))
+        merged = apply_op(lambda t: t.reshape(t.shape[0], 1, -1), [ctx],
+                          name="merge_heads")
         out = self.o_proj(merged)
         if adapters is not None:
-            from ..serving.adapters import lora_delta
-
             out = out + lora_delta(merged, *adapters["o_proj"], layer_idx)
-        return out, tuple(new_cache)
+        return out, cache
 
     def forward(self, x, cache=None, cur_len=None):
         B, S, _ = x.shape
@@ -394,8 +367,6 @@ class LlamaMLP(nn.Layer):
         if adapters is None:
             return self.down_proj(F.silu(self.gate_proj(x))
                                   * self.up_proj(x))
-        from ..serving.adapters import lora_delta
-
         g = self.gate_proj(x) + lora_delta(x, *adapters["gate_proj"],
                                            layer_idx)
         u = self.up_proj(x) + lora_delta(x, *adapters["up_proj"],
@@ -424,16 +395,16 @@ class LlamaDecoderLayer(nn.Layer):
         x = x + self.self_attn(self.input_layernorm(x))
         return x + self.mlp(self.post_attention_layernorm(x))
 
-    def forward_paged(self, x, positions, block_tables, k_pool, v_pool,
-                      adapters=None, layer_idx=0, k_scale=None,
-                      v_scale=None):
-        h, nc = self.self_attn.forward_paged(
-            self.input_layernorm(x), positions, block_tables, k_pool,
-            v_pool, adapters=adapters, layer_idx=layer_idx,
-            k_scale=k_scale, v_scale=v_scale)
-        x = x + h
-        return x + self.mlp(self.post_attention_layernorm(x),
-                            adapters=adapters, layer_idx=layer_idx), nc
+    def forward_paged(self, x, positions, block_tables, cache,
+                      adapters=None, layer_idx=0):
+        with jax.named_scope("attn"):
+            h, cache = self.self_attn.forward_paged(
+                self.input_layernorm(x), positions, block_tables, cache,
+                adapters=adapters, layer_idx=layer_idx)
+            x = x + h
+        with jax.named_scope("mlp"):
+            return x + self.mlp(self.post_attention_layernorm(x),
+                                adapters=adapters, layer_idx=layer_idx), cache
 
 
 class LlamaModel(nn.Layer):
@@ -499,23 +470,18 @@ class LlamaModel(nn.Layer):
     def forward_paged(self, input_ids, positions, block_tables, caches,
                       adapters=None):
         """Paged decode trunk (serving engine): ``input_ids`` [B, 1],
-        ``positions`` [B], ``caches`` a per-layer list of (k_pool, v_pool)
-        page pools — or (k_pool, v_pool, k_scales, v_scales) for int8
-        pages (the scale arrays thread through to the in-kernel dequant
-        and come back updated in ``new_caches``). ``adapters``: per-row
-        gathered LoRA stacks ``{site: (A [T, L, r, in], B [T, L, out,
-        r])}`` applied at every projection site per layer (zero for
-        slot-0 rows). Returns (hidden [B, 1, H], new_caches)."""
+        ``positions`` [B], ``caches`` one paged cache per layer
+        (``PagedKVCachePool.layer_caches``), each handed to its layer
+        unopened. ``adapters``: per-row gathered LoRA stacks
+        ``{site: (A [T, L, r, in], B [T, L, out, r])}`` applied at every
+        projection site per layer (zero for slot-0 rows). Returns
+        (hidden [B, 1, H], new_caches)."""
         x = self.embed_tokens(ensure_tensor(input_ids))
         new_caches = []
         for li, (layer, cache) in enumerate(zip(self.layers, caches)):
-            kp, vp = cache[0], cache[1]
-            ks = cache[2] if len(cache) > 2 else None
-            vs = cache[3] if len(cache) > 2 else None
-            x, nc = layer.forward_paged(x, positions, block_tables, kp, vp,
-                                        adapters=adapters, layer_idx=li,
-                                        k_scale=ks, v_scale=vs)
-            new_caches.append(nc)
+            x, cache = layer.forward_paged(x, positions, block_tables, cache,
+                                           adapters=adapters, layer_idx=li)
+            new_caches.append(cache)
         return self.norm(x), new_caches
 
 

@@ -1535,20 +1535,17 @@ class ServingEngine:
         traced: a draft row's target at position p+j is EXACTLY the
         token the stream would sample there without speculation, which
         is why acceptance-by-equality preserves bit-identical streams."""
-        trunk, model, n_layers = self.trunk, self.model, self.n_layers
+        trunk, model, pool = self.trunk, self.model, self.pool
         site_names = [s for s, _, _ in self.adapters.sites]
         n_adp = 2 * len(site_names)
-        # pool arrays per layer: (k, v) for bf16/f32 pools, (k, v,
-        # k_scales, v_scales) for int8 — the stride is a Python constant
-        # at trace time, so quantization changes WHICH arrays ride as
-        # data, never the program count
-        stride = self.pool.step_stride
 
         def step_fn(tok, tok_pos, tok_bt, tok_adp, sample_rows, sample_pos,
                     temps, seeds, fsm_state, grammar_table, *rest):
-            adp_flat, flat_pools = rest[:n_adp], rest[n_adp:]
-            caches = [tuple(flat_pools[stride * i: stride * (i + 1)])
-                      for i in range(n_layers)]
+            # the pool's operands, regrouped by the pool into one opaque
+            # cache per layer: which arrays a layer keeps (int8 pages add
+            # their scales) is the pool's and ops/paged_cache.py's to know,
+            # and changes WHICH arrays ride as data, never the program count
+            adp_flat, caches = rest[:n_adp], pool.layer_caches(rest[n_adp:])
             with no_grad():
                 # per-row adapter gather: every grid row pulls ITS
                 # owner's (A, B) stack by index — slot 0 rows pull the
@@ -1638,8 +1635,7 @@ class ServingEngine:
                            [masked, ensure_tensor(temps),
                             ensure_tensor(seeds), ensure_tensor(sample_pos)],
                            name="serve_sample")
-            flat = [t for c in ncs for t in c]
-            return (nxt, fin, *flat)
+            return (nxt, fin, *pool.step_flat(ncs))
 
         # "the step compiles once per bucket" becomes monitorable:
         # jit_compiles_total{fn="serving_step"} must pin at the
@@ -1656,18 +1652,19 @@ class ServingEngine:
             self.page_size, self.pages_per_seq, self._spec_rows,
             self.adapters.capacity, self.adapters.rank,
             self._grammar_cap, str(jnp.dtype(self.pool.dtype))))
-        # the step CONSUMES the pool: its arrays (the trailing positional
-        # arguments, after the ten grids and the adapter stacks) are given
-        # up to the program, which writes this step's rows into the same
-        # buffers and hands them back as `flat` — `set_step_flat` swaps
-        # them in, and what the pool held before the call is deleted.
-        # jax pairs a donated input with the first output of equal aval
-        # in order, which `flat` (k0, v0, k1, ...) keeps.
+        # the step CONSUMES the pool: its operands (`pool.step_flat()`, the
+        # trailing positional arguments, after the ten grids and the adapter
+        # stacks) are given up to the program, which writes this step's rows
+        # into the same buffers and hands them back in the same order —
+        # `set_step_flat` swaps them in, and what the pool held before the
+        # call is deleted. jax pairs a donated input with the first output
+        # of equal aval in order, which that order (k0, v0, k1, ...) keeps.
         first_pool = 10 + n_adp
         return jit.StaticFunction(
             step_fn, observe=[self.model], warmup=False, dy2static=False,
             cache_dir=self._compile_cache_dir, cache_key_extra=extra,
-            donate_argnums=range(first_pool, first_pool + stride * n_layers))
+            donate_argnums=range(first_pool,
+                                 first_pool + len(pool.step_flat())))
 
     def _step_once(self) -> List[RequestOutput]:
         t0 = time.perf_counter()
@@ -1907,9 +1904,7 @@ class ServingEngine:
             Tensor(jnp.asarray(sample_pos)), Tensor(jnp.asarray(temps)),
             Tensor(jnp.asarray(seeds)), Tensor(jnp.asarray(fsm_state)),
             self._grammar_device,
-            *self.adapters.arrays(),
-            *[p for i in range(self.n_layers)
-              for p in self.pool.step_arrays(i)])
+            *self.adapters.arrays(), *self.pool.step_flat())
         nxt, fin, flat = res[0], res[1], res[2:]
         self.pool.set_step_flat(flat)
         if self.pool.quantized and total:

@@ -135,46 +135,6 @@ def pages_for_hbm_budget(hbm_bytes: int, page_size: int, n_kv_heads: int,
     return max(int(hbm_bytes) // per, 0)
 
 
-def _write_rows(pool, slots, rows):
-    """``rows`` ``[T, heads, ...]`` into ``pool`` ``[pages, heads, page,
-    ...]``, row ``(t, h)`` at flat slot ``slots[t, h]`` of the pool's
-    ``[pages * heads * page, ...]`` view: ONE row scatter. The flat view
-    keeps the array's own layout, so XLA updates a donated pool where it
-    lies; ``pool.at[page_ids, :, offs].set(rows)`` made it re-lay the WHOLE
-    array so that a token's ``[heads, hd]`` window was contiguous, and lay
-    it back for the kernel: two pool-sized copies per array per step
-    (PERF.md, PR 30). Indices are NOT marked unique: a bucket's padding
-    rows all name (page 0, offset 0), the reserved null page."""
-    tail = pool.shape[3:]
-    flat = pool.reshape((-1,) + tail)
-    flat = flat.at[slots.reshape(-1)].set(
-        rows.reshape((-1,) + tail).astype(pool.dtype))
-    return flat.reshape(pool.shape)
-
-
-def write_step_kv(cache, k_rows, v_rows, block_tables, positions):
-    """The compiled step's KV write hook, one definition for every trunk:
-    row ``t``'s K and V ``[T, n_kv_heads, head_dim]`` land in page
-    ``block_tables[t, positions[t] // page_size]``, slot ``positions[t] %
-    page_size`` of ``cache`` — a layer's :meth:`PagedKVCachePool.
-    step_arrays` as raw arrays, ``(k, v)`` or, for int8 pages, ``(k, v,
-    k_scales, v_scales)``: those quantize on write (per-slot absmax) and
-    write the scales beside the codes. Returns the updated tuple in the
-    same order. Inactive rows carry all-zero block tables and positions,
-    landing their writes on the pool's reserved null page 0."""
-    n_heads, page_size = cache[0].shape[1:3]
-    t = jnp.arange(positions.shape[0], dtype=jnp.int32)
-    page_ids = block_tables[t, positions // page_size]
-    slots = ((page_ids[:, None] * n_heads
-              + jnp.arange(n_heads, dtype=jnp.int32)) * page_size
-             + (positions % page_size)[:, None])
-    rows = (k_rows, v_rows)
-    if len(cache) == 4:
-        (k_rows, k_sc), (v_rows, v_sc) = quantize_kv(k_rows), quantize_kv(v_rows)
-        rows = (k_rows, v_rows, k_sc, v_sc)
-    return tuple(_write_rows(a, slots, r) for a, r in zip(cache, rows))
-
-
 class HostPageStore:
     """Host-RAM second page tier: a dict of ``(seq_id, page_index) →``
     per-layer numpy slabs, written by :meth:`PagedKVCachePool.offload_seq`
@@ -993,26 +953,41 @@ class PagedKVCachePool:
         return 4 if self.quantized else 2
 
     def step_arrays(self, li: int):
-        """Layer ``li``'s cache tuple in step-operand order — the single
-        definition both the engine's program invocation and its
-        result-unpacking use, so the stride cannot drift."""
+        """Layer ``li``'s cache in step-operand order: what the trunk's
+        layer ``li`` is handed as its ``cache`` and what
+        ``ops/paged_cache.paged_attend`` opens."""
         if self.quantized:
             return (self.k_pools[li], self.v_pools[li],
                     self.k_scales[li], self.v_scales[li])
         return (self.k_pools[li], self.v_pools[li])
 
-    def set_step_flat(self, flat) -> None:
-        """Inverse of per-layer :meth:`step_arrays` concatenation: accept
-        the compiled step's flat cache outputs and swap every array (and
-        scale array, when quantized) back in."""
+    def step_flat(self, caches=None) -> list:
+        """Per-layer caches (the pool's own :meth:`step_arrays` by default;
+        inside the step program, the updated ones the trunk returned),
+        concatenated: the operands the compiled step takes, gives up (they
+        are donated) and returns updated, in this order (``k0, v0[, ks0,
+        vs0], k1, ...``)."""
+        if caches is None:
+            caches = map(self.step_arrays, range(self.num_layers))
+        return [t for c in caches for t in c]
+
+    def layer_caches(self, flat) -> list:
+        """Regroup a :meth:`step_flat`-ordered sequence (arrays, or the
+        tracers the step program sees in their place) into one ``cache``
+        per layer: the inverse of the concatenation."""
         s = self.step_stride
+        return [tuple(flat[s * li: s * (li + 1)])
+                for li in range(self.num_layers)]
+
+    def set_step_flat(self, flat) -> None:
+        """The way back: accept the compiled step's flat cache outputs
+        (:meth:`step_flat`'s order) and swap every array (and scale
+        array, when quantized) back in."""
+        caches = self.layer_caches(flat)
         self.set_arrays(
-            [flat[s * i] for i in range(self.num_layers)],
-            [flat[s * i + 1] for i in range(self.num_layers)],
-            k_scales=([flat[s * i + 2] for i in range(self.num_layers)]
-                      if self.quantized else None),
-            v_scales=([flat[s * i + 3] for i in range(self.num_layers)]
-                      if self.quantized else None))
+            [c[0] for c in caches], [c[1] for c in caches],
+            k_scales=[c[2] for c in caches] if self.quantized else None,
+            v_scales=[c[3] for c in caches] if self.quantized else None)
 
     def write_prompt_kv(self, seq_id, layer_kv, start: int = 0) -> None:
         """Prefill's KV write hook: scatter a dense prompt cache into this

@@ -17,6 +17,7 @@ def _ref_all(path):
     return re.findall(r"'([^']+)'", block)
 
 
+@pytest.mark.needs_reference
 def test_static_parity_modulo_ipu():
     names = _ref_all("/root/reference/python/paddle/static/__init__.py")
     # IPU hardware support is deliberately absent (loud, not stubbed)
@@ -25,6 +26,7 @@ def test_static_parity_modulo_ipu():
     assert missing == [], missing
 
 
+@pytest.mark.needs_reference
 @pytest.mark.parametrize("path,mod", [
     ("/root/reference/python/paddle/incubate/__init__.py", paddle.incubate),
     ("/root/reference/python/paddle/incubate/nn/__init__.py",

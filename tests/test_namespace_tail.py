@@ -20,6 +20,7 @@ def _ref_all(path):
     return re.findall(r'["\']([^"\']+)["\']', m.group(1)) if m else []
 
 
+@pytest.mark.needs_reference
 @pytest.mark.parametrize("ref,mod_path", [
     (f"{R}/vision/transforms/__init__.py", "vision.transforms"),
     (f"{R}/vision/datasets/__init__.py", "vision.datasets"),

@@ -21,6 +21,7 @@ def _ref_all(path):
     return re.findall(r"'([^']+)'", block)
 
 
+@pytest.mark.needs_reference
 @pytest.mark.parametrize("ref_path,mod", [
     ("/root/reference/python/paddle/nn/__init__.py", nn),
     ("/root/reference/python/paddle/nn/functional/__init__.py", F),

@@ -11,6 +11,8 @@ import importlib
 import os
 import re
 
+import pytest
+
 REF = "/root/reference/python/paddle"
 
 # reference-internal trees with no public API contract (legacy fluid,
@@ -51,6 +53,7 @@ def _iter_reference_alls():
             yield mod_rel, names
 
 
+@pytest.mark.needs_reference
 def test_every_reference_all_resolves():
     failures = {}
     for mod_rel, names in _iter_reference_alls():

@@ -19,6 +19,7 @@ def _ref_all(path):
     return re.findall(r"'([^']+)'", block)
 
 
+@pytest.mark.needs_reference
 def test_static_nn_parity_gate():
     names = _ref_all("/root/reference/python/paddle/static/nn/__init__.py")
     missing = [n for n in names if not hasattr(nn, n)]

@@ -1,6 +1,6 @@
 """The serving step updates the KV pool where it lies (ISSUE 30): the pool's
 arrays are given up to the compiled step (``StaticFunction(donate_argnums=)``)
-and the K/V write keeps the pool's layout (``kv_cache.write_step_kv``).
+and the K/V write keeps the pool's layout (``ops/paged_cache.write_step_kv``).
 
 On the CPU jax honours donation (a donated array reads ``is_deleted()``), so
 ownership is testable here; that XLA:TPU leaves no pool-shaped copy in the
@@ -16,9 +16,9 @@ import paddle_tpu as paddle
 from paddle_tpu import jit, metrics, nn
 from paddle_tpu.models import (GPTForCausalLM, LlamaForCausalLM, gpt_tiny,
                                llama_tiny)
+from paddle_tpu.ops.paged_cache import write_step_kv
 from paddle_tpu.quantization.observers import quantize_kv
 from paddle_tpu.serving import ServingEngine
-from paddle_tpu.serving.kv_cache import write_step_kv
 
 pytestmark = pytest.mark.serving
 
