@@ -17,14 +17,23 @@ reference needed separate eager/static engines + program passes for this).
 
 Mutable state is functionalized through *slots*: every Parameter/buffer cell,
 optimizer accumulator, and RNG key reachable from the function is passed in
-and returned as an explicit pytree, with input buffers donated so XLA updates
-parameters in place (the buffer-donation answer to the reference's inplace
-``adamw_`` ops — SURVEY.md §7 hard part #2).
+as an explicit pytree. The trace of a signature shows which slots the function
+WROTE: those are donated and returned, so XLA updates them in place (the
+buffer-donation answer to the reference's inplace ``adamw_`` ops — SURVEY.md
+§7 hard part #2); a slot the trace only READ is an ordinary input and no
+output (a serving step's parameters, a fine-tune's frozen layers).
+
+Everything about a call that follows from its signature alone — the written
+slots, the given-up argument leaves, the abstract operands, the output
+structure — is decided once, when the signature's program is built, and kept
+as that signature's launch plan (``_Plan``); a later call of the signature
+reads the current values, launches, and hands back the results.
 """
 from __future__ import annotations
 
 import gc
 import hashlib
+import itertools
 import os
 import pickle
 import tempfile
@@ -243,7 +252,7 @@ def _closure_objects(fn: Callable):
 
 # ------------------------------------------------------------ arg flattening
 class _Static:
-    """Marker wrapping a non-tensor leaf; identity participates in cache key."""
+    """Marker wrapping a non-tensor leaf of a call's arguments."""
 
     __slots__ = ("v",)
 
@@ -251,71 +260,52 @@ class _Static:
         self.v = v
 
 
-def _flatten_args(tree):
-    """Split (args, kwargs) into (traced arrays, spec) where spec rebuilds the
-    structure with placeholders for traced leaves. Tensors and bare jax/numpy
-    arrays are traced; python scalars/strings/None are static."""
+def _flatten_args(args, kwargs):
+    """One call's traced arrays, what each leaf is, the signature those
+    leaves give, and the structure around them. Tensors and bare jax/numpy
+    arrays are traced; python scalars/strings are static (``None`` is
+    structure). ``meta`` is parallel to the leaves: a Tensor's
+    ``stop_gradient``, None for a bare array, a ``_Static`` for the rest.
+
+    The signature is one flat tuple: per traced leaf its kind, shape, dtype
+    and weak_type (jax.jit would silently retrace on a weak/strong flip, but
+    an AOT-loaded executable REJECTS the mismatched aval — keying on it
+    keeps both paths one-signature-one-program), per static leaf its value."""
+    leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
     arrays: list = []
-    meta: list = []  # parallel to arrays: (stop_gradient,)
-
-    def go(x):
+    meta: list = []
+    sig: list = [treedef]
+    for x in leaves:
         if isinstance(x, Tensor):
-            arrays.append(x._value)
-            meta.append(bool(x.stop_gradient))
-            return ("T", len(arrays) - 1)
-        if isinstance(x, (jax.Array, np.ndarray)):
-            arrays.append(jnp.asarray(x))
-            meta.append(True)
-            return ("A", len(arrays) - 1)
-        if isinstance(x, (list, tuple)):
-            return (type(x).__name__, [go(v) for v in x])
-        if isinstance(x, dict):
-            return ("dict", [(k, go(v)) for k, v in sorted(x.items(), key=lambda kv: str(kv[0]))])
-        return ("S", _Static(x))
-
-    spec = go(tree)
-    return arrays, meta, spec
-
-
-def _rebuild_args(spec, arrays, meta):
-    kind, payload = spec
-    if kind == "T":
-        return Tensor(arrays[payload], stop_gradient=meta[payload])
-    if kind == "A":
-        return arrays[payload]
-    if kind == "S":
-        return payload.v
-    if kind == "list":
-        return [_rebuild_args(s, arrays, meta) for s in payload]
-    if kind == "tuple":
-        return tuple(_rebuild_args(s, arrays, meta) for s in payload)
-    if kind == "dict":
-        return {k: _rebuild_args(s, arrays, meta) for k, s in payload}
-    raise AssertionError(kind)
+            a, stop = x._value, bool(x.stop_gradient)
+            meta.append(stop)
+            sig.append(stop)
+        elif isinstance(x, (jax.Array, np.ndarray)):
+            a = x if isinstance(x, jax.Array) else jnp.asarray(x)
+            meta.append(None)
+            sig.append("A")
+        else:
+            meta.append(_Static(x))
+            try:
+                hash(x)
+                sig += ("S", x)
+            except TypeError:
+                sig += ("S", repr(x))
+            continue
+        arrays.append(a)
+        sig += (a.shape, a.dtype, bool(getattr(a, "weak_type", False)))
+    return arrays, meta, tuple(sig), treedef
 
 
-def _spec_key(spec, arrays, meta):
-    kind, payload = spec
-    if kind in ("T", "A"):
-        a = arrays[payload]
-        # weak_type participates: jax.jit would silently retrace on a
-        # weak/strong flip, but an AOT-loaded executable (persistent
-        # compile cache) REJECTS the mismatched aval — keying on it keeps
-        # both paths one-signature-one-program
-        return (kind, tuple(a.shape), str(a.dtype), meta[payload],
-                bool(getattr(a, "weak_type", False)))
-    if kind == "S":
-        v = payload.v
-        try:
-            hash(v)
-            return ("S", v)
-        except TypeError:
-            return ("S", repr(v))
-    if kind in ("list", "tuple"):
-        return (kind, tuple(_spec_key(s, arrays, meta) for s in payload))
-    if kind == "dict":
-        return ("dict", tuple((k, _spec_key(s, arrays, meta)) for k, s in payload))
-    raise AssertionError(kind)
+def _rebuild_args(treedef, meta, arrays):
+    """The (args, kwargs) that :func:`_flatten_args` took apart, around
+    ``arrays`` (tracers, inside the trace)."""
+    it = iter(arrays)
+    return treedef.unflatten([
+        m.v if isinstance(m, _Static)
+        else next(it) if m is None
+        else Tensor(next(it), stop_gradient=m)
+        for m in meta])
 
 
 def _flatten_out(out):
@@ -375,42 +365,40 @@ def _buffer_ptr(v):
         return id(v)
 
 
-def _leaf_indices(spec):
-    """Positions in the flat array list of every traced leaf under ``spec``
-    (a node of :func:`_flatten_args`' structure)."""
-    kind, payload = spec
-    if kind in ("T", "A"):
-        return [payload]
-    if kind in ("list", "tuple"):
-        return [i for s in payload for i in _leaf_indices(s)]
-    if kind == "dict":
-        return [i for _, s in payload for i in _leaf_indices(s)]
-    return []
+def _n_traced_leaves(tree) -> int:
+    return sum(isinstance(x, (Tensor, jax.Array, np.ndarray))
+               for x in jax.tree_util.tree_leaves(tree))
 
 
-def _unalias(state_vals, arrays, given=()):
-    """State buffers, and the argument leaves ``given`` (indices into
-    ``arrays``) that the caller gives up, are donated to the compiled step;
-    XLA rejects a donated buffer that aliases another argument (e.g. two
-    accumulators both produced by one CSE'd zeros_like, a Parameter also
-    passed as a data input, one array passed both given-up and kept). Copy
-    any such duplicate so every donated buffer is unique. Returns the state
-    values and the argument list, with copies in the duplicates' places."""
-    given = set(given)
-    seen = {_buffer_ptr(v) for i, v in enumerate(arrays) if i not in given}
+def _dead_ref():
+    return None
 
-    def unique(v):
-        ptr = _buffer_ptr(v)
-        if ptr in seen:
-            return jnp.array(v, copy=True)
-        seen.add(ptr)
-        return v
 
-    state_vals = [unique(v) for v in state_vals]
-    if given:
-        arrays = [unique(v) if i in given else v
-                  for i, v in enumerate(arrays)]
-    return state_vals, arrays
+def _with_room(f):
+    """``f()``, from a frame so large that the interpreter opens one roomy
+    chunk of its frame stack for it, in which every frame below then fits.
+
+    CPython (3.11 on) keeps Python frames in chunks of 16 KiB: a call whose
+    frame does not fit maps a new chunk, and its return unmaps it. A trace is
+    some hundred frames deep and re-enters its deepest dozen tens of thousands
+    of times, so where a chunk happens to end inside them, every one of those
+    calls pays both. How many frames the caller stands on decides that: the
+    same serving-step trace took 9, 15 or 23 s a bucket on the chip's host
+    (PERF.md section 6, PR 33), and one frame more or less in a refactor
+    moved `setup_s` by seconds. The size is the only thing this frame is for."""
+    return f()
+
+
+_with_room.__code__ = _with_room.__code__.replace(co_stacksize=1 << 16)
+
+
+def _abstract(a):
+    # mesh shardings are part of the program (a re-lowering without them is
+    # another program); single-device placement is not
+    return jax.ShapeDtypeStruct(
+        a.shape, a.dtype, weak_type=bool(getattr(a, "weak_type", False)),
+        sharding=(a.sharding if isinstance(getattr(a, "sharding", None),
+                                           NamedSharding) else None))
 
 
 # -------------------------------------------------- persistent compile cache
@@ -427,7 +415,7 @@ def _unalias(state_vals, arrays, given=()):
 # source split makes warm restarts and rolling reloads monitorable
 # (docs/OBSERVABILITY.md).
 _cache_dir_override: Optional[str] = None
-_MEMORY_CACHE: dict = {}  # full key string -> (aot_executable, out_spec)
+_MEMORY_CACHE: dict = {}  # full key string -> (aot_executable, out_spec, written)
 
 
 def set_compile_cache_dir(path: Optional[str]) -> None:
@@ -498,7 +486,7 @@ def _code_fingerprint(fn) -> str:
 
 
 def _load_disk_entry(path: str, full_key: str):
-    """(aot, out_spec) deserialized from ``path``, or None. ANY failure —
+    """(aot, out_spec, written) deserialized from ``path``, or None. ANY failure —
     missing file, truncated pickle, version/device drift surfacing as a
     deserialization error, a digest collision caught by the stored
     full-key mismatch — means "not cached": the caller falls back to a
@@ -512,12 +500,13 @@ def _load_disk_entry(path: str, full_key: str):
 
         aot = serialize_executable.deserialize_and_load(
             entry["payload"], entry["in_tree"], entry["out_tree"])
-        return aot, entry["out_spec"]
+        return aot, entry["out_spec"], tuple(entry["written"])
     except Exception:
         return None
 
 
-def _store_disk_entry(path: str, full_key: str, aot, out_spec) -> None:
+def _store_disk_entry(path: str, full_key: str, aot, out_spec,
+                      written) -> None:
     """Serialize an AOT executable to ``path`` atomically (tmp file +
     os.replace: a concurrently starting process reads either the old
     complete entry or the new one, never a torn write). Best-effort: an
@@ -529,7 +518,7 @@ def _store_disk_entry(path: str, full_key: str, aot, out_spec) -> None:
         payload, in_tree, out_tree = serialize_executable.serialize(aot)
         blob = pickle.dumps({"key": full_key, "payload": payload,
                              "in_tree": in_tree, "out_tree": out_tree,
-                             "out_spec": out_spec})
+                             "out_spec": out_spec, "written": written})
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path) or ".",
                                    suffix=".tmp")
@@ -548,18 +537,103 @@ def _store_disk_entry(path: str, full_key: str, aot, out_spec) -> None:
 
 
 # ------------------------------------------------------------ StaticFunction
-class _Compiled:
-    __slots__ = ("jitted", "out_spec", "aot", "given")
+class _Plan:
+    """How one signature is launched: everything about a call that follows
+    from the signature alone, decided when its program was built."""
 
-    def __init__(self, jitted, out_spec=None, aot=None, given=()):
-        self.jitted = jitted
-        self.out_spec = out_spec
-        # the executable `_build` compiled (or the persistent cache
-        # held): calls run it; `jitted` stays alive regardless, for
-        # cost_analysis/lower and as the path a call degrades to
-        self.aot = aot
+    __slots__ = ("aot", "out_spec", "given", "written", "read", "donates",
+                 "abstract", "trace", "_jitted", "_refs", "_ptrs", "_ptr_set")
+
+    def __init__(self, given, donates):
+        # the executable `_build` compiled (or the persistent cache held):
+        # calls run it; None where nothing compiled (``lower()``) or a call
+        # degraded to ``jitted``
+        self.aot = None
+        self.out_spec = None
         # flat argument leaves this program consumes (donate_argnums)
         self.given = given
+        # slot indices the trace saw written (donated and returned) and only
+        # read (plain inputs, no outputs); None until a trace or a
+        # persistent-cache entry says
+        self.written = self.read = None
+        self.donates = donates
+        self.abstract = None    # ShapeDtypeStruct tree of `operands`
+        # the jax.jit of this signature, or (a plan that the persistent
+        # cache filled) the call that traces it when someone asks
+        self._jitted = self.trace = None
+        # buffer pointers of the operands the program does not consume, as
+        # last looked up (see `_kept_pointers`)
+        self._refs, self._ptrs, self._ptr_set = [], [], frozenset()
+
+    @property
+    def jitted(self):
+        """The ``jax.jit`` of this signature: what ``lower``/``cost_analysis``
+        read and what a call runs where there is no executable."""
+        if self._jitted is None:
+            self._jitted = self.trace()
+        return self._jitted
+
+    def set_written(self, written, n_state) -> None:
+        if self.written is not None and self.written != tuple(written):
+            raise RuntimeError(
+                "the persistent compile cache holds this signature with "
+                f"slots {self.written} written; the trace wrote {written}")
+        self.written = tuple(written)
+        mine = set(self.written)
+        self.read = tuple(i for i in range(n_state) if i not in mine)
+
+    def operands(self, state, lr_vals, arrays):
+        """This call's operands, as ``jitted`` and the executable take them:
+        written state, read-only state, learning rates, the argument leaves
+        with None where a leaf is given up, and the given-up leaves. Written
+        state and given-up leaves are donated; XLA rejects a donated buffer
+        that is also another argument (two accumulators both produced by one
+        CSE'd zeros_like, a Parameter also passed as a data input, one array
+        passed both given-up and kept), so any such duplicate rides as a
+        copy and every donated buffer is unique."""
+        written = [state[i] for i in self.written]
+        read = [state[i] for i in self.read]
+        kept = list(arrays)
+        given = [kept[i] for i in self.given]
+        for i in self.given:
+            kept[i] = None
+        if self.donates and (written or given):
+            others = self._kept_pointers(
+                read + [a for a in kept if a is not None])
+            mine = set()
+
+            def unique(v):
+                ptr = _buffer_ptr(v)
+                if ptr in others or ptr in mine:
+                    return jnp.array(v, copy=True)
+                mine.add(ptr)
+                return v
+
+            written = [unique(v) for v in written]
+            given = [unique(v) for v in given]
+        return written, read, lr_vals, kept, given
+
+    def _kept_pointers(self, vals):
+        """Buffer pointers of the operands the program only reads. An array
+        object keeps its buffer for life, so one that the last call of this
+        plan already looked up (held weakly: a plan must not keep a replaced
+        parameter alive) is not asked again — a serving step asks its 48
+        donated pool arrays and its fresh grids, not its 293 parameters."""
+        refs, ptrs = self._refs, self._ptrs
+        if len(refs) != len(vals):
+            refs[:], ptrs[:] = [_dead_ref] * len(vals), [None] * len(vals)
+        changed = False
+        for j, v in enumerate(vals):
+            if refs[j]() is not v:
+                try:
+                    refs[j] = weakref.ref(v)
+                except TypeError:
+                    refs[j] = _dead_ref
+                ptrs[j] = _buffer_ptr(v)
+                changed = True
+        if changed:
+            self._ptr_set = frozenset(ptrs)
+        return self._ptr_set
 
 
 class StaticFunction:
@@ -589,8 +663,12 @@ class StaticFunction:
         self._slot_ids: set[int] = set()
         self._opts: list[Optimizer] = []
         self._layers: list[Layer] = []
-        self._cache: dict = {}
-        self._abstract_args: dict = {}  # cache key -> ShapeDtypeStruct tree
+        self._cache: dict = {}  # signature key -> _Plan
+        # cache key -> ShapeDtypeStruct tree of the plan's operands, in
+        # order of recency (the last is the most recent call's)
+        self._abstract_args: dict = {}
+        self._latest_plan: Optional[_Plan] = None
+        self._calls = None  # (reused a plan, built one) counter children
         self._warmed_up = False
         # persistent compile cache: an instance-pinned dir beats the
         # process default (set_compile_cache_dir / PADDLE_TPU_COMPILE_CACHE).
@@ -615,28 +693,19 @@ class StaticFunction:
     def _latest_key(self):
         return next(reversed(self._abstract_args), None)
 
-    def _compiled(self, key=None) -> Optional[_Compiled]:
-        """The program of a called signature (``key=None``: the most
-        recent call's)."""
+    def _plan(self, key=None) -> Optional[_Plan]:
+        """The plan of a called signature (``key=None``: the most recent
+        call's)."""
         return self._cache.get(self._latest_key() if key is None else key)
 
     def _lowered(self, key=None):
         """``jax.stages.Lowered`` of a compiled signature from its recorded
-        abstract arguments (``key=None``: the most recent); None before
-        any call compiled. Lowering may re-trace the function, which
-        leaves tracers in the state slots — they are put back."""
-        if key is None:
-            key = self._latest_key()
-        compiled = self._cache.get(key)
-        abstract = self._abstract_args.get(key)
-        if compiled is None or abstract is None:
+        abstract operands (``key=None``: the most recent); None before
+        any call compiled."""
+        plan = self._plan(key)
+        if plan is None or plan.abstract is None:
             return None
-        saved = [slot.get() for slot in self._slots]
-        try:
-            return compiled.jitted.lower(*abstract)
-        finally:
-            for slot, v in zip(self._slots, saved):
-                slot.set(v)
+        return plan.jitted.lower(*plan.abstract)
 
     def cost_analysis(self, key=None) -> Optional[dict]:
         """XLA cost analysis (flops / bytes accessed / ...) of a compiled
@@ -660,9 +729,9 @@ class StaticFunction:
         kernel shows in both as ``tpu_custom_call`` — chip_smoke.py
         asserts on that instead of trusting the dispatch. None before any
         call compiled."""
-        built = self._compiled(key)
-        if compiled and built is not None and built.aot is not None:
-            return built.aot.as_text()  # the executable the calls run
+        plan = self._plan(key)
+        if compiled and plan is not None and plan.aot is not None:
+            return plan.aot.as_text()  # the executable the calls run
         lowered = self._lowered(key)
         if lowered is None:
             return None
@@ -673,10 +742,10 @@ class StaticFunction:
         writes its outputs into (``key=None``: the most recent) — what the
         ``paddle_tpu_jit_aliased_bytes`` gauge read when it was built. None
         before any call compiled, or where the call runs ``jitted``."""
-        compiled = self._compiled(key)
-        if compiled is None or compiled.aot is None:
+        plan = self._plan(key)
+        if plan is None or plan.aot is None:
             return None
-        return _aliased_bytes(compiled.aot)
+        return _aliased_bytes(plan.aot)
 
     def lower(self, *args, **kwargs):
         """AOT trace + lower WITHOUT executing (reference counterpart: the
@@ -698,8 +767,8 @@ class StaticFunction:
                     "the function once first, or construct with "
                     "warmup=False and list state in observe=")
             self._setup_no_warmup()
-        _, compiled, operands = self._prepare(args, kwargs, compile=False)
-        return compiled.jitted.lower(*operands)
+        plan, operands, _ = self._prepare(args, kwargs, compile=False)
+        return plan.jitted.lower(*operands)
 
     # -- paddle API surface --------------------------------------------------
     @property
@@ -745,36 +814,103 @@ class StaticFunction:
         return (self._cache_dir if self._cache_dir is not None
                 else get_compile_cache_dir())
 
-    def _persistent_key(self, key, example, given) -> str:
+    def _persistent_key(self, key, state, lr_vals, given) -> str:
         """The FULL persistent-cache key, as a stable string: everything
         that shapes the executable's bytes or its calling convention.
         Signature key (shapes/dtypes/weak_type of args, training flags),
         state/lr avals, the function's code fingerprint and caller-
-        supplied extra, the donation policy, and the jax + device
-        fingerprint (a different jaxlib or device kind must miss)."""
-        state_vals, lr_vals = example[:2]
+        supplied extra, the donation policy, the calling convention (an
+        entry stored when ALL state was donated and returned is another
+        executable: a miss) and the jax + device fingerprint (a different
+        jaxlib or device kind must miss)."""
         dev = jax.devices()[0]
         state_avals = tuple((tuple(v.shape), str(v.dtype),
                              bool(getattr(v, "weak_type", False)))
-                            for v in state_vals)
+                            for v in state)
         return repr((
             self.__name__, _code_fingerprint(self._fn),
             self._cache_key_extra, key, state_avals, len(lr_vals),
-            _donation_off(), given,
+            _donation_off(), given, "written-state-donated",
             jax.__version__, jax.lib.__version__,
             dev.platform, dev.device_kind,
         ))
 
-    def _given_up(self, spec) -> tuple:
-        """Flat leaf indices of the positional arguments named in
-        ``donate_argnums``; nothing under ``PADDLE_TPU_NO_DONATE=1``."""
+    def _given_up(self, args) -> tuple:
+        """Flat indices, among a call's traced leaves, of those under the
+        positional arguments named in ``donate_argnums``; nothing under
+        ``PADDLE_TPU_NO_DONATE=1``."""
         if not self._donate_argnums or _donation_off():
             return ()
-        positional = spec[1][0][1]  # spec of (args, kwargs) -> args' specs
+        ends = list(itertools.accumulate(map(_n_traced_leaves, args)))
         return tuple(i for n in self._donate_argnums
-                     for i in _leaf_indices(positional[n]))
+                     for i in range(ends[n - 1] if n else 0, ends[n]))
 
-    def _build(self, spec, meta, key=None, example=None, given=()):
+    def _trace(self, plan, treedef, meta, state, lr_vals, arrays):
+        """Trace the function once for one signature, learn from the trace
+        which state slots it wrote, and return the ``jax.jit`` that takes
+        the written slots donated and the rest read-only. ``state``,
+        ``lr_vals`` and ``arrays`` are arrays or their abstract values."""
+        slots, opts, fn = self._slots, self._opts, self._fn
+        given, seen = plan.given, {}
+
+        def all_state_in(state_vals, lr_vals, arg_arrays):
+            for slot, v in zip(slots, state_vals):
+                slot.set(v)
+            for opt, lr in zip(opts, lr_vals):
+                opt._lr_override = lr
+            try:
+                args, kwargs = _rebuild_args(treedef, meta, arg_arrays)
+                out = fn(*args, **kwargs)
+            finally:
+                for opt in opts:
+                    opt._lr_override = None
+            out_arrays, seen["out_spec"] = _flatten_out(out)
+            # a slot that still holds the very tracer it was given was not
+            # written: read-only for this program
+            seen["written"] = [i for i, (slot, v)
+                               in enumerate(zip(slots, state_vals))
+                               if slot.get() is not v]
+            seen["n_out"] = len(out_arrays)
+            return out_arrays + [slots[i].get() for i in seen["written"]]
+
+        saved = [slot.get() for slot in slots]
+        try:
+            closed = _with_room(
+                lambda: jax.make_jaxpr(all_state_in)(state, lr_vals, arrays))
+        finally:
+            # no tracer outlives the trace: the state is what it was
+            for slot, v in zip(slots, saved):
+                slot.set(v)
+                slot.sanitize()
+        plan.set_written(seen["written"], len(slots))
+        plan.out_spec = seen["out_spec"]
+        written, read, n_out = plan.written, plan.read, seen["n_out"]
+
+        def _functional(written_vals, read_vals, lr_vals, arg_arrays,
+                        given_arrays):
+            state_vals = [None] * len(slots)
+            for i, v in zip(written + read, written_vals + read_vals):
+                state_vals[i] = v
+            arg_arrays = list(arg_arrays)
+            for i, v in zip(given, given_arrays):
+                arg_arrays[i] = v
+            outs = jax.core.eval_jaxpr(closed.jaxpr, closed.consts,
+                                       *state_vals, *lr_vals, *arg_arrays)
+            return outs[:n_out], outs[n_out:]
+
+        # Written state is donated so XLA reuses its buffers for the updated
+        # state (in-place optimizer semantics, reference: inplace op pass),
+        # and so are the argument leaves the constructor's caller gave up
+        # (``given_arrays``; empty unless declared). A donation-induced
+        # wrongness would be TPU-only in effect — PADDLE_TPU_NO_DONATE=1
+        # disables both as a bisect axis.
+        return jax.jit(_functional,
+                       donate_argnums=(0, 4) if plan.donates else ())
+
+    def _build(self, key, treedef, meta, given, state, lr_vals, arrays,
+               compile=True):
+        """The plan of a signature this function has not run yet, and the
+        first call's operands."""
         # every signature-cache miss materializes ONE program, counted
         # exactly once with its source: "fresh" paid a trace + XLA
         # compile, "disk" deserialized a persisted executable (warm
@@ -784,91 +920,68 @@ class StaticFunction:
         # "decode compiles exactly once" invariant stays a monitorable
         # metric (paddle_tpu_jit_compiles_total{fn,source}), and a
         # recompile storm shows up on /metrics before it shows up as a
-        # latency cliff. ``example`` (the first call's operands) is what
-        # the executable is compiled for; without it (``lower()``) nothing
+        # latency cliff. Without ``compile`` (``lower()``) nothing
         # compiles here and a call runs ``jitted``.
         from ..metrics import get_registry
 
-        slots, opts, fn = self._slots, self._opts, self._fn
-        holder = _Compiled(None, given=given)
-
-        def _functional(state_vals, lr_vals, arg_arrays, given_arrays):
-            for slot, v in zip(slots, state_vals):
-                slot.set(v)
-            for opt, lr in zip(opts, lr_vals):
-                opt._lr_override = lr
-            if given:
-                arg_arrays = list(arg_arrays)
-                for i, v in zip(given, given_arrays):
-                    arg_arrays[i] = v
-            try:
-                args, kwargs = _rebuild_args(spec, arg_arrays, meta)
-                out = fn(*args, **kwargs)
-            finally:
-                for opt in opts:
-                    opt._lr_override = None
-            out_arrays, out_spec = _flatten_out(out)
-            holder.out_spec = out_spec
-            new_state = [slot.get() for slot in slots]
-            return out_arrays, new_state
-
-        # State buffers are donated so XLA reuses them for the updated state
-        # (in-place optimizer semantics, reference: inplace op pass), and so
-        # are the argument leaves the constructor's caller gave up
-        # (``given_arrays``; empty unless declared). A donation-induced
-        # wrongness would be TPU-only in effect — PADDLE_TPU_NO_DONATE=1
-        # disables both as a bisect axis.
-        donate = () if _donation_off() else (0, 3)
-        holder.jitted = jax.jit(_functional, donate_argnums=donate)
+        plan = _Plan(given, donates=not _donation_off())
         source = "fresh"
         registry = get_registry()
-        if example is not None:
-            cache_dir = self._resolve_cache_dir()
-            full_key = path = ent = None
-            if cache_dir is not None:
-                full_key = self._persistent_key(key, example, given)
-                path = os.path.join(
-                    cache_dir,
-                    f"{self.__name__}-"
-                    f"{hashlib.sha256(full_key.encode()).hexdigest()[:32]}"
-                    ".jitcache")
-                ent = _MEMORY_CACHE.get(full_key)
-                if ent is not None:
-                    source = "memory"
-                else:
-                    ent = _load_disk_entry(path, full_key)
-                    if ent is not None:
-                        _MEMORY_CACHE[full_key] = ent
-                        source = "disk"
+        cache_dir = self._resolve_cache_dir() if compile else None
+        full_key = path = ent = None
+        if cache_dir is not None:
+            full_key = self._persistent_key(key, state, lr_vals, given)
+            path = os.path.join(
+                cache_dir,
+                f"{self.__name__}-"
+                f"{hashlib.sha256(full_key.encode()).hexdigest()[:32]}"
+                ".jitcache")
+            ent = _MEMORY_CACHE.get(full_key)
             if ent is not None:
-                holder.aot, holder.out_spec = ent
+                source = "memory"
             else:
-                try:
-                    # the trace fires _functional, which captures
-                    # out_spec on `holder` as a side effect
-                    holder.aot = holder.jitted.lower(*example).compile()
-                except Exception:
-                    # an unlowerable corner falls back to the plain
-                    # jax.jit path — correctness never depends on the
-                    # ahead-of-time build
-                    holder.aot = None
-                if holder.aot is not None and full_key is not None:
-                    _MEMORY_CACHE[full_key] = (holder.aot, holder.out_spec)
-                    _store_disk_entry(path, full_key, holder.aot,
-                                      holder.out_spec)
-            if holder.aot is not None:
-                # what donation bought, as the executable has it: bytes of
-                # arguments whose buffers the outputs are written into. A
-                # step that should update a pool in place reads the pool's
-                # bytes here; 0 means every donated buffer was copied.
-                registry.gauge(
-                    "paddle_tpu_jit_aliased_bytes",
-                    "Bytes of argument buffers that the StaticFunction "
-                    "program built last for this fn writes its outputs "
-                    "into (XLA memory analysis): donated state and "
-                    "donate_argnums leaves updated in place",
-                    labels=("fn",),
-                ).labels(fn=self.__name__).set(float(_aliased_bytes(holder.aot)))
+                ent = _load_disk_entry(path, full_key)
+                if ent is not None:
+                    _MEMORY_CACHE[full_key] = ent
+                    source = "disk"
+        if ent is not None:
+            plan.aot, plan.out_spec, written = ent
+            plan.set_written(written, len(state))
+            abstract = jax.tree_util.tree_map(_abstract,
+                                              (state, lr_vals, arrays))
+            plan.trace = lambda: self._trace(plan, treedef, meta, *abstract)
+        else:
+            plan._jitted = self._trace(plan, treedef, meta, state, lr_vals,
+                                       arrays)
+        operands = plan.operands(state, lr_vals, arrays)
+        plan.abstract = jax.tree_util.tree_map(_abstract, operands)
+        if compile and ent is None:
+            try:
+                plan.aot = _with_room(
+                    lambda: plan.jitted.lower(*operands).compile())
+            except Exception:
+                # an unlowerable corner falls back to the plain
+                # jax.jit path — correctness never depends on the
+                # ahead-of-time build
+                plan.aot = None
+            if plan.aot is not None and full_key is not None:
+                _MEMORY_CACHE[full_key] = (plan.aot, plan.out_spec,
+                                           plan.written)
+                _store_disk_entry(path, full_key, plan.aot, plan.out_spec,
+                                  plan.written)
+        if plan.aot is not None:
+            # what donation bought, as the executable has it: bytes of
+            # arguments whose buffers the outputs are written into. A
+            # step that should update a pool in place reads the pool's
+            # bytes here; 0 means every donated buffer was copied.
+            registry.gauge(
+                "paddle_tpu_jit_aliased_bytes",
+                "Bytes of argument buffers that the StaticFunction "
+                "program built last for this fn writes its outputs "
+                "into (XLA memory analysis): written state and "
+                "donate_argnums leaves updated in place",
+                labels=("fn",),
+            ).labels(fn=self.__name__).set(float(_aliased_bytes(plan.aot)))
         registry.counter(
             "paddle_tpu_jit_compiles_total",
             "XLA programs materialized into a StaticFunction signature "
@@ -876,7 +989,7 @@ class StaticFunction:
             "loaded the persistent compile cache, \"memory\" reused a "
             "process-wide build", labels=("fn", "source"),
         ).labels(fn=self.__name__, source=source).inc()
-        return holder
+        return plan, operands
 
     # -- call ----------------------------------------------------------------
     def _setup_no_warmup(self):
@@ -897,29 +1010,42 @@ class StaticFunction:
         self._warmed_up = True
 
     def _prepare(self, args, kwargs, compile=True):
-        """One call's signature key, its program (built on a miss; compiled
-        too unless ``compile=False``) and the operands of ``_functional``:
-        state, learning rates, the argument leaves with None where a leaf
-        is given up, and the given-up leaves."""
-        arrays, meta, spec = _flatten_args((args, kwargs))
-        key = (
-            _spec_key(spec, arrays, meta),
-            tuple(l.training for l in self._layers),
-        )
-        compiled = self._cache.get(key)
-        given = self._given_up(spec) if compiled is None else compiled.given
-        state_vals, arrays = _unalias([s.get() for s in self._slots],
-                                      arrays, given)
+        """One call's plan (built on a miss; compiled too unless
+        ``compile=False``), its operands, and whether this call built the
+        plan."""
+        arrays, meta, sig, treedef = _flatten_args(args, kwargs)
+        key = (sig, tuple(l.training for l in self._layers))
+        plan = self._cache.get(key)
+        state = [s.get() for s in self._slots]
         lr_vals = [jnp.asarray(o.get_lr(), jnp.float32) for o in self._opts]
-        kept = list(arrays)
-        for i in given:
-            kept[i] = None
-        operands = (state_vals, lr_vals, kept, [arrays[i] for i in given])
-        if compiled is None:
-            compiled = self._build(spec, tuple(meta), key,
-                                   operands if compile else None, given)
-            self._cache[key] = compiled
-        return key, compiled, operands
+        built = plan is None
+        if built:
+            plan, operands = self._build(
+                key, treedef, meta, self._given_up(args), state, lr_vals,
+                arrays, compile)
+            self._cache[key] = plan
+            self._abstract_args[key] = plan.abstract
+        else:
+            operands = plan.operands(state, lr_vals, arrays)
+            if plan is not self._latest_plan:
+                # move-to-end: dict order = recency
+                self._abstract_args[key] = self._abstract_args.pop(key)
+        self._latest_plan = plan
+        return plan, operands, built
+
+    def _count_call(self, built: bool) -> None:
+        if self._calls is None:
+            from ..metrics import get_registry
+
+            calls = get_registry().counter(
+                "paddle_tpu_jit_calls_total",
+                "Calls of a StaticFunction's compiled program, by path: "
+                "\"plan\" launched from the signature's launch plan, "
+                "\"build\" had to build it first (trace, compile or a "
+                "persistent-cache load)", labels=("fn", "path"))
+            self._calls = tuple(calls.labels(fn=self.__name__, path=p)
+                                for p in ("plan", "build"))
+        self._calls[built].inc()
 
     def __call__(self, *args, **kwargs):
         if not self._warmed_up:
@@ -927,30 +1053,22 @@ class StaticFunction:
                 self._setup_no_warmup()
             else:
                 return self._warmup(args, kwargs)
-        key, compiled, operands = self._prepare(args, kwargs)
-        self._abstract_args.pop(key, None)  # move-to-end: dict order = recency
-        # mesh shardings are part of the program (a re-lowering without
-        # them is another program); single-device placement is not
-        self._abstract_args[key] = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(
-                a.shape, a.dtype,
-                sharding=(a.sharding if isinstance(
-                    getattr(a, "sharding", None), NamedSharding) else None)),
-            operands)
-        if compiled.aot is not None:
+        plan, operands, built = self._prepare(args, kwargs)
+        self._count_call(built)
+        if plan.aot is not None:
             try:
-                out_arrays, new_state = compiled.aot(*operands)
+                out_arrays, new_written = plan.aot(*operands)
             except Exception:
                 # a calling-convention mismatch (aval drift the key
                 # missed, a sharding the executable was not compiled for)
                 # degrades to the jax.jit path for good — the signature
                 # check fails BEFORE execution, so the donated buffers
                 # are still intact for the retry
-                compiled.aot = None
-                out_arrays, new_state = compiled.jitted(*operands)
+                plan.aot = None
+                out_arrays, new_written = plan.jitted(*operands)
         else:
-            out_arrays, new_state = compiled.jitted(*operands)
-        for slot, v in zip(self._slots, new_state):
-            slot.set(v)
-            slot.sanitize()
-        return _rebuild_out(compiled.out_spec, out_arrays)
+            out_arrays, new_written = plan.jitted(*operands)
+        slots = self._slots
+        for i, v in zip(plan.written, new_written):
+            slots[i].set(v)
+        return _rebuild_out(plan.out_spec, out_arrays)

@@ -1897,13 +1897,11 @@ class ServingEngine:
             self._step_prog = self._compile_with_retry(
                 "serving.compile_step", self._make_step)
         t_prog = time.perf_counter()
+        # the nine grids go up in one transfer call, not nine
+        grids = jax.device_put([tok, tok_pos, tok_bt, tok_adp, sample_rows,
+                                sample_pos, temps, seeds, fsm_state])
         res = self._step_prog(
-            Tensor(jnp.asarray(tok)), Tensor(jnp.asarray(tok_pos)),
-            Tensor(jnp.asarray(tok_bt)), Tensor(jnp.asarray(tok_adp)),
-            Tensor(jnp.asarray(sample_rows)),
-            Tensor(jnp.asarray(sample_pos)), Tensor(jnp.asarray(temps)),
-            Tensor(jnp.asarray(seeds)), Tensor(jnp.asarray(fsm_state)),
-            self._grammar_device,
+            *map(Tensor, grids), self._grammar_device,
             *self.adapters.arrays(), *self.pool.step_flat())
         nxt, fin, flat = res[0], res[1], res[2:]
         self.pool.set_step_flat(flat)

@@ -3,6 +3,7 @@
 Mirrors the reference's dy2static test strategy (SURVEY.md §4: run the same
 nn code eagerly and compiled, compare outputs — test/dygraph_to_static/).
 """
+import jax
 import numpy as np
 import pytest
 
@@ -10,6 +11,7 @@ import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu import jit
+from paddle_tpu.jit import static_function
 
 
 def _make_model_and_data(seed=7):
@@ -191,3 +193,140 @@ def test_program_text_and_cost_analysis_leave_state_usable():
     assert step.cost_analysis()["flops"] > 0
     assert float(step(xb, yb).numpy()) < first  # still trains
     assert len(step._cache) == 1
+
+
+# ------------------------------------------------ launch plans (ISSUE 33)
+def _main_head(text):
+    """The one line that holds ``@main``'s parameters and results."""
+    head = text[text.index("func.func public @main("):]
+    return head[:head.index("\n")]
+
+
+def test_read_only_state_is_neither_donated_nor_returned():
+    """A function that reads its layer's parameters and never writes them
+    compiles a program with no state outputs, and calling it leaves every
+    parameter the same array in the same buffer."""
+    model, x, _ = _make_model_and_data()
+    ref = model(paddle.to_tensor(x)).numpy()
+    fwd = jit.StaticFunction(lambda t: model(t), observe=[model],
+                             warmup=False)
+    held = [(p._value, p._value.unsafe_buffer_pointer())
+            for p in model.parameters()]
+    for _ in range(4):
+        out = fwd(paddle.to_tensor(x))
+    np.testing.assert_allclose(out.numpy(), ref, rtol=1e-5, atol=1e-5)
+    assert fwd._plan().written == ()
+    head = _main_head(fwd.program_text())
+    assert "tf.aliasing_output" not in head
+    assert head.count("jax.result_info") == 1
+    for p, (array, ptr) in zip(model.parameters(), held):
+        assert p._value is array and not array.is_deleted()
+        assert array.unsafe_buffer_pointer() == ptr
+
+
+def test_slot_written_under_one_signature_and_read_under_another():
+    """Which slots a program writes is a fact of ONE signature: the same
+    function counts under `bump=True` and only reads the count under
+    `bump=False`; interleaved, both are right."""
+    count = paddle.to_tensor(np.zeros((2,), np.float32))
+
+    def fn(x, bump):
+        if bump:
+            paddle.add_(count, paddle.to_tensor(np.ones((2,), np.float32)))
+        return x + count
+
+    sf = jit.StaticFunction(fn, observe=[count], warmup=False)
+    x = paddle.to_tensor(np.full((2,), 10.0, np.float32))
+    want = 0.0
+    for bump in (True, False, False, True, True, False, True):
+        want += bump
+        held = count._value
+        np.testing.assert_allclose(sf(x, bump).numpy(), 10.0 + want)
+        np.testing.assert_allclose(count.numpy(), want)
+        assert held.is_deleted() == bump  # consumed only where written
+    assert len(sf._cache) == 2
+    slot = [i for i, s in enumerate(sf._slots)
+            if getattr(s, "t", None) is count]
+    assert sorted(list(p.written) for p in sf._cache.values()) == [[], slot]
+
+
+@pytest.mark.parametrize("depth", [1, 6])
+def test_second_call_does_not_ask_about_read_only_slots(monkeypatch, depth):
+    """On a call that reuses its plan nothing is rebuilt from the signature:
+    no ShapeDtypeStruct, and buffer pointers are asked of the one given-up
+    leaf and the one fresh argument — however many parameters the function
+    only reads."""
+    paddle.seed(3)
+    model = nn.Sequential(*[nn.Linear(4, 4) for _ in range(depth)])
+    sf = jit.StaticFunction(lambda x, scratch: (model(x), scratch * 2.0),
+                            observe=[model], warmup=False,
+                            donate_argnums=(1,))
+
+    def call():
+        return sf(paddle.to_tensor(np.ones((2, 4), np.float32)),
+                  paddle.to_tensor(np.ones((3, 4), np.float32)))
+
+    call()
+    asked = []
+    ptr, sds = static_function._buffer_ptr, jax.ShapeDtypeStruct
+    monkeypatch.setattr(static_function, "_buffer_ptr",
+                        lambda v: asked.append("ptr") or ptr(v))
+    monkeypatch.setattr(jax, "ShapeDtypeStruct",
+                        lambda *a, **k: asked.append("sds") or sds(*a, **k))
+    out, doubled = call()
+    assert asked == ["ptr", "ptr"]
+    np.testing.assert_allclose(doubled.numpy(), 2.0)
+    # a parameter that is replaced is looked at again, once
+    first = next(iter(model.parameters()))
+    first._value = first._value + 0.0
+    del asked[:]
+    call()
+    call()
+    assert asked == ["ptr"] * 3 + ["ptr"] * 2
+
+
+def test_introspection_answers_for_the_latest_key_after_plan_calls():
+    model, x, _ = _make_model_and_data()
+    fwd = jit.StaticFunction(lambda t: model(t), observe=[model],
+                             warmup=False)
+    big, small = paddle.to_tensor(x), paddle.to_tensor(x[:8])
+    fwd(big)
+    key_big = fwd._latest_key()
+    fwd(small)
+    key_small = fwd._latest_key()
+    assert key_big != key_small and list(fwd._cache) == [key_big, key_small]
+    for _ in range(2):  # plan calls: no build, and the order still follows
+        fwd(big)
+        assert fwd._latest_key() == key_big
+        assert "tensor<32x8xf32>" in fwd.program_text()
+    fwd(small)
+    assert fwd._latest_key() == key_small
+    assert "tensor<8x8xf32>" in fwd.program_text()
+    assert "tensor<32x8xf32>" in fwd.program_text(key_big)
+    assert fwd.cost_analysis()["flops"] < fwd.cost_analysis(key_big)["flops"]
+    assert "tensor<8x8xf32>" in fwd.lower(small).as_text()
+    assert len(fwd._cache) == 2
+
+
+def test_the_trace_runs_in_one_roomy_chunk_of_the_frame_stack():
+    """``_with_room`` is an ordinary call whose frame is large enough for
+    the interpreter to open one chunk that holds every frame below it
+    (PERF.md section 6, PR 33), and the trace goes through it."""
+    import sys
+
+    assert static_function._with_room.__code__.co_stacksize >= 1 << 16
+    assert static_function._with_room(lambda: 7) == 7
+    seen = []
+
+    def fn(t):
+        f, names = sys._getframe(), []
+        while f is not None:
+            names.append(f.f_code.co_name)
+            f = f.f_back
+        seen.append(names)
+        return t * 2.0
+
+    sf = jit.StaticFunction(fn, warmup=False, dy2static=False)
+    sf(paddle.to_tensor(np.ones((2,), np.float32)))
+    sf(paddle.to_tensor(np.ones((2,), np.float32)))
+    assert len(seen) == 1 and "_with_room" in seen[0]  # traced once, in it

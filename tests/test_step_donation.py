@@ -98,6 +98,45 @@ def test_step_aliases_every_pool_array_to_its_own_output(make_model,
         assert alias[-n_pool:] == [2 + i for i in range(n_pool)]
 
 
+def _jit_calls(fn, path):
+    fam = metrics.get_registry().get("paddle_tpu_jit_calls_total")
+    return 0.0 if fam is None else fam.sum_labels(fn=fn, path=path)
+
+
+@pytest.mark.parametrize("make_model,kv_dtype", _ENGINES)
+def test_step_donates_the_pool_and_nothing_else(make_model, kv_dtype,
+                                                monkeypatch):
+    """The serving step only READS the model: of its operands the pool's
+    arrays alone are donated, and they alone ride back beside `nxt, fin`.
+    Every bucket is built by one call and launched from its plan by every
+    other (`paddle_tpu_jit_calls_total{fn="serving_step", path}`)."""
+    calls = []
+    call = jit.StaticFunction.__call__
+    monkeypatch.setattr(
+        jit.StaticFunction, "__call__",
+        lambda self, *a, **k: calls.append(self.__name__) or call(self, *a, **k))
+    before = {p: _jit_calls("serving_step", p) for p in ("build", "plan")}
+    eng = _engine(make_model, kv_dtype)
+    params = [p._value for p in eng.model.parameters()]
+    while eng.has_work:
+        eng.step()
+    pool_bytes = sum(a.nbytes for a in _pool_arrays(eng.pool))
+    n_pool = eng.pool.step_stride * eng.pool.num_layers
+    assert len(eng.step_aliased_bytes()) >= 2
+    assert set(eng.step_aliased_bytes()) == {pool_bytes}
+    for text in eng.step_program_texts():
+        alias, n_results = _main_signature(text)
+        assert n_results == 2 + n_pool
+        assert [a for a in alias if a is not None] == [
+            2 + i for i in range(n_pool)]
+    assert all(p._value is v and not v.is_deleted()
+               for p, v in zip(eng.model.parameters(), params))
+    built = _jit_calls("serving_step", "build") - before["build"]
+    reused = _jit_calls("serving_step", "plan") - before["plan"]
+    assert built == eng.compile_counts()["step"] >= 2
+    assert calls.count("serving_step") == built + reused and reused >= 1
+
+
 @pytest.mark.parametrize("make_model,kv_dtype", _ENGINES)
 def test_step_consumes_the_pool_arrays(make_model, kv_dtype):
     """After a step the arrays the pool held before are deleted and the
@@ -207,9 +246,11 @@ def _s(v=2.0):
 
 
 def test_no_declared_argument_lowers_to_the_same_program():
-    """No declaration, no change of program: only the state is marked, the
-    argument leaves ride as before, and an empty declaration is the same
-    text."""
+    """No declaration, no change of program: the argument leaves ride as
+    before and an empty declaration is the same text. The function only
+    READS its state (the layer's parameters; the RNG key it never touches is
+    not even a parameter of the program), so nothing is marked and the
+    state adds no result."""
     lin, fn = _affine()
     texts = []
     for kw in ({}, {"donate_argnums": ()}):
@@ -220,10 +261,37 @@ def test_no_declared_argument_lowers_to_the_same_program():
         texts.append(sf.program_text())
     assert texts[0] == texts[1]
     alias, n_results = _main_signature(texts[0])
-    n_state = len(sf._slots)  # the layer's parameters and the RNG key
-    assert len(alias) == n_state + 3 and n_results == 2 + n_state
-    assert all(a is not None for a in alias[:n_state])
-    assert alias[n_state:] == [None] * 3
+    n_params = len(list(lin.parameters()))
+    assert len(sf._slots) == n_params + 1  # and the RNG key
+    assert len(alias) == n_params + 3 and n_results == 2
+    assert alias == [None] * (n_params + 3)
+    assert sf.aliased_bytes() == 0
+
+
+def test_a_written_slot_is_the_one_marked_and_returned():
+    """The twin: the same function also counts its calls in a tensor it
+    observes. That one slot is written, so it alone is donated (the first
+    parameter, aliasing its output behind the two results) and returned;
+    the parameters beside it stay read-only."""
+    lin, fn = _affine()
+    calls = paddle.to_tensor(np.zeros((3, 5), np.float32))
+
+    def counting(x, scratch, y):
+        paddle.add_(calls, paddle.to_tensor(np.ones((3, 5), np.float32)))
+        return fn(x, scratch, y)
+
+    sf = jit.StaticFunction(counting, observe=[lin, calls], warmup=False)
+    weight = lin.weight._value
+    for n in (1, 2, 3):
+        held = calls._value
+        sf(_x(), _x(2.0), _x(3.0))
+        np.testing.assert_allclose(calls.numpy(), float(n))
+        assert held.is_deleted() and lin.weight._value is weight
+    alias, n_results = _main_signature(sf.program_text())
+    n_params = len(list(lin.parameters()))
+    assert n_results == 3 and len(alias) == 1 + n_params + 3
+    assert alias == [2] + [None] * (n_params + 3)
+    assert sf.aliased_bytes() == held.nbytes
 
 
 def test_declared_argument_is_consumed_and_rides_last():
@@ -283,10 +351,45 @@ def test_executable_mismatch_degrades_before_any_buffer_is_consumed():
     (compiled,) = sf._cache.values()
 
     def refuses(*operands):
-        assert not any(a.is_deleted() for a in operands[3])
+        assert not any(a.is_deleted() for a in operands[-1])
         raise TypeError("compiled for another signature")
 
     compiled.aot = refuses
     s = _x(5.0)
     np.testing.assert_allclose(sf(_x(), s, _x(3.0))[1].numpy(), 10.0)
     assert s._value.is_deleted() and compiled.aot is None
+
+
+def test_old_convention_cache_entry_is_a_miss(tmp_path):
+    """A `.jitcache` entry as the all-state-donated convention stored it
+    (no record of the written slots) under this signature's file name is not
+    loaded and does not crash: the program is built fresh, and the key a new
+    entry is stored under names the convention."""
+    import pickle
+
+    lin, fn = _affine()
+
+    def build():
+        jit.clear_compile_cache(memory=True)
+        sf = jit.StaticFunction(fn, observe=[lin], warmup=False,
+                                cache_dir=str(tmp_path))
+        return sf(_x(), _x(2.0), _x(3.0))[0].numpy()
+
+    fam = metrics.get_registry().counter(
+        "paddle_tpu_jit_compiles_total", labels=("fn", "source"))
+    fresh = lambda: fam.sum_labels(fn=fn.__name__, source="fresh")  # noqa: E731
+    try:
+        want = build()
+        [path] = tmp_path.glob("*.jitcache")
+        entry = pickle.loads(path.read_bytes())
+        assert "written-state-donated" in entry["key"]
+        assert entry.pop("written") == ()
+        path.write_bytes(pickle.dumps(entry))
+        n = fresh()
+        np.testing.assert_array_equal(build(), want)
+        assert fresh() == n + 1
+        assert "written" in pickle.loads(path.read_bytes())  # stored anew
+        build()
+        assert fresh() == n + 1  # and that one loads
+    finally:
+        jit.clear_compile_cache(memory=True)
