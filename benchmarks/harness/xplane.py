@@ -7,7 +7,9 @@ plane ``/device:TPU:<n>`` per chip whose line ``XLA Ops`` carries one event
 per executed HLO operation (start and duration in ns; a ``while`` encloses
 the operations of its body, so per-operation time is SELF time), and a plane
 ``/host:CPU`` whose thread lines carry ``TraceAnnotation`` spans on the same
-clock. An event's name is the whole HLO instruction (``%paged_attention.24 =
+clock: the harness's ``bench.*`` spans and the program's own (``sweep``, the
+router's, around ``step`` and its phases ``step.plan`` ... ``step.land``).
+An event's name is the whole HLO instruction (``%paged_attention.24 =
 bf16[...] custom-call(...), custom_call_target="tpu_custom_call", ...``):
 operations are keyed here by the instruction's name without its number
 (``paged_attention``, ``convolution_add_fusion``, ``fusion``), and a Mosaic
@@ -23,7 +25,9 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 WINDOW_SPAN = "bench.window"
-HOST_SPAN_PREFIX = "bench."
+# host spans that idle time is charged to: the harness's, and the program's
+# sweep > step > step.<phase> (paddle_tpu/serving/tracing.py)
+HOST_SPAN_PREFIXES = ("bench.", "sweep", "step")
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
@@ -132,7 +136,7 @@ def summarize(trace_dir_or_file: str, n_chips: int) -> Summary:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if not e.name.startswith(HOST_SPAN_PREFIX):
+                    if not e.name.startswith(HOST_SPAN_PREFIXES):
                         continue
                     span = (e.start_ns, e.start_ns + e.duration_ns, e.name)
                     if e.name == WINDOW_SPAN:
@@ -168,9 +172,39 @@ def summarize(trace_dir_or_file: str, n_chips: int) -> Summary:
         n_chips=n, n_events=n_events)
 
 
+def _innermost(spans: List[Tuple[float, float, str]]
+               ) -> List[Tuple[float, float, str]]:
+    """Nested (start, end, name) spans as disjoint pieces in time order,
+    each named by the innermost span that is open there."""
+    out: List[Tuple[float, float, str]] = []
+    stack: List[Tuple[float, str]] = []   # (end, name), outermost first
+    t = 0.0                               # pieces are written up to here
+
+    def close(upto: float):
+        nonlocal t
+        while stack and stack[-1][0] <= upto:
+            end, name = stack.pop()
+            if end > t:
+                out.append((t, end, name))
+                t = end
+
+    for a, b, name in sorted(spans, key=lambda s: (s[0], -s[1])):
+        close(a)
+        if stack:
+            if a > t:
+                out.append((t, a, stack[-1][1]))
+            b = min(b, stack[-1][0])   # a child ends with its parent
+        t = a
+        stack.append((b, name))
+    close(float("inf"))
+    return out
+
+
 def _idle_by_span(busy, lo, hi, host_spans) -> Dict[str, float]:
-    """Idle time of one chip, each gap charged to the innermost ``bench.*``
-    host span that covers its middle (``outside`` where none does)."""
+    """Idle time of one chip by what the host was doing: every part of a
+    gap goes to the innermost host span open during that part (``outside``
+    where none is), so a gap that spans several phases is split among them
+    by overlap."""
     gaps = []
     t = lo
     for a, b in busy:
@@ -179,19 +213,20 @@ def _idle_by_span(busy, lo, hi, host_spans) -> Dict[str, float]:
         t = max(t, b)
     if hi > t:
         gaps.append((t, hi))
-    spans = sorted(host_spans)
+    pieces = _innermost(host_spans)
     out: Dict[str, float] = {}
     j = 0
     for a, b in gaps:
-        mid = 0.5 * (a + b)
-        while j < len(spans) and spans[j][1] < mid:
+        while j < len(pieces) and pieces[j][1] <= a:
             j += 1   # ended before this gap, so before every later one
-        name, width = "outside", float("inf")
-        for k in range(j, len(spans)):
-            s0, s1, sname = spans[k]
-            if s0 > mid:
+        covered = 0.0
+        for k in range(j, len(pieces)):
+            p0, p1, name = pieces[k]
+            if p0 >= b:
                 break
-            if s1 >= mid and s1 - s0 < width:
-                name, width = sname, s1 - s0
-        out[name] = out.get(name, 0.0) + (b - a)
+            part = min(b, p1) - max(a, p0)
+            out[name] = out.get(name, 0.0) + part
+            covered += part
+        if b - a > covered:
+            out["outside"] = out.get("outside", 0.0) + (b - a) - covered
     return out

@@ -3,7 +3,9 @@ cell, a traffic mix and a metric are found by name, with no table in code."""
 import json
 import os
 
-from benchmarks.harness import spec
+import pytest
+
+from benchmarks.harness import bounds, spec
 
 
 def _bench():
@@ -67,8 +69,6 @@ def test_a_cell_is_added_by_new_files_and_entries_only(tmp_path):
 
 
 def test_every_cell_has_limits_and_a_cell_without_them_does_not_run():
-    import pytest
-
     from benchmarks.harness import runner
     from benchmarks.harness.compare import Compared, all_ok
 
@@ -115,3 +115,68 @@ def test_benchmark_json_keeps_to_the_contracts_form():
     assert len({m["name"] for m in metrics}) == len(metrics)
     four = sum(w["chips"] == 4 for w in bench["workloads"])
     assert four <= max(1, len(bench["workloads"]) // 4)
+
+
+# ---- the bounds against the runs they were set from (benchmarks/bounds.json)
+def test_spread_is_the_range_without_the_run_farthest_from_the_median():
+    # median 100.5; 110 is farthest and goes: (102 - 98) / 100.5
+    assert bounds.spread([100, 98, 101, 110, 102, 99]) == 4 / 100.5
+    # the low end is farthest here
+    assert bounds.spread([10.0, 10.1, 9.0, 10.2]) == (10.2 - 10.0) / 10.05
+    assert bounds.spread([5.0, 5.0, 5.0, 5.0]) == 0.0
+    # at least twice the mean, at most eight times the wider, within 1-10%
+    lo, hi = bounds.rule_range([[100, 101, 102, 103, 120],
+                                [100, 100, 101, 101, 90]])
+    assert lo == pytest.approx(3 / 102 + 1 / 100) and hi == 0.10
+    assert bounds.rule_range([[1000.0, 1000.01, 1000.02, 999.99]] * 2) == \
+        (0.01, 0.01)
+
+
+def test_every_metric_a_cell_reports_has_its_runs_recorded():
+    bench, rec = _bench(), bounds.load()
+    assert len(rec["commit"]) == 40
+    for w in bench["workloads"]:
+        cell = spec.load_cell(w["name"])
+        got = rec["cells"][w["name"]]
+        seeds = got["seeds"]
+        assert len(set(seeds)) == len(seeds) >= 4
+        assert len(got["sets"]) == 2    # the same seeds in both, in order
+        for m in cell.end_to_end:
+            for one in got["sets"]:
+                values = one["runs"][m["name"]]
+                assert len(values) == len(seeds)
+                assert all(v > 0 for v in values)
+
+
+def test_every_bound_lies_inside_the_rules_range_of_its_recorded_runs():
+    bench, rec = _bench(), bounds.load()
+    rows = bounds.table(bench, rec)
+    assert [r["metric"] for r in rows] == [m["name"]
+                                           for m in bench["end_to_end"]]
+    for r in rows:
+        held = rec["bounds"][r["metric"]]
+        assert held["bound"] == r["bound"]          # the record says the same
+        assert held["cell"] == r["cell"]
+        assert held["spreads"] == pytest.approx(r["spreads"], rel=1e-9)
+        assert r["lowest"] <= r["bound"] <= r["highest"], r
+        assert round(r["bound"] / bounds.STEP, 9) % 1 == 0   # half percents
+        if r["metric"] in bounds.FIXED:
+            assert r["bound"] == bounds.FIXED[r["metric"]]
+
+
+def test_the_serve_bounds_hold_the_64_slot_witness_where_the_range_reaches():
+    """A new 64-slot closed loop has to spread by at most half a bound to be
+    admitted under it: the witness's recorded runs say whether it would."""
+    bench, rec = _bench(), bounds.load()
+    wit = rec["witness"]
+    by_name = {r["metric"]: r for r in bounds.table(bench, rec)}
+    assert len(wit["sets"]) == 2
+    for metric, held in wit["admitted"].items():
+        sets = [one["runs"][metric] for one in wit["sets"]]
+        assert all(len(v) == len(wit["seeds"]) for v in sets)
+        mean = sum(bounds.spread(v) for v in sets) / len(sets)
+        assert held["mean_spread"] == pytest.approx(mean, rel=1e-9)
+        row = by_name[metric]
+        assert held["admitted"] == (mean <= row["bound"] / 2)
+        # not admitted only where the rule's range ends below it
+        assert held["admitted"] or row["bound"] == row["highest"]
