@@ -1,2 +1,2 @@
-"""One module per way of building and driving a system under test, found by
-the name a configuration file gives under ``entries``."""
+"""One module per family and kind of traffic, ``<family>_<kind>.py``: what
+builds and drives that family's system under test (``spec.Cell.entry``)."""

@@ -17,9 +17,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..harness import reference, traffic as traffic_mod, weights as W
+from ..families.gpt import model as gpt_common, reference, weights as W
+from ..harness import traffic as traffic_mod
 from ..harness.compare import Compared, worst_leaf_gap
-from . import gpt_common
 
 KIND = "train"
 FOLLOWED_STEPS = 3      # the reference follows these

@@ -1,8 +1,11 @@
-"""What both GPT entries need from the program: the model built from a
-configuration file's keys, with the benchmark's seeded weights in it."""
+"""What the GPT family needs from the program: the model built from a
+configuration file's keys, with the benchmark's seeded weights in it, and
+what its pool has to hold."""
 from __future__ import annotations
 
 from typing import Any, Dict, Iterator, Tuple
+
+from . import weights as W
 
 # reference leaf -> attribute path under the program's decoder layer
 _LAYER_LEAVES = {
@@ -60,3 +63,24 @@ def load_weights(model, weights: Dict[str, Any]) -> None:
         if tuple(v.shape) != tuple(p.shape):
             raise ValueError(f"{name}: seeded {v.shape} vs program {p.shape}")
         p._set_value(v.astype(p._value.dtype))
+
+
+def serve_model(cfg: Dict[str, Any], seed: int):
+    """The program's model as it is served (``amp`` O2 bfloat16), the seeded
+    weights in it."""
+    from paddle_tpu import amp
+    from paddle_tpu.models import GPTForCausalLM
+
+    model = GPTForCausalLM(gpt_config(cfg))
+    model = amp.decorate(model, level="O2", dtype="bfloat16")
+    load_weights(model, W.make_weights(cfg, seed))
+    return model
+
+
+def pool_args(model, serving: Dict[str, Any]) -> Dict[str, Any]:
+    """What ``Router.add_model`` needs to size this model's pool: keys and
+    values of every layer in bfloat16 pages, as many as ``kv_pool_bytes``
+    buys."""
+    from ...harness.serve_loop import paged_kv_pool_args
+
+    return paged_kv_pool_args(model, serving)

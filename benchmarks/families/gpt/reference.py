@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -153,51 +153,6 @@ def logits_at(weights, ids, rows, cfg, prec: str = "f32"):
                             weights["lnf_b"], x,
                             jnp.asarray(rows, jnp.int32),
                             float(cfg["layer_norm_epsilon"]), prec)
-
-
-# ------------------------------------------------ served tokens vs reference
-def served_gaps(weights, cfg, requests: Sequence[Tuple[np.ndarray, Sequence[int]]],
-                rows_per_block: int = 4, control: Optional[str] = None):
-    """For each (prompt, served tokens) run the reference once over prompt +
-    tokens and read, at every served position, how far the served token's
-    float32 logit lies below the reference's best, in units of that row's
-    logit standard deviation. Returns (gaps [n_tokens], exact matches).
-
-    With ``control`` (a lower precision) the token judged at each position is
-    the one that precision puts first on the same prefix, not the served one.
-    """
-    width = max(len(p) + len(t) for p, t in requests)
-    width = -(-width // 128) * 128
-    gaps: List[np.ndarray] = []
-    exact = 0
-    for lo in range(0, len(requests), rows_per_block):
-        blk = list(requests[lo:lo + rows_per_block])
-        while len(blk) < rows_per_block:   # one compiled shape per cell
-            blk.append((blk[0][0][:1], []))
-        ids = np.zeros((len(blk), width), np.int32)
-        rows, toks = [], []
-        for r, (prompt, served) in enumerate(blk):
-            seq = np.concatenate([np.asarray(prompt, np.int32),
-                                  np.asarray(served, np.int32)])
-            ids[r, :seq.size] = seq   # right-padded: causal rows never see it
-            for j, tok in enumerate(served):
-                rows.append((r, len(prompt) + j - 1))
-                toks.append(int(tok))
-        if not rows:
-            continue
-        pad = -len(rows) % 64   # few distinct row counts, so few compiles
-        rows_p = np.asarray(rows + [rows[0]] * pad, np.int32)
-        ref = np.asarray(logits_at(weights, ids, rows_p, cfg, "f32"))
-        ref = ref[:len(rows)]
-        judged = np.asarray(toks)
-        if control is not None:
-            low = np.asarray(logits_at(weights, ids, rows_p, cfg, control))
-            judged = np.argmax(low[:len(rows)], axis=-1)
-        best = ref.max(axis=-1)
-        got = ref[np.arange(len(rows)), judged]
-        gaps.append((best - got) / ref.std(axis=-1))
-        exact += int(np.sum(got == best))
-    return np.concatenate(gaps) if gaps else np.zeros(0), exact
 
 
 # --------------------------------------------------------------- training

@@ -1,7 +1,7 @@
 """GPT weights from ``--seed``: one jitted call, on the device, in the type
 they are served and trained in (bfloat16).
 
-The layout is the reference's (harness/reference.py); an entry copies the
+The layout is the reference's (reference.py, beside this file); model.py copies the
 leaves into the program's parameters by name. Distribution: the GPT-2/GPT-3
 initialisation, N(0, 0.02) for matrices and embeddings with the two
 residual projections scaled by 1/sqrt(2 L), LayerNorm gains 1 and shifts 0,
@@ -16,15 +16,9 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from ...harness.traffic import seed_key
+
 STD = 0.02
-
-
-def seed_key(seed: int):
-    """A PRNG key from any non-negative whole number (the driver's seeds
-    pass 2**31, which int32 does not hold)."""
-    seed = int(seed)
-    return jax.random.fold_in(
-        jax.random.PRNGKey(seed & 0x7FFFFFFF), (seed >> 31) & 0x7FFFFFFF)
 
 
 def shapes(cfg: Dict[str, Any]) -> Dict[str, Any]:

@@ -22,6 +22,7 @@ class Ctx:
     def __init__(self, cell: spec.Cell, seed: int, devices, t_start: float):
         self.cell, self.seed = cell, seed
         self.devices, self.t_start = devices, t_start
+        self.family = spec.load_family(cell.family)
 
     def since_start(self) -> float:
         return time.perf_counter() - self.t_start
@@ -38,7 +39,7 @@ class Run:
                  device: Dict[str, Any], trace: Optional[xplane.Summary]):
         self.record, self.setup_s = record, setup_s
         self.device, self.trace = device, trace
-        self.config = ctx.cell.config
+        self.config, self.family = ctx.cell.config, ctx.family
         self.chips = len(ctx.devices)
         self.peaks = (chip.peaks(device["kind"])
                       if device["platform"] == "tpu" else None)

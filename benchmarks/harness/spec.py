@@ -1,11 +1,13 @@
 """Reads BENCHMARK.json and finds a cell's files by the names it gives.
 
-Nothing here knows a cell, a configuration, a traffic mix or a metric by
-name: a workload names its ``config`` and ``traffic``; the configuration's
-``file`` is in BENCHMARK.json (it names the family of entry modules under
-``benchmarks/entries/``); the traffic mix is
-``benchmarks/traffic/<traffic>.json``; a metric is
-``benchmarks/end_to_end/<name>.py`` or ``benchmarks/layer_metrics/<name>.py``.
+Nothing here knows a cell, a configuration, a family, a traffic mix or a
+metric by name: a workload names its ``config`` and ``traffic``; the
+configuration's ``file`` is in BENCHMARK.json and names its family under
+``entry`` (``benchmarks/families/<family>/`` holds the model's binding,
+weights, reference and work count, ``benchmarks/entries/<family>_<kind>.py``
+drives it); the traffic mix is ``benchmarks/traffic/<traffic>.json``; a
+metric is ``benchmarks/end_to_end/<name>.py`` or
+``benchmarks/layer_metrics/<name>.py``.
 """
 from __future__ import annotations
 
@@ -32,12 +34,18 @@ class Cell:
     per_layer: List[Dict[str, Any]]
 
     @property
+    def family(self) -> str:
+        """Name of the package under benchmarks/families/ that holds what is
+        this model's alone."""
+        return self.config["entry"]
+
+    @property
     def entry(self) -> str:
         """Name of the module under benchmarks/entries/ that builds and
         drives this cell: ``<the configuration's entry>_<the traffic's
         kind>``, so a new kind of traffic brings its module and edits no
         configuration, and a new model family brings its own."""
-        return f"{self.config['entry']}_{self.traffic['kind']}"
+        return f"{self.family}_{self.traffic['kind']}"
 
 
 def _read_json(path: str) -> Dict[str, Any]:
@@ -87,3 +95,9 @@ def load_reader(kind: str, name: str):
 
 def load_entry(name: str):
     return importlib.import_module(f"benchmarks.entries.{name}")
+
+
+def load_family(name: str):
+    """The package benchmarks/families/<name> (families/__init__.py says
+    what it provides)."""
+    return importlib.import_module(f"benchmarks.families.{name}")

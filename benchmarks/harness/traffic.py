@@ -39,6 +39,16 @@ from typing import Any, Dict, Iterator, List, Tuple
 import numpy as np
 
 
+def seed_key(seed: int):
+    """A PRNG key from any non-negative whole number (the driver's seeds
+    pass 2**31, which int32 does not hold)."""
+    import jax
+
+    seed = int(seed)
+    return jax.random.fold_in(
+        jax.random.PRNGKey(seed & 0x7FFFFFFF), (seed >> 31) & 0x7FFFFFFF)
+
+
 def train_batches(traffic: Dict[str, Any], vocab: int,
                   seed: int) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
     """Endless (ids, labels) int64 [batch, seq_len]; every row differs."""

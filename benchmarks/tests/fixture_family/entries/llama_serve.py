@@ -1,5 +1,4 @@
-"""Serving entry of the GPT family: the harness's closed loop over the
-family's model, pool and reference."""
+"""Serving entry of the second family: the harness's closed loop over it."""
 from ..harness import serve_loop
 
 

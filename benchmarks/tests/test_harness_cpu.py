@@ -105,9 +105,10 @@ def test_served_token_altered_where_it_is_produced_is_not_correct(monkeypatch):
 
 
 def test_a_compile_inside_the_window_fails_the_run(monkeypatch):
-    from benchmarks.entries import gpt_serve
+    from benchmarks.harness import serve_loop
 
-    monkeypatch.setattr(gpt_serve.ServeRun, "_warm_buckets", lambda self: None)
+    monkeypatch.setattr(serve_loop.ServeRun, "_warm_buckets",
+                        lambda self: None)
     tiny.TRAFFIC["serve-cold"] = dict(tiny.TRAFFIC["serve-tiny"],
                                       ramp_finished=0, callers=1)
     try:
