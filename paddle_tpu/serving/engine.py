@@ -89,8 +89,8 @@ __all__ = ["ServingEngine"]
 
 _MIN_GRID_TOKENS = 16
 _engine_counter = itertools.count()
-# the grid counters of a step that ran no rows (tracing.COUNTERS["step"]
-# less its last, ``landed``)
+# the grid counters of a call that landed no step (tracing.COUNTERS["step"]
+# up to ``kv_held``)
 _NO_GRID = (0, 0, 0, 0, 0, 0, 0, 0)
 
 faults.declare_point(
@@ -101,7 +101,7 @@ faults.declare_point(
     "adoption + slot parking) — a raise retires that request with "
     "finish_reason=\"error\"; batch-mates proceed")
 faults.declare_point(
-    "serving.decode_step", "in _step_once, after the per-slot KV-room "
+    "serving.decode_step", "in _plan, after the per-slot KV-room "
     "loop and before the unified compiled step consumes the pools — arm "
     "call= here to corrupt state (e.g. pool.poison_seq), delay_s to trip "
     "the watchdog")
@@ -160,12 +160,17 @@ class _SeqState:
 
     __slots__ = ("req", "ids", "pos", "last_token", "gen", "t_last",
                  "t_admit", "inserted_nodes", "adp_slot", "fsm",
-                 "fsm_off", "fsm_state", "parked")
+                 "fsm_off", "fsm_state", "parked", "ahead", "ahead_tokens")
 
     def __init__(self, req: Request, ids: np.ndarray, pos: int):
         self.req = req
         self.ids = np.asarray(ids, np.int32).reshape(-1)
         self.pos = int(pos)          # tokens of KV written so far
+        # what the slot's launched, not yet landed rows advance it by: KV
+        # positions and sampled tokens. The next plan starts from
+        # pos + ahead; pos and gen only ever hold what has landed
+        self.ahead = 0
+        self.ahead_tokens = 0
         self.last_token = -1         # meaningful once prefill completes
         # generated ids (incl. eos when hit); journal-seeded for a
         # migrated request
@@ -200,6 +205,16 @@ class _SeqState:
     @property
     def prefilling(self) -> bool:
         return self.pos < self.ids.size
+
+
+class _Launch:
+    """One step on the device: launched, not yet landed. ``rows`` are
+    ``(slot, state, tokens, positions, is_chunk, n_draft, src)``, each
+    with the ``_SeqState`` it was planned for, so a row whose sequence
+    ended before it landed is recognised and thrown away."""
+
+    __slots__ = ("rows", "nxt", "fin", "counts", "t0", "t_prog", "fresh",
+                 "clips", "discarded")
 
 
 class ServingEngine:
@@ -262,7 +277,10 @@ class ServingEngine:
         # contiguous stream across engines
         self._trace = tracing.get_tracer()
         self._phase = None          # the open step.* span (tracing.Span)
-        self._grid_counts = _NO_GRID
+        # steps launched and not landed, oldest first: at most one between
+        # two step() calls (see step)
+        self._flight: List[_Launch] = []
+        self._no_prev = None        # `prev` with nothing in flight: zeros
         self.trunk = model._decode_trunk()
         n_layers, n_kv, head_dim = model._cache_spec()
         self.n_layers = n_layers
@@ -566,7 +584,7 @@ class ServingEngine:
             labels=_eng).labels(**self._lbl)
         self._m_grammar_states.set(1.0)
         # host-tier SLO guard (docs/OBSERVABILITY.md): pages restored by
-        # a BLOCKING prefetch inside _step_once — the unpark policy
+        # a BLOCKING prefetch inside _plan — the unpark policy
         # failed to hide the host→HBM copy before the slot's step
         self._m_prefetch_late = reg.counter(
             "paddle_tpu_serving_kv_prefetch_late_total",
@@ -707,7 +725,9 @@ class ServingEngine:
             return True
         for i, st in enumerate(self.slots):
             if st is not None and st.req.req_id == req_id:
+                # its rows in flight are thrown away when they land
                 self._retire_abnormal(st, slot=i, reason="cancelled")
+                self._drop_ended()
                 return True
         return False
 
@@ -792,7 +812,10 @@ class ServingEngine:
 
         No lifecycle counters move (the requests retire elsewhere), and
         pages are freed best-effort per sequence — a crashed engine's
-        pool may refuse, and its memory is being abandoned anyway."""
+        pool may refuse, and its memory is being abandoned anyway. A step
+        in flight is thrown away: only landed tokens are journaled, and
+        the sibling samples the in-flight ones again, identically."""
+        self._flight.clear()
         states: List[_SeqState] = []
         for i, st in enumerate(self.slots):
             if st is not None:
@@ -1011,18 +1034,32 @@ class ServingEngine:
         tokens + prompt chunks under the token budget) → retire. Returns
         requests that finished during this step.
 
+        A call lands exactly one step, and launches the next one BEFORE it
+        lands that one whenever the next plan needs nothing the landing
+        produces (:meth:`_lands_first`): the host plans, packs and launches
+        step k+1 while step k runs on the device, then waits for and lands
+        step k. A row whose token is step k's sample takes it from k's
+        output on the device (``prev``/``tok_src`` in :meth:`_make_step`),
+        and each slot is planned from ``pos + ahead``: what its rows in
+        flight will have written. So between two calls at most one step is
+        in flight. With nothing in flight the call launches k, then k+1
+        where it may, and lands k; where the engine lands first, the call
+        is plan → launch → land of one step, as when nothing overlapped.
+
         On the trace ring (and, under ``jax.profiler.start_trace``, on
         the host plane of the device trace) the call is one ``step`` span
-        tiled by ``step.plan`` → ``step.pack`` → ``step.dispatch`` →
-        ``step.wait`` → ``step.land``; a step that runs no rows is
-        ``step.plan`` alone."""
+        tiled by ``step.plan`` → ``step.pack`` → ``step.dispatch`` of the
+        step it launches, then ``step.wait`` → ``step.land`` of the step
+        it lands; a call that launches and lands nothing is ``step.plan``
+        alone. The span's grid counters describe the step it landed."""
         from ..profiler import record_counter
 
         trace = self._trace
         span = trace.begin("step", self.engine_id)
         self._phase = trace.begin("step.plan", self.engine_id)
-        self._grid_counts = _NO_GRID
         counts = None
+        landed: Optional[_Launch] = None
+        ahead = discarded = 0
         t0 = time.perf_counter()
         if self.watchdog is not None:
             self.watchdog.begin_step()
@@ -1032,43 +1069,21 @@ class ServingEngine:
             try:
                 faults.point("serving.step")
                 finished.extend(self._sweep_deadlines())
-                if self._overload is not None:
-                    # brownout level >= 3: preempt batch-tier decode
-                    # slots (journal + requeue, the migration move
-                    # turned inward) — BEFORE admission so the freed
-                    # slots and pages are available to interactive
-                    # work this very step
-                    self._brownout_enforce()
-                if self._host_offload:
-                    # page pressure relief BEFORE admission: parking a
-                    # cold low-priority slot moves its pages (and its
-                    # worst-case tail reservation) to the host tier, so
-                    # can_admit sees the reclaimed capacity this very
-                    # step — offload-before-reject, and before the
-                    # prefix cache gets evicted for the same pages
-                    self._park_for_pressure()
-                free = sum(1 for s in self.slots if s is None)
-                _cap = (None if self._overload is None
-                        else self._overload.admit_priority_cap())
-                for req in self.scheduler.admit(free, self.pool,
-                                                max_priority=_cap):
-                    self._m_requests.labels(event="admitted", **self._lbl).inc()
-                    try:
-                        # an admission failure (cache/alloc fault,
-                        # injected drill) fails THIS request, not the
-                        # engine: batch-mates keep decoding, the queue
-                        # keeps draining
-                        self._admit(req)
-                    except Exception as e:
-                        finished.append(
-                            self._fail_admitted_request(req, e))
-                if self._host_offload:
-                    # restore parked slots whose pages now fit again —
-                    # AFTER admission so a just-admitted head request is
-                    # never displaced by the stream it preempted
-                    self._unpark_ready()
-                if any(s is not None for s in self.slots):
-                    finished.extend(self._step_once())
+                launched = False
+                if not self._flight:
+                    self._launch_next(finished)
+                    launched = bool(self._flight)
+                if self._flight and not self._lands_first():
+                    if launched:   # k+1's plan is a phase of its own
+                        self._phase = trace.next("step.plan", self._phase)
+                    n = len(self._flight)
+                    landed = self._launch_next(finished)
+                    ahead = int(landed is None and len(self._flight) > n)
+                if landed is None and self._flight:
+                    landed = self._flight.pop(0)
+                    self._land(landed, finished)
+                discarded = (0 if landed is None else landed.discarded) \
+                    + self._drop_ended()
             finally:
                 # the watchdog bracket must close even when the step body
                 # raises (an armed fault, an unhandled bug) — otherwise
@@ -1107,7 +1122,8 @@ class ServingEngine:
             # counter track next to the per-request tracks
             trace.emit("step.tokens", self.engine_id,
                        arg=float(tokens_this_step))
-            counts = self._grid_counts + (tokens_this_step,)
+            counts = ((_NO_GRID if landed is None else landed.counts)
+                      + (tokens_this_step, ahead, discarded))
         finally:
             # a step that raised still closes its spans (without counts):
             # an open one would adopt every later span as its child
@@ -1116,6 +1132,34 @@ class ServingEngine:
             trace.end(span, counts)
         # outputs were registered in self._outputs eagerly at retirement
         return finished
+
+    def _lands_first(self) -> bool:
+        """Whether the next plan needs what landing the step in flight
+        produces, so that step lands before the next one is planned: a
+        grammar's mask needs the slot's last token, drafts read the
+        landed stream, and parking, unparking (any host-tier engine: a
+        caller may park a slot between two calls) and brownout preemption
+        move a slot's state off the step path."""
+        if self.drafter is not None or self._host_offload:
+            return True
+        if (self._overload is not None
+                and self._overload.preempt_priority_cut() is not None):
+            return True
+        return any(st is not None and st.fsm is not None
+                   for st in self.slots)
+
+    def _drop_ended(self) -> int:
+        """Forget the step in flight once none of its rows' sequences is
+        live (they ended at an earlier landing, were cancelled or timed
+        out): nothing would land from it, and an engine with no live slot
+        then holds nothing in flight. Returns the rows thrown away."""
+        if not self._flight or any(self.slots[r[0]] is r[1]
+                                   for ln in self._flight
+                                   for r in ln.rows):
+            return 0
+        n = sum(r[2].size for ln in self._flight for r in ln.rows)
+        self._flight.clear()
+        return n
 
     # ------------------------------------------------- host-tier parking
     def _find_slot(self, req_id):
@@ -1509,8 +1553,14 @@ class ServingEngine:
         - ``fsm_state`` [B, S] — each sample's ABSOLUTE grammar-table
           row (0 = reserved all-True identity row; draft columns carry
           host-precomputed hypothetical states),
+        - ``tok_src`` [T] — per row, -1 to take ``tok``, or j >= 0 to
+          take ``prev[j]``: a decode row whose token the step still in
+          flight samples,
         - ``grammar_table`` [grammar_states, V] — the interned DFA
           allow-masks, one device table for every live grammar,
+        - ``prev`` [B*S] — the samples of the previous call (its ``nxt``,
+          the device array itself: neither transferred nor donated), or
+          zeros where nothing is in flight,
         - ``*rest`` — the stacked adapter (A, B) arrays per site, then
           the paged KV pools, consumed and returned functionally.
 
@@ -1540,13 +1590,21 @@ class ServingEngine:
         n_adp = 2 * len(site_names)
 
         def step_fn(tok, tok_pos, tok_bt, tok_adp, sample_rows, sample_pos,
-                    temps, seeds, fsm_state, grammar_table, *rest):
+                    temps, seeds, fsm_state, tok_src, grammar_table, prev,
+                    *rest):
             # the pool's operands, regrouped by the pool into one opaque
             # cache per layer: which arrays a layer keeps (int8 pages add
             # their scales) is the pool's and ops/paged_cache.py's to know,
             # and changes WHICH arrays ride as data, never the program count
             adp_flat, caches = rest[:n_adp], pool.layer_caches(rest[n_adp:])
             with no_grad():
+                # a row whose token the step in flight samples reads it
+                # from that step's output here, never through the host
+                tok = apply_op(
+                    lambda t, s, pv: jnp.where(
+                        (s >= 0)[:, None], pv[jnp.maximum(s, 0)][:, None], t),
+                    [ensure_tensor(tok), ensure_tensor(tok_src),
+                     ensure_tensor(prev)], name="tokens_in_flight")
                 # per-row adapter gather: every grid row pulls ITS
                 # owner's (A, B) stack by index — slot 0 rows pull the
                 # zero identity, so the delta below is + 0.0 exactly
@@ -1653,23 +1711,76 @@ class ServingEngine:
             self.adapters.capacity, self.adapters.rank,
             self._grammar_cap, str(jnp.dtype(self.pool.dtype))))
         # the step CONSUMES the pool: its operands (`pool.step_flat()`, the
-        # trailing positional arguments, after the ten grids and the adapter
-        # stacks) are given up to the program, which writes this step's rows
-        # into the same buffers and hands them back in the same order —
+        # trailing positional arguments, after the ten grids, the grammar
+        # table, the previous samples and the adapter stacks) are given up
+        # to the program, which writes this step's rows into the same buffers and hands them back in the same order —
         # `set_step_flat` swaps them in, and what the pool held before the
         # call is deleted. jax pairs a donated input with the first output
         # of equal aval in order, which that order (k0, v0, k1, ...) keeps.
-        first_pool = 10 + n_adp
+        first_pool = 12 + n_adp
         return jit.StaticFunction(
             step_fn, observe=[self.model], warmup=False, dy2static=False,
             cache_dir=self._compile_cache_dir, cache_key_extra=extra,
             donate_argnums=range(first_pool,
                                  first_pool + len(pool.step_flat())))
 
-    def _step_once(self) -> List[RequestOutput]:
+    def _launch_next(self, finished: List[RequestOutput]) -> Optional[_Launch]:
+        """Plan, pack and launch the next step behind whatever is in
+        flight. Returns the step it landed first, if it did: the oldest in
+        flight, where the new step's token-grid bucket never ran here (it
+        compiles inside the launch, and no landing should wait behind a
+        compile)."""
         t0 = time.perf_counter()
-        B = self.max_batch_slots
-        finished: List[RequestOutput] = []
+        rows = self._plan(finished)
+        if not rows:
+            return None
+        launch, grids, prev = self._pack(rows, t0)
+        landed = None
+        if launch.fresh and self._flight:
+            landed = self._flight.pop(0)
+            self._land(landed, finished)
+        self._dispatch(launch, grids, prev)
+        return landed
+
+    def _plan(self, finished: List[RequestOutput]) -> list:
+        """The ``step.plan`` phase: brownout and host-tier hooks,
+        admission, then the next step's rows with their KV room reserved.
+        Each slot is planned from ``pos + ahead``, where its rows in flight
+        will leave it; a slot whose token in flight is its last
+        (``max_new_tokens``) gets no row."""
+        if self._overload is not None:
+            # brownout level >= 3: preempt batch-tier decode slots
+            # (journal + requeue, the migration move turned inward) —
+            # BEFORE admission so the freed slots and pages are
+            # available to interactive work this very step
+            self._brownout_enforce()
+        if self._host_offload:
+            # page pressure relief BEFORE admission: parking a cold
+            # low-priority slot moves its pages (and its worst-case tail
+            # reservation) to the host tier, so can_admit sees the
+            # reclaimed capacity this very step — offload-before-reject,
+            # and before the prefix cache gets evicted for the same pages
+            self._park_for_pressure()
+        free = sum(1 for s in self.slots if s is None)
+        _cap = (None if self._overload is None
+                else self._overload.admit_priority_cap())
+        for req in self.scheduler.admit(free, self.pool, max_priority=_cap):
+            self._m_requests.labels(event="admitted", **self._lbl).inc()
+            try:
+                # an admission failure (cache/alloc fault, injected
+                # drill) fails THIS request, not the engine: batch-mates
+                # keep decoding, the queue keeps draining
+                self._admit(req)
+            except Exception as e:
+                finished.append(self._fail_admitted_request(req, e))
+        if self._host_offload:
+            # restore parked slots whose pages now fit again — AFTER
+            # admission so a just-admitted head request is never
+            # displaced by the stream it preempted
+            self._unpark_ready()
+        rows: list = []
+        if not any(s is not None for s in self.slots):
+            return rows
         decode_idx: List[int] = []
         prefill_info = []
         for i, st in enumerate(self.slots):
@@ -1694,9 +1805,10 @@ class ServingEngine:
                     finished.append(self._retire_abnormal(
                         st, slot=i, reason="error", error=e))
                     continue
-            if st.prefilling:
-                prefill_info.append((i, int(st.ids.size) - st.pos, st.req))
-            else:
+            p = st.pos + st.ahead
+            if p < st.ids.size:
+                prefill_info.append((i, int(st.ids.size) - p, st.req))
+            elif len(st.gen) + st.ahead_tokens < st.req.max_new_tokens:
                 decode_idx.append(i)
         # brownout hooks (overload.OverloadController): both are pure
         # planning data — chunk sizes and draft gating never touch the
@@ -1719,7 +1831,8 @@ class ServingEngine:
         # same SLO order as chunks). Each slot's draft count is further
         # capped so the burst can never overrun max_new_tokens (the base
         # decode emits >= 1, hence remaining-1) or the request's page
-        # reservation / context window.
+        # reservation / context window. A drafting engine lands first
+        # (_lands_first), so nothing of these slots is in flight.
         drafts: Dict[int, np.ndarray] = {}
         if (self.drafter is not None and decode_idx
                 and not (_ovl is not None and _ovl.drafts_paused)):
@@ -1779,20 +1892,20 @@ class ServingEngine:
         # this exclusivity); chunk rows reserve their whole range via
         # extend_write. Out of pages (impossible unless injected/
         # buggy): quarantine the victim, keep the rest of the batch —
-        # its row simply never joins the grid.
-        rows = []  # (slot, token ids [c], positions [c], is_chunk, n_draft)
-        n_decode_tokens = 0
-        n_draft_tokens = 0
+        # its row simply never joins the grid. A decode row whose token
+        # is still in flight reads it on the device: src is its index in
+        # the previous step's samples (-1: the host's last_token).
+        S = self._spec_rows
         for i in decode_idx:
             st = self.slots[i]
+            p = st.pos + st.ahead
             d_toks = drafts.get(i)
             d = 0 if d_toks is None else int(d_toks.size)
             try:
                 if d:
-                    self.pool.extend_write(st.req.req_id, st.pos,
-                                           st.pos + 1 + d)
+                    self.pool.extend_write(st.req.req_id, p, p + 1 + d)
                 else:
-                    self.pool.extend(st.req.req_id, st.pos + 1)
+                    self.pool.extend(st.req.req_id, p + 1)
             except Exception as e:
                 finished.append(
                     self._retire_abnormal(st, slot=i, reason="error",
@@ -1800,34 +1913,38 @@ class ServingEngine:
                 continue
             toks = (np.concatenate([[st.last_token], d_toks]).astype(np.int32)
                     if d else np.asarray([st.last_token], np.int32))
-            rows.append((i, toks,
-                         np.arange(st.pos, st.pos + 1 + d, dtype=np.int32),
-                         False, d))
-            n_decode_tokens += 1
-            n_draft_tokens += d
+            rows.append((i, st, toks,
+                         np.arange(p, p + 1 + d, dtype=np.int32),
+                         False, d, i * S if st.ahead_tokens else -1))
         for i, c in chunks:
             st = self.slots[i]
+            p = st.pos + st.ahead
             try:
-                self.pool.extend_write(st.req.req_id, st.pos, st.pos + c)
+                self.pool.extend_write(st.req.req_id, p, p + c)
             except Exception as e:
                 finished.append(
                     self._retire_abnormal(st, slot=i, reason="error",
                                           error=e))
                 continue
-            rows.append((i, st.ids[st.pos:st.pos + c],
-                         np.arange(st.pos, st.pos + c, dtype=np.int32),
-                         True, 0))
+            rows.append((i, st, st.ids[p:p + c],
+                         np.arange(p, p + c, dtype=np.int32), True, 0, -1))
         faults.point("serving.decode_step")
-        if not rows:
-            return finished
-        trace = self._trace
-        self._phase = trace.next("step.pack", self._phase)
-        total = sum(r[1].size for r in rows)
+        return rows
+
+    def _pack(self, rows: list, t0: float):
+        """The ``step.pack`` phase: the ten numpy grids of ``rows``, and
+        the step's record. Returns (launch, grids, prev): ``prev`` is the
+        samples of the step in flight, which rows with ``src`` >= 0 read."""
+        self._phase = self._trace.next("step.pack", self._phase)
+        B = self.max_batch_slots
+        total = sum(r[2].size for r in rows)
         T = self._grid_tokens(total)
+        launch = _Launch()
+        launch.rows, launch.t0, launch.clips = rows, t0, None
         # a bucket this engine never ran compiles (or deserializes from
         # the disk cache) inside the coming program call — remember it
         # now so the wall time lands in the trace's compile bucket
-        fresh_bucket = T not in self._grid_buckets_seen
+        launch.fresh = T not in self._grid_buckets_seen
         self._grid_buckets_seen.add(T)
         S = self._spec_rows
         tok = np.zeros((T, 1), np.int32)
@@ -1841,10 +1958,10 @@ class ServingEngine:
         # absolute grammar-table rows per sample; idle/unconstrained
         # entries stay 0 = the all-True identity row (mask is a no-op)
         fsm_state = np.zeros((B, S), np.int32)
+        tok_src = np.full(T, -1, np.int32)
         cur = 0
-        kv_walked = kv_held = 0
-        for i, toks, poss, is_chunk, d in rows:
-            st = self.slots[i]
+        kv_walked = kv_held = n_decode = n_draft = 0
+        for i, st, toks, poss, is_chunk, d, src in rows:
             c = toks.size
             # the keys the kernel walks: row at position p attends p + 1
             # of them, so a slot's c consecutive rows walk the sum; the
@@ -1867,6 +1984,9 @@ class ServingEngine:
                     # samples get the same row harmlessly
                     fsm_state[i, 0] = st.fsm_off + st.fsm_state
             else:
+                n_decode += 1
+                n_draft += d
+                tok_src[cur] = src
                 # base decode row + its d draft rows are contiguous:
                 # sample column j targets position pos+j, i.e. the token
                 # FOLLOWING the j-th burst token
@@ -1887,62 +2007,94 @@ class ServingEngine:
             temps[i] = st.req.temperature
             seeds[i] = st.req.seed
             cur += c
-        self._grid_counts = (
-            total, T, n_decode_tokens,
-            total - n_decode_tokens - n_draft_tokens, n_draft_tokens,
-            len(rows), kv_walked, kv_held)
-        self._phase = trace.next("step.dispatch", self._phase)
+        launch.counts = (total, T, n_decode, total - n_decode - n_draft,
+                         n_draft, len(rows), kv_walked, kv_held)
+        grids = [tok, tok_pos, tok_bt, tok_adp, sample_rows, sample_pos,
+                 temps, seeds, fsm_state, tok_src]
+        prev = self._flight[-1].nxt if self._flight else None
+        return launch, grids, prev
+
+    def _dispatch(self, launch: _Launch, grids: list, prev) -> None:
+        """The ``step.dispatch`` phase: one transfer of the grids, the
+        program call, and the pool's arrays swapped for the ones the call
+        returns. Nothing here waits for the device."""
+        self._phase = self._trace.next("step.dispatch", self._phase)
         if self._step_prog is None:
-            fresh_bucket = True
+            launch.fresh = True
             self._step_prog = self._compile_with_retry(
                 "serving.compile_step", self._make_step)
-        t_prog = time.perf_counter()
-        # the nine grids go up in one transfer call, not nine
-        grids = jax.device_put([tok, tok_pos, tok_bt, tok_adp, sample_rows,
-                                sample_pos, temps, seeds, fsm_state])
+        if prev is None:
+            if self._no_prev is None:
+                self._no_prev = Tensor(jax.device_put(np.zeros(
+                    self.max_batch_slots * self._spec_rows, np.int32)))
+            prev = self._no_prev
+        launch.t_prog = time.perf_counter()
+        # the ten grids go up in one transfer call, not ten
         res = self._step_prog(
-            *map(Tensor, grids), self._grammar_device,
+            *map(Tensor, jax.device_put(grids)), self._grammar_device, prev,
             *self.adapters.arrays(), *self.pool.step_flat())
-        nxt, fin, flat = res[0], res[1], res[2:]
+        launch.nxt, launch.fin, flat = res[0], res[1], res[2:]
         self.pool.set_step_flat(flat)
+        total = launch.counts[0]
         if self.pool.quantized and total:
             # absmax-floor accounting for THIS step's written slots: a
             # clipped scale means a (page, pos, head) row whose KV
-            # underflowed the quantizer's dynamic range (kv_cache docs)
-            w_pages = tok_bt[np.arange(total),
-                             tok_pos[:total] // self.page_size]
+            # underflowed the quantizer's dynamic range (kv_cache docs).
+            # Asked of the arrays the step returned, before a later step
+            # is given them, as a device count that is read when the step
+            # lands: nothing here waits for the device
+            tok_pos, tok_bt = grids[1][:total], grids[2]
+            w_pages = tok_bt[np.arange(total), tok_pos // self.page_size]
             live = w_pages > 0
             if live.any():
-                self.pool.record_scale_clips(
-                    w_pages[live], (tok_pos[:total] % self.page_size)[live])
+                launch.clips = self.pool.scale_clips(
+                    w_pages[live], (tok_pos % self.page_size)[live])
+        for i, st, toks, poss, is_chunk, d, src in launch.rows:
+            st.ahead += toks.size
+            st.ahead_tokens += int(not is_chunk
+                                   or int(poss[-1]) + 1 == st.ids.size)
+        self._flight.append(launch)
+
+    def _land(self, launch: _Launch, finished: List[RequestOutput]) -> None:
+        """The ``step.wait`` and ``step.land`` phases of one launched step:
+        read its samples back, then acceptance, streaming and retirement.
+        A row whose sequence ended since it was planned (eos, NaN
+        quarantine, cancel, deadline) is thrown away: its sample is never
+        streamed, journaled or counted (``launch.discarded``)."""
+        trace = self._trace
+        B, S = self.max_batch_slots, self._spec_rows
         self._phase = trace.next("step.wait", self._phase)
-        nxt_host = np.asarray(nxt.numpy()).reshape(B, S)
-        fin_host = np.asarray(fin.numpy()).reshape(B, S).astype(bool)
+        nxt_host = np.asarray(launch.nxt.numpy()).reshape(B, S)
+        fin_host = np.asarray(launch.fin.numpy()).reshape(B, S).astype(bool)
         self._phase = trace.next("step.land", self._phase)
         now = time.perf_counter()
-        self._m_decode.observe(now - t0)
-        self._m_mix_decode.observe(n_decode_tokens)
-        self._m_mix_draft.observe(n_draft_tokens)
-        self._m_mix_prefill.observe(total - n_decode_tokens
-                                    - n_draft_tokens)
-        if fresh_bucket:
+        n_decode, n_chunk, n_draft = launch.counts[2:5]
+        self._m_decode.observe(now - launch.t0)
+        self._m_mix_decode.observe(n_decode)
+        self._m_mix_draft.observe(n_draft)
+        self._m_mix_prefill.observe(n_chunk)
+        if launch.clips is not None:
+            self.pool.count_scale_clips(launch.clips)
+        for i, st, toks, poss, is_chunk, d, src in launch.rows:
+            st.ahead -= toks.size
+            st.ahead_tokens -= int(not is_chunk
+                                   or int(poss[-1]) + 1 == st.ids.size)
+        if launch.fresh:
             # a fresh token-grid bucket compiled inside this program
             # call: charge its wall time to every rider, so the
             # attribution pass can name compile (not prefill) as where
             # a cold request's TTFT went
-            for _ci, _ct, _cp, _cic, _cd in rows:
-                _cst = self.slots[_ci]
-                if _cst is not None:
-                    self._trace.emit("req.compile", _cst.req.req_id,
-                                     arg=now - t_prog, t=now)
-
-        for i, toks, poss, is_chunk, d in rows:
-            st = self.slots[i]
-            if st is None:
-                # an earlier row's callback cancelled THIS slot's
-                # request reentrantly — touching it again would
-                # double-free its pages (no admission runs mid-step, so
-                # a non-None slot is still the row's own state)
+            for i, st, *_ in launch.rows:
+                if self.slots[i] is st:
+                    self._trace.emit("req.compile", st.req.req_id,
+                                     arg=now - launch.t_prog, t=now)
+        discarded = 0
+        for i, st, toks, poss, is_chunk, d, src in launch.rows:
+            if self.slots[i] is not st or int(poss[0]) != st.pos:
+                # the sequence ended after this row was planned (or an
+                # earlier row's callback cancelled it reentrantly):
+                # touching it again would land a token nobody may see
+                discarded += toks.size
                 continue
             n_sample = 1 if is_chunk else d + 1
             if not fin_host[i, :n_sample].all():
@@ -2028,7 +2180,7 @@ class ServingEngine:
                     break
                 if self.slots[i] is not st:
                     break  # reentrant cancel inside the stream callback
-        return finished
+        launch.discarded = discarded
 
     def _land_token(self, st: _SeqState, slot: int, token: int,
                     now: float) -> Optional[RequestOutput]:

@@ -809,24 +809,30 @@ class PagedKVCachePool:
         return len(idxs)
 
     def record_scale_clips(self, page_ids, offs) -> int:
-        """Count this step's written slots whose absmax scale clamped at
-        KV_SCALE_FLOOR (all layers, K and V) and move the
-        ``kv_dequant_scale_clip_total`` counter. The engine calls this
-        with the step's (page, offset) coords right after the program
-        returns — a floor-clamped slot quantized with its dynamic range
-        collapsed (absmax underflow), the one int8 failure mode absmax
-        scaling cannot round away (docs/OBSERVABILITY.md)."""
+        """Count the written slots at (page, offset) whose absmax scale
+        clamped at KV_SCALE_FLOOR (all layers, K and V) and move the
+        ``kv_dequant_scale_clip_total`` counter — a floor-clamped slot
+        quantized with its dynamic range collapsed (absmax underflow),
+        the one int8 failure mode absmax scaling cannot round away
+        (docs/OBSERVABILITY.md)."""
         if not self.quantized or len(page_ids) == 0:
             return 0
+        return self.count_scale_clips(self.scale_clips(page_ids, offs))
+
+    def scale_clips(self, page_ids, offs):
+        """:meth:`record_scale_clips`' count as a device scalar, read off
+        the pool's arrays as they are now, without waiting for it: the
+        engine asks it right after a step's program call, and reads it
+        (:meth:`count_scale_clips`) when that step lands."""
         pages = jnp.asarray(np.asarray(page_ids, np.int32))
         oo = jnp.asarray(np.asarray(offs, np.int32))
         floor = jnp.float32(KV_SCALE_FLOOR)
-        n = 0
-        for li in range(self.num_layers):
-            n += int(jnp.sum(
-                self.k_scales[li]._value[pages, :, oo] <= floor))
-            n += int(jnp.sum(
-                self.v_scales[li]._value[pages, :, oo] <= floor))
+        return sum(jnp.sum(s[li]._value[pages, :, oo] <= floor)
+                   for li in range(self.num_layers)
+                   for s in (self.k_scales, self.v_scales))
+
+    def count_scale_clips(self, n) -> int:
+        n = int(n)
         if n:
             self._m_scale_clips.inc(n)
         return n

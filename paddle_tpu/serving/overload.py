@@ -37,7 +37,7 @@ expired outcomes extend the exactly-once ledger instead of escaping it.
 The controller is a passive observer like the autoscaler: call
 :meth:`OverloadController.observe` once per ``router.step()`` sweep.
 Engines consult the attached controller at admission and inside
-``_step_once`` planning; detaching it restores stock behavior.
+``_plan`` planning; detaching it restores stock behavior.
 """
 from __future__ import annotations
 
@@ -282,7 +282,7 @@ class OverloadController:
         return worst
 
     # ----------------------------------------------------- engine-side API
-    # Engines call these from add_request / _step_once; every answer is
+    # Engines call these from add_request / _plan; every answer is
     # host-side data (chunk sizes, draft gating, park decisions) so the
     # compile surface cannot change.
     @property

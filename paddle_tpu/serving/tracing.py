@@ -143,8 +143,10 @@ EVENTS: Dict[str, str] = {
     "step.dispatch": "span: host->device transfers and the call of the "
                      "step program until it returns (a fresh bucket "
                      "compiles here)",
-    "step.wait": "span: blocked on the step's read-back: the device "
-                 "runs the step",
+    "step.wait": "span: blocked on the read-back of the step this call "
+                 "lands: launched by the previous call where the engine "
+                 "runs one step ahead, so the device runs it and then "
+                 "the one just launched",
     "step.land": "span: acceptance, token landing, stream callbacks, "
                  "prefix-cache insert, retirement, stats and gauges",
 }
@@ -156,9 +158,13 @@ COUNTERS: Dict[str, Tuple[str, ...]] = {
     # seqs: slots with a row; kv_walked: sum over real rows of (row
     # position + 1), the keys the paged kernel's grid walks this step;
     # kv_held: sum over those slots of (last row's position + 1), the
-    # keys that exist to be read once; landed: tokens landed
+    # keys that exist to be read once — all of the step the call landed;
+    # landed: tokens landed; ahead: 1 where the call launched a step while
+    # the previous one was still in flight; discarded: rows thrown away
+    # because their sequence had ended before they landed
     "step": ("rows", "bucket", "decode_rows", "chunk_rows", "draft_rows",
-             "seqs", "kv_walked", "kv_held", "landed"),
+             "seqs", "kv_walked", "kv_held", "landed", "ahead",
+             "discarded"),
 }
 
 # TTFT attribution buckets (docs/OBSERVABILITY.md "TTFT attribution"):

@@ -310,9 +310,13 @@ class TestServingChaos:
         victim = engine.add_request(_PROMPTS[3], max_new_tokens=6)
         mate = engine.add_request(_PROMPTS[2], max_new_tokens=6)
         queued = engine.add_request(_PROMPTS[2], max_new_tokens=4)
-        engine.step()  # admit + chunk victim/mate (queued waits: 2 slots)
+        # the first call admits and chunks victim and mate (queued waits:
+        # 2 slots), each chunk drawing a page, then plans their first
+        # decode step behind it: the victim's fresh page is the third draw
         with faults.inject("serving.kv_alloc",
-                           raise_=faults.ResourceExhausted, times=1):
+                           raise_=faults.ResourceExhausted, times=1,
+                           after=2):
+            engine.step()
             outs = engine.run()
         assert len(outs) == 3
         assert outs[victim].finish_reason == "error"

@@ -439,7 +439,8 @@ class TestBrownoutPreemption:
                             event="preempted", engine_id="m/0",
                             model_id="m")
         ctl.level = 3                     # injected ladder state
-        engine.step()
+        engine.step()                     # lands the step in flight first
+        engine.step()                     # the next plan preempts
         assert _counter("paddle_tpu_serving_requests_total",
                         event="preempted", engine_id="m/0",
                         model_id="m") == preempt0 + 1
@@ -478,7 +479,8 @@ class TestBrownoutPreemption:
         for _ in range(2):
             engine.step()
         ctl.level = 3
-        engine.step()
+        engine.step()      # lands the step in flight first
+        engine.step()      # the next plan preempts
         live = [s.req.req_id for s in engine.slots if s is not None]
         assert inter in live and batch not in live
         ctl.level = 0

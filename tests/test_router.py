@@ -766,3 +766,41 @@ class TestRotationAndLabels:
                   snap["paddle_tpu_router_engine_state"]["series"]}
         assert states[(("engine_id", "default/0"),
                        ("model_id", "default"))] == 0.0
+
+
+class TestPipelinedFleet:
+    def test_each_engine_runs_a_step_ahead_streams_match_dense(self):
+        """Two engines behind the router each keep their own step in
+        flight: a sweep launches each engine's next step before it lands
+        the one in flight, and every stream is token-identical to dense
+        generate()."""
+        from paddle_tpu.serving import tracing
+
+        tr = tracing.RequestTracer(capacity=65536)
+        old = tracing.set_tracer(tr)
+        try:
+            model = _model()
+            r = Router()
+            r.add_model("m", model, replicas=2, page_size=4,
+                        max_batch_slots=2)
+            prompts = [P3, P4, P5, P4[::-1]]
+            rids = [r.submit(p, model="m", max_new_tokens=6)
+                    for p in prompts]
+            outs = r.run()
+            evs = tr.events()
+        finally:
+            tracing.set_tracer(old)
+        for rid, p in zip(rids, prompts):
+            dense = model.generate(paddle.to_tensor(np.asarray(p)[None, :]),
+                                   max_new_tokens=6, temperature=0.0)
+            assert list(outs[rid].token_ids) == list(
+                np.asarray(dense.numpy())[0, len(p):])
+        for eid in ("m/0", "m/1"):
+            ahead = [e["counts"]["ahead"] for e in evs
+                     if e["name"] == "step" and e["req_id"] == eid
+                     and "counts" in e]
+            assert len(ahead) >= 6 and sum(ahead) >= len(ahead) - 2
+            eng = r.engine(eid)
+            assert eng.pool.used_pages == 0 and not eng._flight
+            c = eng.compile_counts()
+            assert c["step"] == c["step_buckets"]

@@ -1385,6 +1385,174 @@ class TestGenerateStats:
 # ─────────────────────────── slow batch sweeps ───────────────────────────
 
 
+# ───────────────────── one step in flight while planning ─────────────────────
+
+
+@pytest.fixture
+def ring():
+    """A private tracer, installed before the engine captures it."""
+    from paddle_tpu.serving import tracing
+
+    tr = tracing.RequestTracer(capacity=65536)
+    old = tracing.set_tracer(tr)
+    try:
+        yield tr
+    finally:
+        tracing.set_tracer(old)
+
+
+def _counts(tr, engine):
+    """The ``step`` counters of every call of ``engine``, oldest first."""
+    return [e["counts"] for e in tr.events()
+            if e["name"] == "step" and e["req_id"] == engine.engine_id
+            and "counts" in e]
+
+
+def _drive(engine, until=None):
+    """step() until the engine drains (or ``until()`` holds)."""
+    while engine.has_work and not (until is not None and until()):
+        engine.step()
+
+
+class TestPipelinedStep:
+    """Step k+1 is planned, packed and launched while step k is on the
+    device; k+1 takes k's samples on the device and each slot is planned
+    from ``pos + ahead``. A row whose sequence ended before it lands is
+    thrown away. Every stream stays token-identical to dense generate()."""
+
+    @pytest.mark.parametrize("case", ["eos", "cancel", "nan", "known_last",
+                                      "final_chunk"])
+    def test_streams_match_dense_with_a_step_in_flight(self, ring, case):
+        from paddle_tpu import faults
+
+        model = _llama()
+        kw = dict(page_size=4, max_batch_slots=2)
+        if case == "final_chunk":
+            kw["token_budget"] = 4     # the 9-token prompt lands in 3 chunks
+        eng = ServingEngine(model, **kw)
+        streamed = {}
+
+        def cb(rid, tok, fin, seq):
+            if tok is not None:
+                streamed.setdefault(rid, []).append(int(tok))
+
+        eos = int(_dense_gen(model, _PROMPTS[0], 3)[2])
+        n_new = {"known_last": (1, 2)}.get(case, (8, 6))
+        req = [(_PROMPTS[0], n_new[0],
+                eos if case == "eos" else None),
+               (_PROMPTS[1], n_new[1], None)]
+        rids = [eng.add_request(p, max_new_tokens=n, eos_token_id=e,
+                                stream_cb=cb) for p, n, e in req]
+        if case == "cancel":
+            # cancel the first request with its next row in flight
+            _drive(eng, until=lambda: len(streamed.get(rids[0], ())) >= 2)
+            assert eng.slots[0].ahead_tokens == 1
+            at_cancel = list(streamed[rids[0]])
+            assert eng.cancel(rids[0])
+            _drive(eng)
+            assert streamed[rids[0]] == at_cancel
+        elif case == "nan":
+            # poison the first request's KV while its next row is in
+            # flight: the step reading it quarantines the sequence
+            _drive(eng, until=lambda: len(streamed.get(rids[0], ())) >= 2)
+            with faults.inject("serving.decode_step",
+                               call=lambda: eng.pool.poison_seq(rids[0]),
+                               times=1) as spec:
+                _drive(eng)
+            assert spec.fired == 1
+        else:
+            _drive(eng)
+        outs = eng.take_outputs()
+        assert outs[rids[0]].finish_reason == {
+            "cancel": "cancelled", "nan": "nan", "eos": "stop"}.get(
+                case, "length")
+        for rid, (p, n, e) in zip(rids, req):
+            dense = _dense_gen(model, p, n, eos=e)
+            got = np.asarray(outs[rid].token_ids)
+            if outs[rid].finish_reason in ("length", "stop"):
+                assert got.size == (3 if e is not None else n)
+            np.testing.assert_array_equal(got, dense[:got.size])
+            assert streamed.get(rid, []) == list(got)
+        assert eng.pool.used_pages == 0 and not eng._flight
+        c = eng.compile_counts()
+        assert c["step"] == c["step_buckets"]
+        counts = _counts(ring, eng)
+        assert sum(s["landed"] for s in counts) == sum(
+            len(streamed.get(r, ())) for r in rids)
+        discarded = sum(s["discarded"] for s in counts)
+        if case in ("eos", "cancel", "nan"):
+            assert discarded >= 1      # the ended sequence's row in flight
+        else:
+            assert discarded == 0      # no row past a known last token
+        assert sum(s["ahead"] for s in counts) >= len(counts) - 2
+
+    def test_final_chunk_sample_feeds_the_next_row_on_the_device(self, ring):
+        """With the final chunk in flight the slot's decode row is planned
+        at position len(prompt) and takes its token from the chunk's
+        sample, which the host has not seen yet."""
+        model = _llama()
+        eng = ServingEngine(model, page_size=4, max_batch_slots=2)
+        rid = eng.add_request(_PROMPTS[1], max_new_tokens=4)
+        eng.step()   # the prompt's one chunk lands; its decode row is out
+        st = eng.slots[0]
+        assert st.pos == _PROMPTS[1].size and len(st.gen) == 1
+        assert st.ahead == 1 and st.ahead_tokens == 1
+        rows = eng._flight[0].rows
+        assert [(r[3][0], r[6]) for r in rows] == [(_PROMPTS[1].size, 0)]
+        out = eng.run()[rid]
+        np.testing.assert_array_equal(np.asarray(out.token_ids),
+                                      _dense_gen(model, _PROMPTS[1], 4))
+
+    @pytest.mark.parametrize("mode", ["plain", "grammar", "drafts"])
+    def test_ahead_on_steady_decode_and_landing_first(self, ring, mode):
+        """``ahead`` reads 1 on a call that launched its step behind the
+        one in flight: every call of a steady decode but the first (a
+        fresh bucket) and the last (nothing left to launch); 0 on every
+        call while a grammar slot is live or the engine drafts."""
+        from paddle_tpu.serving import GrammarFSM, toy_tokenizer
+
+        model = _llama()
+        eng = ServingEngine(model, page_size=4, max_batch_slots=2,
+                            spec_k=2 if mode == "drafts" else 0)
+        rid = eng.add_request(_PROMPTS[0], max_new_tokens=8)
+        done_at = []      # the call in which the grammar request retires
+        if mode == "grammar":
+            fsm = GrammarFSM.compile("[a-z]{4,6}", toy_tokenizer(128))
+            eng.add_request(
+                _PROMPTS[2], max_new_tokens=8, grammar=fsm,
+                stream_cb=lambda r, tok, fin, seq: fin and done_at.append(
+                    eng.stats["steps"]))
+        _drive(eng)
+        outs = eng.take_outputs()
+        np.testing.assert_array_equal(np.asarray(outs[rid].token_ids),
+                                      _dense_gen(model, _PROMPTS[0], 8))
+        ahead = [s["ahead"] for s in _counts(ring, eng)]
+        if mode == "plain":
+            # the first call's decode step is the engine's first in its
+            # bucket: it compiles, so the chunk step lands before it goes
+            assert ahead == [0] + [1] * (len(ahead) - 2) + [0]
+        elif mode == "drafts":
+            assert ahead == [0] * len(ahead)
+        else:
+            assert ahead[:done_at[0] + 1] == [0] * (done_at[0] + 1)
+            assert ahead[-2:] == [1, 0]   # alone again, it runs ahead
+        c = eng.compile_counts()
+        assert c["step"] == c["step_buckets"]
+
+    def test_one_call_lands_one_step(self, ring):
+        """Callers that count tokens per step() see one step's tokens per
+        call, as when nothing overlapped: one token per decoding slot."""
+        eng = ServingEngine(_llama(), page_size=4, max_batch_slots=2)
+        for p in _PROMPTS[:2]:
+            eng.add_request(p, max_new_tokens=6)
+        landed = []
+        while eng.has_work:
+            before = eng.stats["generated_tokens"]
+            eng.step()
+            landed.append(eng.stats["generated_tokens"] - before)
+        assert landed == [2] * 6
+
+
 @pytest.mark.slow
 class TestBatchSweeps:
     @pytest.mark.parametrize("slots", [1, 4, 8])

@@ -511,6 +511,12 @@ def _hist_count(name, tier):
 
 PHASES = ("step.plan", "step.pack", "step.dispatch", "step.wait",
           "step.land")
+# the phase sequences a call can have, by initial (k: pack): nothing
+# launched or landed; launch + land; land only; launch k, launch k+1, land
+# k; a fresh bucket lands first, then launches; the same behind a launch
+# of k; launch k, plan k+1 with no row, land k
+_STEP_FORMS = {"p", "pkdwl", "pwl", "pkdpkdwl", "pkwld", "pkdpkwld",
+               "pkdpwl"}
 
 
 def _model64(seed=0):
@@ -525,11 +531,13 @@ def _spans(events):
 
 
 def _check_step_family(step, children):
-    """``children`` (in ring order) are the phases in order, nested in
-    their ``step``, disjoint, and no longer than it together."""
+    """``children`` (in ring order) are the phases in one of the orders
+    a call has (``_STEP_FORMS``), nested in their ``step``, disjoint, and
+    no longer than it together."""
     names = [c["name"] for c in children]
-    assert names == list(PHASES[:len(names)]), names
-    assert len(names) in (1, 5)   # a step without rows is its plan alone
+    code = {"step.plan": "p", "step.pack": "k", "step.dispatch": "d",
+            "step.wait": "w", "step.land": "l"}
+    assert "".join(code[n] for n in names) in _STEP_FORMS, names
     lo, hi = step["t"] - step["arg"], step["t"]
     prev_end = lo
     for c in children:
@@ -592,10 +600,11 @@ class TestSpans:
         assert len(idle) == 1
 
     def test_step_counters_on_a_hand_built_grid(self):
-        """(b) two decode rows at contexts 20 and 31 and one 5-token
+        """(b) two decode rows at contexts 21 and 32 and one 5-token
         chunk at positions 12-16 (its first 12 tokens come from the
-        prefix cache): rows 7, kv_walked 21 + 32 + (13+..+17), kv_held
-        21 + 32 + 17."""
+        prefix cache): rows 7, kv_walked 22 + 33 + (13+..+17), kv_held
+        22 + 33 + 17. The second call launches that step behind the
+        decode step in flight; the third lands it."""
         with _fresh(capacity=4096) as tr:
             eng = ServingEngine(_model64(), page_size=4, num_pages=64,
                                 max_batch_slots=3, max_model_len=64,
@@ -611,12 +620,14 @@ class TestSpans:
             eng.add_request(np.concatenate([shared, rng.randint(1, 32, (5,))]),
                             max_new_tokens=2)
             eng.step()
+            eng.step()
             last = [e for e in tr.events() if e["name"] == "step"][-1]
         assert last["counts"] == {
             "rows": 7, "bucket": eng._grid_tokens(7), "decode_rows": 2,
             "chunk_rows": 5, "draft_rows": 0, "seqs": 3,
-            "kv_walked": 21 + 32 + (13 + 14 + 15 + 16 + 17),
-            "kv_held": 21 + 32 + 17, "landed": 3}
+            "kv_walked": 22 + 33 + (13 + 14 + 15 + 16 + 17),
+            "kv_held": 22 + 33 + 17, "landed": 3, "ahead": 1,
+            "discarded": 0}
         assert last["parent"] == 0     # no router, no sweep
 
     def test_spans_lie_on_the_profiler_trace_host_plane(self, tmp_path):
@@ -632,7 +643,7 @@ class TestSpans:
             r = Router()
             r.add_model("m", _model64(), replicas=1, page_size=4,
                         num_pages=64, max_batch_slots=2, max_model_len=64)
-            r.submit(P5, model="m", max_new_tokens=4)
+            r.submit(P5, model="m", max_new_tokens=6)
             r.step()       # compile outside the trace
             jax.profiler.start_trace(str(tmp_path))
             try:
