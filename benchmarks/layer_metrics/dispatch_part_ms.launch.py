@@ -1,0 +1,3 @@
+"""Host loop: mean over the window's steps of their ``step.launch`` spans, the
+children of ``step.dispatch`` (the program's trace ring)."""
+from benchmarks.layer_metrics.program_parts import dispatch_launch_ms as read  # noqa: F401

@@ -37,8 +37,9 @@ Code grammar:
   call) — the receiver shape is the discriminator that keeps
   unrelated ``.emit(...)`` APIs (the ONNX node builder) out of the
   catalog. Doc side: a backtick span in the FIRST cell of an
-  OBSERVABILITY.md table row matching ``req.name`` / ``step.name``, or
-  one of the bare span names ``step`` / ``sweep``.
+  OBSERVABILITY.md table row matching ``req.name`` / ``step.name`` /
+  ``brownout.name`` / ``param.name``, or one of the bare span names
+  ``step`` / ``sweep``.
 """
 from __future__ import annotations
 
@@ -60,12 +61,12 @@ __all__ = [
 _METRIC_TOKEN_RE = re.compile(
     r"^(paddle_tpu_[a-zA-Z0-9_]+)(\{([a-zA-Z0-9_,\s]*)\})?$")
 _FAULT_TOKEN_RE = re.compile(r"^[a-z][a-z0-9_]*\.[a-z][a-z0-9_]*$")
-# trace events are namespaced req./step./brownout. — disjoint from
-# fault tokens only by convention, so the event catalog lives in
+# trace events are namespaced req./step./brownout./param. — disjoint
+# from fault tokens only by convention, so the event catalog lives in
 # OBSERVABILITY.md (TPL010) while fault points live in RESILIENCE.md
 # (TPL004)
 _EVENT_TOKEN_RE = re.compile(
-    r"^((req|step|brownout)\.[a-z][a-z0-9_]*|step|sweep)$")
+    r"^((req|step|brownout|param)\.[a-z][a-z0-9_]*|step|sweep)$")
 _TRACE_SITE_ATTRS = ("emit", "begin", "next")
 _TRACER_RECEIVER_RE = re.compile(r"^_?tracer?$")
 _BACKTICK_RE = re.compile(r"`([^`]+)`")

@@ -49,6 +49,7 @@ from .. import tensor as tensor_mod
 from ..generator import Generator, default_generator
 from ..nn.layer_base import Layer
 from ..optimizer.optimizer import Optimizer
+from ..profiler.ring import get_tracer
 from ..tensor import Tensor
 
 __all__ = ["StaticFunction", "InputSpec", "set_compile_cache_dir",
@@ -922,73 +923,99 @@ class StaticFunction:
         # recompile storm shows up on /metrics before it shows up as a
         # latency cliff. Without ``compile`` (``lower()``) nothing
         # compiles here and a call runs ``jitted``.
+        #
+        # On the trace ring the build is one ``step.build`` span (counts:
+        # ``fresh``, the source above as 1 or 0) with its parts as
+        # children: ``step.load`` (the disk entry), ``step.trace``,
+        # ``step.lower``, ``step.compile``; a "memory" build is the span
+        # alone.
         from ..metrics import get_registry
 
-        plan = _Plan(given, donates=not _donation_off())
+        tracer, name = get_tracer(), self.__name__
+        build = tracer.begin("step.build", name)
+        part = None
         source = "fresh"
-        registry = get_registry()
-        cache_dir = self._resolve_cache_dir() if compile else None
-        full_key = path = ent = None
-        if cache_dir is not None:
-            full_key = self._persistent_key(key, state, lr_vals, given)
-            path = os.path.join(
-                cache_dir,
-                f"{self.__name__}-"
-                f"{hashlib.sha256(full_key.encode()).hexdigest()[:32]}"
-                ".jitcache")
-            ent = _MEMORY_CACHE.get(full_key)
-            if ent is not None:
-                source = "memory"
-            else:
-                ent = _load_disk_entry(path, full_key)
+        try:
+            plan = _Plan(given, donates=not _donation_off())
+            registry = get_registry()
+            cache_dir = self._resolve_cache_dir() if compile else None
+            full_key = path = ent = None
+            if cache_dir is not None:
+                full_key = self._persistent_key(key, state, lr_vals, given)
+                path = os.path.join(
+                    cache_dir,
+                    f"{name}-"
+                    f"{hashlib.sha256(full_key.encode()).hexdigest()[:32]}"
+                    ".jitcache")
+                ent = _MEMORY_CACHE.get(full_key)
                 if ent is not None:
-                    _MEMORY_CACHE[full_key] = ent
-                    source = "disk"
-        if ent is not None:
-            plan.aot, plan.out_spec, written = ent
-            plan.set_written(written, len(state))
-            abstract = jax.tree_util.tree_map(_abstract,
-                                              (state, lr_vals, arrays))
-            plan.trace = lambda: self._trace(plan, treedef, meta, *abstract)
-        else:
-            plan._jitted = self._trace(plan, treedef, meta, state, lr_vals,
-                                       arrays)
-        operands = plan.operands(state, lr_vals, arrays)
-        plan.abstract = jax.tree_util.tree_map(_abstract, operands)
-        if compile and ent is None:
-            try:
-                plan.aot = _with_room(
-                    lambda: plan.jitted.lower(*operands).compile())
-            except Exception:
-                # an unlowerable corner falls back to the plain
-                # jax.jit path — correctness never depends on the
-                # ahead-of-time build
-                plan.aot = None
-            if plan.aot is not None and full_key is not None:
-                _MEMORY_CACHE[full_key] = (plan.aot, plan.out_spec,
-                                           plan.written)
-                _store_disk_entry(path, full_key, plan.aot, plan.out_spec,
-                                  plan.written)
-        if plan.aot is not None:
-            # what donation bought, as the executable has it: bytes of
-            # arguments whose buffers the outputs are written into. A
-            # step that should update a pool in place reads the pool's
-            # bytes here; 0 means every donated buffer was copied.
-            registry.gauge(
-                "paddle_tpu_jit_aliased_bytes",
-                "Bytes of argument buffers that the StaticFunction "
-                "program built last for this fn writes its outputs "
-                "into (XLA memory analysis): written state and "
-                "donate_argnums leaves updated in place",
-                labels=("fn",),
-            ).labels(fn=self.__name__).set(float(_aliased_bytes(plan.aot)))
-        registry.counter(
-            "paddle_tpu_jit_compiles_total",
-            "XLA programs materialized into a StaticFunction signature "
-            "cache, by source: \"fresh\" paid an XLA compile, \"disk\" "
-            "loaded the persistent compile cache, \"memory\" reused a "
-            "process-wide build", labels=("fn", "source"),
-        ).labels(fn=self.__name__, source=source).inc()
+                    source = "memory"
+                else:
+                    part = tracer.begin("step.load", name)
+                    ent = _load_disk_entry(path, full_key)
+                    if ent is not None:
+                        _MEMORY_CACHE[full_key] = ent
+                        source = "disk"
+            if ent is not None:
+                tracer.end(part)
+                part = None
+                plan.aot, plan.out_spec, written = ent
+                plan.set_written(written, len(state))
+                abstract = jax.tree_util.tree_map(_abstract,
+                                                  (state, lr_vals, arrays))
+                plan.trace = lambda: self._trace(plan, treedef, meta,
+                                                 *abstract)
+            else:
+                part = (tracer.begin("step.trace", name) if part is None
+                        else tracer.next("step.trace", part))
+                plan._jitted = self._trace(plan, treedef, meta, state,
+                                           lr_vals, arrays)
+            operands = plan.operands(state, lr_vals, arrays)
+            plan.abstract = jax.tree_util.tree_map(_abstract, operands)
+            if compile and ent is None:
+                part = tracer.next("step.lower", part)
+                try:
+                    lowered = _with_room(
+                        lambda: plan.jitted.lower(*operands))
+                    part = tracer.next("step.compile", part)
+                    plan.aot = _with_room(lowered.compile)
+                except Exception:
+                    # an unlowerable corner falls back to the plain
+                    # jax.jit path — correctness never depends on the
+                    # ahead-of-time build
+                    plan.aot = None
+                if plan.aot is not None and full_key is not None:
+                    _MEMORY_CACHE[full_key] = (plan.aot, plan.out_spec,
+                                               plan.written)
+                    _store_disk_entry(path, full_key, plan.aot,
+                                      plan.out_spec, plan.written)
+            tracer.end(part)
+            part = None
+            if plan.aot is not None:
+                # what donation bought, as the executable has it: bytes
+                # of arguments whose buffers the outputs are written
+                # into. A step that should update a pool in place reads
+                # the pool's bytes here; 0 means every donated buffer
+                # was copied.
+                registry.gauge(
+                    "paddle_tpu_jit_aliased_bytes",
+                    "Bytes of argument buffers that the StaticFunction "
+                    "program built last for this fn writes its outputs "
+                    "into (XLA memory analysis): written state and "
+                    "donate_argnums leaves updated in place",
+                    labels=("fn",),
+                ).labels(fn=name).set(float(_aliased_bytes(plan.aot)))
+            registry.counter(
+                "paddle_tpu_jit_compiles_total",
+                "XLA programs materialized into a StaticFunction "
+                "signature cache, by source: \"fresh\" paid an XLA "
+                "compile, \"disk\" loaded the persistent compile cache, "
+                "\"memory\" reused a process-wide build",
+                labels=("fn", "source"),
+            ).labels(fn=name, source=source).inc()
+        finally:
+            tracer.end(part)
+            tracer.end(build, (int(source == "fresh"),))
         return plan, operands
 
     # -- call ----------------------------------------------------------------
@@ -1053,22 +1080,31 @@ class StaticFunction:
                 self._setup_no_warmup()
             else:
                 return self._warmup(args, kwargs)
-        plan, operands, built = self._prepare(args, kwargs)
-        self._count_call(built)
-        if plan.aot is not None:
-            try:
-                out_arrays, new_written = plan.aot(*operands)
-            except Exception:
-                # a calling-convention mismatch (aval drift the key
-                # missed, a sharding the executable was not compiled for)
-                # degrades to the jax.jit path for good — the signature
-                # check fails BEFORE execution, so the donated buffers
-                # are still intact for the retry
-                plan.aot = None
+        # on the trace ring: step.operands -> step.launch -> step.outputs,
+        # under whatever span is open (the engine's step.dispatch)
+        tracer = get_tracer()
+        sp = tracer.begin("step.operands", self.__name__)
+        try:
+            plan, operands, built = self._prepare(args, kwargs)
+            self._count_call(built)
+            sp = tracer.next("step.launch", sp)
+            if plan.aot is not None:
+                try:
+                    out_arrays, new_written = plan.aot(*operands)
+                except Exception:
+                    # a calling-convention mismatch (aval drift the key
+                    # missed, a sharding the executable was not compiled
+                    # for) degrades to the jax.jit path for good — the
+                    # signature check fails BEFORE execution, so the
+                    # donated buffers are still intact for the retry
+                    plan.aot = None
+                    out_arrays, new_written = plan.jitted(*operands)
+            else:
                 out_arrays, new_written = plan.jitted(*operands)
-        else:
-            out_arrays, new_written = plan.jitted(*operands)
-        slots = self._slots
-        for i, v in zip(plan.written, new_written):
-            slots[i].set(v)
-        return _rebuild_out(plan.out_spec, out_arrays)
+            sp = tracer.next("step.outputs", sp)
+            slots = self._slots
+            for i, v in zip(plan.written, new_written):
+                slots[i].set(v)
+            return _rebuild_out(plan.out_spec, out_arrays)
+        finally:
+            tracer.end(sp)

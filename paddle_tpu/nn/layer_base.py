@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import dtypes
+from ..profiler.ring import get_tracer
 from ..tensor import Parameter, Tensor
 
 __all__ = ["Layer"]
@@ -144,7 +145,15 @@ class Layer:
             init = I.Constant(0.0)
         else:
             init = I.XavierUniform()
-        value = init(tuple(int(s) for s in shape), dtype)
+        # one ``param.init`` span per parameter on the trace ring: the
+        # initializer's call as the host sees it (JAX dispatches the
+        # values asynchronously)
+        tracer = get_tracer()
+        sp = tracer.begin("param.init", "param")
+        try:
+            value = init(tuple(int(s) for s in shape), dtype)
+        finally:
+            tracer.end(sp)
         name = attr.name if attr is not None and attr.name else None
         p = Parameter(value, trainable=not (attr is not None and not attr.trainable), name=name)
         if attr is not None:

@@ -1117,11 +1117,6 @@ class ServingEngine:
                            self.stats["tokens_per_sec"])
             record_counter("serving.page_utilization",
                            self.stats["page_utilization"])
-            # engine-scoped trace event: step.tokens keys on the
-            # engine_id, so trace_dump renders engine throughput as a
-            # counter track next to the per-request tracks
-            trace.emit("step.tokens", self.engine_id,
-                       arg=float(tokens_this_step))
             counts = ((_NO_GRID if landed is None else landed.counts)
                       + (tokens_this_step, ahead, discarded))
         finally:
@@ -2015,23 +2010,31 @@ class ServingEngine:
         return launch, grids, prev
 
     def _dispatch(self, launch: _Launch, grids: list, prev) -> None:
-        """The ``step.dispatch`` phase: one transfer of the grids, the
-        program call, and the pool's arrays swapped for the ones the call
-        returns. Nothing here waits for the device."""
-        self._phase = self._trace.next("step.dispatch", self._phase)
+        """The ``step.dispatch`` phase: one transfer of the grids
+        (``step.transfer``), the program call (its ``step.operands``,
+        ``step.launch`` and ``step.outputs``), and the pool's arrays
+        swapped for the ones the call returns. Nothing here waits for the
+        device."""
+        trace = self._trace
+        self._phase = trace.next("step.dispatch", self._phase)
         if self._step_prog is None:
             launch.fresh = True
             self._step_prog = self._compile_with_retry(
                 "serving.compile_step", self._make_step)
-        if prev is None:
-            if self._no_prev is None:
-                self._no_prev = Tensor(jax.device_put(np.zeros(
-                    self.max_batch_slots * self._spec_rows, np.int32)))
-            prev = self._no_prev
-        launch.t_prog = time.perf_counter()
-        # the ten grids go up in one transfer call, not ten
+        sp = trace.begin("step.transfer", self.engine_id)
+        try:
+            if prev is None:
+                if self._no_prev is None:
+                    self._no_prev = Tensor(jax.device_put(np.zeros(
+                        self.max_batch_slots * self._spec_rows, np.int32)))
+                prev = self._no_prev
+            launch.t_prog = time.perf_counter()
+            # the ten grids go up in one transfer call, not ten
+            sent = [Tensor(a) for a in jax.device_put(grids)]
+        finally:
+            trace.end(sp)
         res = self._step_prog(
-            *map(Tensor, jax.device_put(grids)), self._grammar_device, prev,
+            *sent, self._grammar_device, prev,
             *self.adapters.arrays(), *self.pool.step_flat())
         launch.nxt, launch.fin, flat = res[0], res[1], res[2:]
         self.pool.set_step_flat(flat)

@@ -924,7 +924,8 @@ class TestTPL010TraceEventParity:
     @pytest.mark.parametrize("site, name", [
         ('sp = self._trace.begin("step", "e")', "step"),
         ('sp = self._trace.next("step.fixture", sp)', "step.fixture"),
-        ('sp = self._trace.begin("sweep", "router")', "sweep")])
+        ('sp = self._trace.begin("sweep", "router")', "sweep"),
+        ('sp = tracer.begin("param.init", "param")', "param.init")])
     def test_span_sites_are_cataloged_like_emit_sites(self, tmp_path,
                                                       site, name):
         # spans (tracer.begin / tracer.next) name themselves at the call

@@ -225,7 +225,7 @@ def _imported(path, tree):
             yield from (f"{base}.{a.name}" for a in node.names)
 
 
-@pytest.mark.parametrize("package", ["models", "ops"])
+@pytest.mark.parametrize("package", ["models", "ops", "jit", "nn"])
 def test_lower_layers_do_not_import_serving(package):
     found = [(os.path.relpath(path, REPO), name)
              for path, tree in _modules(package)

@@ -35,6 +35,7 @@ import paddle_tpu as paddle
 from paddle_tpu import faults, metrics
 from paddle_tpu.loadgen import LoadDriver, TraceConfig, generate_trace
 from paddle_tpu.models import LlamaForCausalLM, llama_tiny
+from paddle_tpu.profiler import ring
 from paddle_tpu.serving import (RequestTracer, Router, ServingEngine,
                                 TTFT_BUCKETS, attribute_ttft, tracing,
                                 validate_events)
@@ -222,7 +223,7 @@ class TestFlightRecorder:
         clk.t = 10.0
         tr.emit("req.admit", "a")
         tr.emit("req.chunk", "a")
-        tr.emit("step.tokens", "m/0", arg=3.0)
+        tr.emit("brownout.level", "m", arg=1.0)
         before = _counter("paddle_tpu_trace_recorder_dumps_total",
                           reason="why not+ok")
         path = tr.dump_flight(reason="why not+ok")
@@ -234,7 +235,7 @@ class TestFlightRecorder:
         assert payload["window_s"] == 5.0
         names = {e["name"] for e in payload["events"]}
         assert "req.enqueue" not in names          # windowed out
-        assert names == {"req.admit", "req.chunk", "step.tokens"}
+        assert names == {"req.admit", "req.chunk", "brownout.level"}
         assert [e["seq"] for e in payload["requests"]["a"]] == [0, 1]
         assert _counter("paddle_tpu_trace_recorder_dumps_total",
                         reason="why not+ok") == before + 1
@@ -269,8 +270,12 @@ class TestEngineTimeline:
             assert names.count("req.retire") == 1
             assert tl[-1]["name"] == "req.retire"
             assert tl[-1]["label"] == "length"
-            # engine steps journal as engine-keyed counter events
-            assert any(e["name"] == "step.tokens" for e in tr.events())
+            # engine steps journal as engine-keyed ``step`` spans whose
+            # ``landed`` counter adds up to the tokens streamed
+            steps = [e for e in tr.events() if e["name"] == "step"]
+            assert steps and all(e["req_id"] == engine.engine_id
+                                 for e in steps)
+            assert sum(e["counts"]["landed"] for e in steps) == 4
             assert tr.dropped == 0
 
     def test_warm_prefix_emits_prefix_hit(self):
@@ -411,25 +416,26 @@ class TestChromeExport:
             _ev(1.5, "a", 1, "req.adopt", arg=1.0, label="m/1"),
             _ev(1.9, "a", 2, "req.retire", label="length"),
             _ev(1.2, "b", 0, "req.enqueue", arg=3.0, label="m/0"),
-            _ev(1.1, "m/0", 0, "step.tokens", arg=4.0),
+            dict(_ev(1.1, "m/0", 0, "step", arg=0.1), span=1, parent=0,
+                 counts={"rows": 4, "landed": 4}),
         ]
         doc, problems = td.chrome_trace(evs, pid=7)
         assert problems == []
         assert doc["displayTimeUnit"] == "ms"
         tracks = {e["args"]["name"] for e in doc["traceEvents"]
                   if e["ph"] == "M"}
-        assert tracks == {"req a", "req b"}
+        assert tracks == {"req a", "req b", "spans m/0"}
         # a migrated request is ONE track: its slices share a tid and
         # each gap is labeled by the event that ends it
         slices = [e for e in doc["traceEvents"]
-                  if e["ph"] == "X" and e["args"]["req_id"] == "a"]
+                  if e["ph"] == "X" and e["args"].get("req_id") == "a"]
         assert len({e["tid"] for e in slices}) == 1
         hop = next(e for e in slices if e["name"] == "req.adopt")
         assert hop["ts"] == pytest.approx(1.0e6)
         assert hop["dur"] == pytest.approx(0.5e6)
         counters = [e for e in doc["traceEvents"] if e["ph"] == "C"]
         assert counters == [{
-            "name": "step.tokens/m/0", "ph": "C", "cat": "counter",
+            "name": "landed/m/0", "ph": "C", "cat": "counter",
             "ts": pytest.approx(1.1e6), "pid": 7,
             "args": {"value": 4.0}}]
 
@@ -688,7 +694,7 @@ class TestSpans:
             def __exit__(self, *exc):
                 pass
 
-        monkeypatch.setattr(tracing, "TraceAnnotation", _Ann)
+        monkeypatch.setattr(ring, "TraceAnnotation", _Ann)
         tr = RequestTracer(capacity=64, enabled=False)
         sp = tr.begin("step", "e")
         ph = tr.next("step.pack", tr.begin("step.plan", "e"))
@@ -734,7 +740,8 @@ class TestSpans:
                 with pytest.raises(RuntimeError):
                     eng.step()
             eng.run()
-            spans = _spans(tr.events())
+            spans = [e for e in _spans(tr.events())
+                     if e["req_id"] == eng.engine_id]
         assert tr._open is None
         assert [e["name"] for e in spans[:2]] == ["step.plan", "step"]
         assert "counts" not in spans[1]
@@ -742,11 +749,10 @@ class TestSpans:
 
     def test_trace_dump_renders_spans_as_complete_events(self):
         td = _trace_dump_mod()
-        step = dict(_ev(2.0, "m/0", 2, "step", arg=0.5), span=2, parent=1,
+        step = dict(_ev(2.0, "m/0", 1, "step", arg=0.5), span=2, parent=1,
                     counts={"rows": 3, "bucket": 8})
         evs = [
             dict(_ev(1.6, "m/0", 0, "step.plan", arg=0.1), span=3, parent=2),
-            _ev(1.9, "m/0", 1, "step.tokens", arg=2.0),
             step,
             dict(_ev(2.1, "router", 0, "sweep", arg=0.7), span=1, parent=0),
             _ev(1.0, "a", 0, "req.enqueue"),

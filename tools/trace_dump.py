@@ -7,12 +7,12 @@ becomes ONE named track — every gap between consecutive journal events
 is a slice labeled by the event that ENDS it, so a track reads as
 "where this request's time went" (queue wait ends at req.admit, a
 prefill wait ends at req.chunk, a migration hop shows as
-req.export/req.adopt slices) — each engine's ``step.tokens`` events
-become a counter track, and the spans (the router's ``sweep``, each
-engine's ``step`` and its ``step.plan`` … ``step.land`` phases) become
-"X" slices with their own start and duration on one track per engine,
-nested as they ran, the ``step`` slice carrying its grid counters in
-``args``. A request that hopped engines mid-decode
+req.export/req.adopt slices) — each engine's ``step`` spans' ``landed``
+counter becomes a counter track, and the spans (the router's ``sweep``,
+each engine's ``step`` and its ``step.plan`` … ``step.land`` phases, a
+compiled call's ``step.operands`` … ``step.outputs``) become "X" slices
+with their own start and duration on one track per key, nested as they
+ran, the ``step`` slice carrying its grid counters in ``args``. A request that hopped engines mid-decode
 renders as ONE contiguous track: the tracer's fleet-global seq stream
 orders events across the hop, and the exactly-once audit
 (``tracing.validate_events``) runs before export — a duplicated or
@@ -45,17 +45,16 @@ __all__ = ["chrome_trace", "load_events", "main"]
 def chrome_trace(events, pid=None):
     """Chrome-trace dict for a list of journal event dicts (the shape
     ``RequestTracer.events()`` / ``dump_flight`` emit). ``req.*``
-    timelines become one named track per request; ``step.tokens``
-    becomes one counter track per engine; spans (events with a ``span``
-    id: ``t`` is the end, ``arg`` the seconds) become one track per key
-    after the request tracks."""
+    timelines become one named track per request; the ``landed``
+    counter of each engine's ``step`` spans becomes one counter track per
+    engine; spans (events with a ``span`` id: ``t`` is the end, ``arg``
+    the seconds) become one track per key after the request tracks."""
     from paddle_tpu.serving import tracing
 
     pid = os.getpid() if pid is None else pid
     out = []
     problems = tracing.validate_events(events)
-    req_events = [e for e in events
-                  if e["name"] != "step.tokens" and "span" not in e]
+    req_events = [e for e in events if "span" not in e]
 
     by_req = {}
     for e in req_events:
@@ -77,23 +76,25 @@ def chrome_trace(events, pid=None):
                          "arg": e["arg"], "label": e["label"]}})
     span_tids = {}
     for e in events:
-        if e["name"] == "step.tokens":
-            out.append({"name": f"step.tokens/{e['req_id']}", "ph": "C",
+        if "span" not in e:
+            continue
+        landed = e.get("counts", {}).get("landed")
+        if e["name"] == "step" and landed is not None:
+            out.append({"name": f"landed/{e['req_id']}", "ph": "C",
                         "cat": "counter", "ts": e["t"] * 1e6, "pid": pid,
-                        "args": {"value": e["arg"]}})
-        elif "span" in e:
-            key = str(e["req_id"])
-            tid = span_tids.get(key)
-            if tid is None:
-                tid = span_tids[key] = len(by_req) + len(span_tids) + 2
-                out.append({"name": "thread_name", "ph": "M", "pid": pid,
-                            "tid": tid, "args": {"name": f"spans {key}"}})
-            out.append({
-                "name": e["name"], "ph": "X", "cat": "span",
-                "ts": (e["t"] - e["arg"]) * 1e6, "dur": e["arg"] * 1e6,
-                "pid": pid, "tid": tid,
-                "args": {"key": key, "span": e["span"],
-                         "parent": e["parent"], **e.get("counts", {})}})
+                        "args": {"value": float(landed)}})
+        key = str(e["req_id"])
+        tid = span_tids.get(key)
+        if tid is None:
+            tid = span_tids[key] = len(by_req) + len(span_tids) + 2
+            out.append({"name": "thread_name", "ph": "M", "pid": pid,
+                        "tid": tid, "args": {"name": f"spans {key}"}})
+        out.append({
+            "name": e["name"], "ph": "X", "cat": "span",
+            "ts": (e["t"] - e["arg"]) * 1e6, "dur": e["arg"] * 1e6,
+            "pid": pid, "tid": tid,
+            "args": {"key": key, "span": e["span"],
+                     "parent": e["parent"], **e.get("counts", {})}})
     return ({"traceEvents": out, "displayTimeUnit": "ms"}, problems)
 
 
