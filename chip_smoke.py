@@ -202,23 +202,33 @@ def paged_kernel_phase(nh: int, nkv: int, hd: int, page_size: int,
     """The Pallas ragged kernel against ``ref_paged_attention`` on this
     device at the engine's widths, bf16 and int8 pages, seeded inputs —
     the serve phase's token check cannot see attention through near-random
-    weights, so the kernel's numbers are checked here directly."""
+    weights, so the kernel's numbers are checked here directly. Beside 16
+    decode rows at lengths 1 to the table's end ride two prompt chunks,
+    each its rows at consecutive positions under one table (the last
+    ending at the table's end): query blocks of many rows."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas import paged_attention as pa
     from paddle_tpu.quantization.observers import quantize_kv
 
     rng = np.random.default_rng(2)
-    rows, num_pages = 16, 2 * pages_per_seq
+    decode, num_pages = 16, 2 * pages_per_seq
     max_len = pages_per_seq * page_size
+    chunks = [(40, max_len), (24, max_len // 2)]  # (rows, last length)
+    rows = decode + sum(c for c, _ in chunks)
     q = jnp.asarray(rng.standard_normal((rows, nh, hd)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((num_pages, nkv, page_size, hd)),
                     jnp.bfloat16)
     v = jnp.asarray(rng.standard_normal((num_pages, nkv, page_size, hd)),
                     jnp.bfloat16)
-    bt = jnp.asarray(rng.integers(1, num_pages, (rows, pages_per_seq)),
-                     jnp.int32)
-    lens = jnp.asarray(np.linspace(1, max_len, rows).astype(np.int32))
+    bt = [rng.integers(1, num_pages, (decode, pages_per_seq))]
+    lens = [np.linspace(1, max_len, decode)]
+    for c, last in chunks:
+        bt.append(np.repeat(rng.integers(1, num_pages, (1, pages_per_seq)),
+                            c, axis=0))
+        lens.append(np.arange(last - c + 1, last + 1))
+    bt = jnp.asarray(np.concatenate(bt), jnp.int32)
+    lens = jnp.asarray(np.concatenate(lens).astype(np.int32))
     worst = {}
     for name in ("bf16", "int8"):
         kw = {}

@@ -81,8 +81,8 @@ faults.declare_point(
 # gives them; ``events()`` renders them as a dict under ``"counts"``.
 COUNTERS: Dict[str, Tuple[str, ...]] = {
     # rows: real rows of the token grid; bucket: its padded length T;
-    # seqs: slots with a row; kv_walked: sum over real rows of (row
-    # position + 1), the keys the paged kernel's grid walks this step;
+    # seqs: slots with a row; kv_walked: the keys the paged kernel walks
+    # this step, its last row's position + 1 for each query block;
     # kv_held: sum over those slots of (last row's position + 1), the
     # keys that exist to be read once — all of the step the call landed;
     # landed: tokens landed; ahead: 1 where the call launched a step while

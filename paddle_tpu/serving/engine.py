@@ -77,6 +77,7 @@ import numpy as np
 from .. import faults, jit, metrics
 from ..autograd.engine import no_grad
 from ..ops._apply import apply_op, ensure_tensor
+from ..ops.pallas.paged_attention import query_block_rows, walked_keys
 from ..tensor import Tensor
 from . import tracing
 from .adapters import AdapterStore
@@ -365,6 +366,12 @@ class ServingEngine:
                                      n_kv, head_dim, dtype=kv_dtype,
                                      engine_id=self.engine_id,
                                      model_id=self.model_id)
+        # rows the paged kernel puts in one query block at this pool's
+        # shapes: the walk counter (_pack) counts what the kernel walks
+        self._query_rows = query_block_rows(
+            self.pages_per_seq, n_kv, self.page_size, head_dim,
+            jnp.dtype(self.pool.dtype).itemsize,
+            int(model.config.num_heads))
         # host offload tier (docs/SERVING.md "KV page tiers &
         # quantization"): when armed, admission pressure parks cold
         # lower-urgency slots — their pages swap to the pool's
@@ -1958,11 +1965,11 @@ class ServingEngine:
         kv_walked = kv_held = n_decode = n_draft = 0
         for i, st, toks, poss, is_chunk, d, src in rows:
             c = toks.size
-            # the keys the kernel walks: row at position p attends p + 1
-            # of them, so a slot's c consecutive rows walk the sum; the
-            # keys that exist for the slot are its last row's
+            # the keys the kernel walks: a slot's c consecutive rows form
+            # query blocks of up to _query_rows rows, each walking its
+            # last row's keys; the keys that exist are the last row's
             first, last = int(poss[0]), int(poss[-1])
-            kv_walked += c * (first + last + 2) // 2
+            kv_walked += walked_keys(first + 1, c, self._query_rows)
             kv_held += last + 1
             tok[cur:cur + c, 0] = toks
             tok_pos[cur:cur + c] = poss

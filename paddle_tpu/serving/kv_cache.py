@@ -33,9 +33,9 @@ pool can cover every live sequence's ``prompt + max_new_tokens`` tail, so
 a mid-decode out-of-pages abort is impossible without preemption.
 
 Layout note: the last two axes are ``(page_size, head_dim)`` because the
-TPU lowering of the paged kernel (ops/pallas/paged_attention.py) DMAs one
-head's page per grid step and needs that block's last two dims to be the
-array's own. The kv-head axis is second, so a later multi-chip serving PR
+TPU lowering of the paged kernel (ops/pallas/paged_attention.py) DMAs a
+whole page, every head, as one contiguous run, and a copy's last two dims
+must be the array's own. The kv-head axis is second, so a later multi-chip serving PR
 can still shard ``n_kv_heads`` over 'mp' without touching the allocator
 or block tables (page ids are replicated host metadata).
 

@@ -177,51 +177,117 @@ class TestPagedAttentionKernel:
         assert str(4 * T * (pages + 1)) in msg
         assert str(pa.SMEM_PREFETCH_LIMIT_BYTES) in msg
 
-    @pytest.mark.parametrize("ppb", [None, 1, 2],
-                             ids=["one-block", "page-blocks", "two-pages"])
-    def test_ragged_flattened_rows_match_fallback(self, monkeypatch, ppb):
+    # each case: the rows' groups as (table, rows, first position) — a
+    # group is one slot's rows at consecutive positions under its table —
+    # then bucket padding rows, and the budgets cut so that a page block
+    # holds `ppb` pages and a query block at most 8 rows (`q8`)
+    @pytest.mark.parametrize("groups,pad,ppb,q8,nh,quantized", [
+        ([("a", 1, 12), ("b", 5, 7), ("c", 1, 0)], 0, None, False, 4, False),
+        ([("a", 1, 12), ("b", 5, 7), ("c", 1, 0)], 0, 1, False, 4, False),
+        ([("a", 1, 12), ("b", 5, 7), ("c", 1, 0)], 0, 2, False, 4, False),
+        # a chunk of 19 rows: query blocks of 8, 8 and 3
+        ([("a", 1, 30), ("b", 19, 4), ("c", 1, 2)], 0, 2, True, 4, False),
+        # a decode row and its three draft rows, beside a lone decode row
+        ([("a", 4, 20), ("b", 1, 9)], 0, 2, True, 4, False),
+        # neighbouring slots that share their first pages, not their
+        # tables, at positions that run on across the boundary
+        ([("a", 4, 10), ("a+", 4, 14)], 0, 1, True, 4, False),
+        # identical tables on adjacent rows at positions that do not run on
+        ([("a", 1, 5), ("a", 1, 9), ("a", 1, 9), ("a", 2, 3)], 0, 2, True,
+         4, False),
+        # an engine bucket: real rows, then padding rows on the null page
+        ([("a", 1, 12), ("b", 9, 7)], 6, 2, True, 4, False),
+        ([("a", 1, 30), ("b", 19, 4), ("c", 1, 2)], 3, 2, True, 4, True),
+        ([("a", 1, 30), ("b", 19, 4), ("c", 1, 2)], 3, 2, True, 8, False),
+    ], ids=["one-block", "page-blocks", "two-pages", "chunk-over-q",
+            "drafts", "shared-prefix", "not-consecutive", "padding",
+            "int8", "gqa-4"])
+    def test_ragged_flattened_rows_match_fallback(
+            self, monkeypatch, groups, pad, ppb, q8, nh, quantized):
         """The unified-step contract (ISSUE 11): mixed per-slot query
         lengths ride as FLATTENED rows — a decode slot contributes one
         row, a chunk slot one row per token, each with its slot's block
-        table repeated and consecutive positions. The kernel serves the
-        ragged grid unchanged (interpret mode) and matches the jnp
-        fallback."""
+        table repeated and consecutive positions. The kernel gathers a
+        slot's rows into query blocks that walk its pages once, and
+        matches the jnp fallback row for row (interpret mode); a padding
+        row, whose table names no page, reads zeros."""
         monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
         import jax.numpy as jnp
 
         from paddle_tpu.ops.pallas import paged_attention as pa
+        from paddle_tpu.quantization.observers import quantize_kv
 
         rng = np.random.default_rng(1)
-        nh, nkv, hd, page, pages, width = 4, 2, 64, 8, 20, 4
+        nkv, hd, page, pages, width = 2, 64, 8, 20, 4
         if ppb is not None:  # a chunk's rows name the same pages, block
             # after block, each masked at its own length
             monkeypatch.setattr(pa, "KV_BLOCK_VMEM_BYTES",
                                 4 * ppb * nkv * page * hd * 4)
-        # slot A: decode (q_len 1 at pos 12); slot B: a 5-token chunk at
-        # positions 7..11; slot C: decode at pos 0 (first decode step)
-        q_lens = [1, 5, 1]
-        starts = [12, 7, 0]
-        T = sum(q_lens)
-        slot_bt = rng.integers(1, pages, (3, width)).astype(np.int32)
-        row_bt = np.concatenate([
-            np.repeat(slot_bt[i:i + 1], q_lens[i], axis=0)
-            for i in range(3)])
-        row_lens = np.concatenate([
-            np.arange(starts[i], starts[i] + q_lens[i]) + 1
-            for i in range(3)]).astype(np.int32)
+        if q8:
+            monkeypatch.setattr(pa, "QUERY_BLOCK_VMEM_BYTES", 0)
+            assert pa.query_block_rows(width, nkv, page, hd, 4, nh) == 8
+        tables = {name: rng.integers(1, pages, width).astype(np.int32)
+                  for name in ("a", "b", "c")}
+        tables["a+"] = tables["a"].copy()
+        tables["a+"][2:] = rng.integers(1, pages, width - 2)
+        assert (tables["a+"] != tables["a"]).any()
+        row_bt = np.concatenate(
+            [np.repeat(tables[t][None], n, axis=0) for t, n, _ in groups]
+            + [np.zeros((pad, width), np.int32)])
+        row_lens = np.concatenate(
+            [np.arange(p0, p0 + n) + 1 for _, n, p0 in groups]
+            + [np.ones(pad, np.int64)]).astype(np.int32)
+        T = len(row_lens)
         q = jnp.asarray(rng.standard_normal((T, nh, hd)), jnp.float32)
         kp = jnp.asarray(rng.standard_normal((pages, nkv, page, hd)),
                          jnp.float32)
         vp = jnp.asarray(rng.standard_normal((pages, nkv, page, hd)),
                          jnp.float32)
+        kw = {}
+        if quantized:
+            kp, ks = quantize_kv(kp)
+            vp, vs = quantize_kv(vp)
+            kw = dict(k_scale=ks, v_scale=vs)
         ref = pa.ref_paged_attention(q, kp, vp, jnp.asarray(row_bt),
-                                     jnp.asarray(row_lens))
+                                     jnp.asarray(row_lens), **kw)
         out = pa.ragged_paged_attention(q, kp, vp, jnp.asarray(row_bt),
                                         jnp.asarray(row_lens),
-                                        use_kernel=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                        use_kernel=True, **kw)
+        live = T - pad
+        np.testing.assert_allclose(np.asarray(out)[:live],
+                                   np.asarray(ref)[:live],
                                    atol=2e-5, rtol=2e-5)
+        assert not np.asarray(out)[live:].any()
 
+    @pytest.mark.parametrize("lens,tables,q_rows,marked", [
+        # a run of 5 under one table, cut at 2 rows a block
+        ([3, 4, 5, 6, 7], [1, 1, 1, 1, 1], 2, [3, -4, 5, -6, 7]),
+        # another table, a position that does not run on, a repeat
+        ([3, 4, 9, 10, 10], [1, 2, 2, 2, 2], 8, [3, 4, 9, -10, 10]),
+        # padding rows name the null page: no block, nothing walked
+        ([8, 9, 1, 1], [1, 1, 0, 0], 8, [8, -9, 0, 0]),
+    ], ids=["cut-at-q", "breaks", "padding"])
+    def test_query_blocks_mark_the_rows(self, lens, tables, q_rows, marked):
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        bt = np.asarray(tables, np.int32)[:, None] * np.ones((1, 3),
+                                                              np.int32)
+        got = pa._query_blocks(jnp.asarray(bt), jnp.asarray(lens, jnp.int32),
+                               q_rows)
+        assert np.asarray(got).tolist() == marked
+
+    @pytest.mark.parametrize("first,n,q_rows,walked", [
+        (31, 1, 8, 31),                         # a decode row
+        (21, 4, 8, 24),                         # with its three drafts
+        (5, 19, 8, (5 + 7) + (5 + 15) + (5 + 18)),  # blocks of 8, 8, 3
+        (100, 16, 8, 107 + 115),                # two whole blocks
+    ], ids=["decode", "drafts", "chunk-over-q", "whole-blocks"])
+    def test_walked_keys_is_one_walk_a_block(self, first, n, q_rows, walked):
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        assert pa.walked_keys(first, n, q_rows) == walked
 
 # ───────────────────────────── kv-cache pool ─────────────────────────────
 

@@ -608,9 +608,12 @@ class TestSpans:
     def test_step_counters_on_a_hand_built_grid(self):
         """(b) two decode rows at contexts 21 and 32 and one 5-token
         chunk at positions 12-16 (its first 12 tokens come from the
-        prefix cache): rows 7, kv_walked 22 + 33 + (13+..+17), kv_held
-        22 + 33 + 17. The second call launches that step behind the
-        decode step in flight; the third lands it."""
+        prefix cache): rows 7, kv_walked 22 + 33 + 17 (the chunk's five
+        rows are one query block of the kernel, which walks its last
+        row's keys once), kv_held 22 + 33 + 17. The second call launches
+        that step behind the decode step in flight; the third lands it."""
+        from paddle_tpu.ops.pallas.paged_attention import walked_keys
+
         with _fresh(capacity=4096) as tr:
             eng = ServingEngine(_model64(), page_size=4, num_pages=64,
                                 max_batch_slots=3, max_model_len=64,
@@ -631,10 +634,41 @@ class TestSpans:
         assert last["counts"] == {
             "rows": 7, "bucket": eng._grid_tokens(7), "decode_rows": 2,
             "chunk_rows": 5, "draft_rows": 0, "seqs": 3,
-            "kv_walked": 22 + 33 + (13 + 14 + 15 + 16 + 17),
+            "kv_walked": 22 + 33 + walked_keys(13, 5, eng._query_rows),
             "kv_held": 22 + 33 + 17, "landed": 3, "ahead": 1,
             "discarded": 0}
         assert last["parent"] == 0     # no router, no sweep
+        assert walked_keys(13, 5, eng._query_rows) == 17
+
+    def test_walk_counter_follows_the_kernels_query_blocks(
+            self, monkeypatch):
+        """``kv_walked`` counts what the paged kernel walks: a chunk of c
+        rows is ceil(c / Q) walks, each its block's last row's keys, and
+        a decode row one walk. Q is the kernel module's rule at the pool's
+        shapes, cut here so that a 30-token prompt spans four blocks."""
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        monkeypatch.setattr(pa, "QUERY_BLOCK_VMEM_BYTES", 0)
+        with _fresh(capacity=4096) as tr:
+            eng = ServingEngine(_model64(), page_size=4, num_pages=64,
+                                max_batch_slots=2, max_model_len=64,
+                                token_budget=64)
+            Q = pa.query_block_rows(eng.pages_per_seq, 1, 4, 16, 4, 1)
+            assert eng._query_rows == Q == 8
+            rng = np.random.RandomState(7)
+            eng.add_request(rng.randint(1, 32, (11,)), max_new_tokens=8)
+            eng.step()     # its prompt, whole
+            eng.add_request(rng.randint(1, 32, (30,)), max_new_tokens=2)
+            eng.step()     # its decode at 11, launched ahead
+            eng.step()     # the decode at 12 beside the new prompt
+            last = [e for e in tr.events() if e["name"] == "step"][-1]
+        c = last["counts"]
+        assert (c["decode_rows"], c["chunk_rows"]) == (1, 30)
+        # the decode row at position 12; the chunk at 0-29 in blocks of
+        # 8, 8, 8 and 6 rows, walking 8, 16, 24 and 30 keys
+        assert c["kv_held"] == 13 + 30
+        assert c["kv_walked"] == 13 + (8 + 16 + 24 + 30)
+        assert c["kv_walked"] == 13 + pa.walked_keys(1, 30, Q)
 
     def test_spans_lie_on_the_profiler_trace_host_plane(self, tmp_path):
         """(c) under jax.profiler.start_trace the .xplane.pb's host
