@@ -12,6 +12,11 @@ from .llama import (  # noqa: F401,E402
 )
 
 __all__ += ["LlamaConfig", "LlamaModel", "LlamaForCausalLM", "llama_tiny"]
+from .olmo_hybrid import (  # noqa: F401,E402
+    OlmoHybridConfig, OlmoHybridForCausalLM, OlmoHybridModel,
+)
+
+__all__ += ["OlmoHybridConfig", "OlmoHybridModel", "OlmoHybridForCausalLM"]
 from .bert import (  # noqa: F401,E402
     BertConfig, BertForPretraining, BertForSequenceClassification, BertModel,
     bert_base, bert_tiny,

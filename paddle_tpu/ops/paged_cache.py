@@ -1,23 +1,61 @@
-"""A layer's paged KV cache as ONE opaque value, and the one function that
-opens it inside the compiled serving step.
+"""A layer's cache as ONE opaque value, and the functions that open it
+inside the compiled serving step, one for each kind the pool holds.
 
 ``cache`` is whatever :meth:`PagedKVCachePool.layer_caches` groups for a
-layer: today ``(k, v)`` page pools ``[pages, n_kv_heads, page_size,
-head_dim]`` or, for int8 pages, ``(k, v, k_scales, v_scales)``. A trunk's
-``forward_paged`` projects its rows and calls :func:`paged_attend`; it never
-indexes, measures or unpacks the value, so a new cache kind (a sliding
-window, a latent cache, a recurrent state) is a change here and in the pool,
-not in every model (docs/SERVING.md "Adding a trunk").
+layer, by the layer's kind:
+
+- paged K/V: ``(k, v)`` page pools ``[pages, n_kv_heads, page_size,
+  head_dim]`` or, for int8 pages, ``(k, v, k_scales, v_scales)``, opened by
+  :func:`paged_attend`;
+- per-slot state: ``(state, tail)``, a gated delta-rule state ``[slots, H/G,
+  d_k, G*d_v]`` float32 and the short convolution's tail ``[slots, taps,
+  channels]`` of the last pre-conv inputs, opened by :func:`state_attend`.
+
+A trunk's ``forward_paged`` projects its rows and calls one of them; it
+never indexes, measures or unpacks the value, so a new cache kind (a sliding
+window, a latent cache) is a change here and in the pool, not in every model
+(docs/SERVING.md "Adding a trunk").
 """
 from __future__ import annotations
 
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 
 from ..quantization.observers import quantize_kv
 from ._apply import apply_op, ensure_tensor
+from .pallas.gated_delta import gated_delta, state_head_group
 from .pallas.paged_attention import ragged_paged_attention
 
-__all__ = ["paged_attend", "write_step_kv"]
+__all__ = ["SlotState", "paged_attend", "state_attend", "write_step_kv"]
+
+
+@dataclasses.dataclass(frozen=True)
+class SlotState:
+    """The kind of a layer that keeps per-slot state instead of pages: a
+    gated delta-rule state of ``heads`` x ``key_dim`` x ``value_dim`` in
+    float32 (it multiplies into itself every token) and the short
+    convolution's tail, the last ``conv_taps`` pre-conv inputs of
+    ``conv_channels`` channels in ``tail_dtype`` (the step's activations'),
+    per slot. The state's heads lie side by side on its lane axis as
+    ops/pallas/gated_delta.py lays them."""
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv_channels: int
+    conv_taps: int = 3
+    tail_dtype: str = "bfloat16"
+
+    def shapes(self, slots: int):
+        g = state_head_group(self.heads, self.value_dim)
+        return ((slots, self.heads // g, self.key_dim, g * self.value_dim),
+                (slots, self.conv_taps, self.conv_channels))
+
+    def bytes_per_slot(self) -> int:
+        tail = jnp.dtype(self.tail_dtype).itemsize
+        return (4 * self.heads * self.key_dim * self.value_dim
+                + tail * self.conv_taps * self.conv_channels)
 
 
 def _write_rows(pool, slots, rows):
@@ -86,3 +124,87 @@ def paged_attend(cache, q_rows, k_rows, v_rows, block_tables, positions,
                                         positions, *cache)],
         name="paged_attend")
     return ctx, tuple(cache)
+
+
+def _segments(slots):
+    """Per row: the row that starts its slot's run of rows, and whether it
+    ends one (a slot's rows are consecutive: the engine packs them so)."""
+    t = jnp.arange(slots.shape[0], dtype=jnp.int32)
+    new = slots[1:] != slots[:-1]
+    first = jnp.concatenate([jnp.ones((1,), bool), new])
+    last = jnp.concatenate([new, jnp.ones((1,), bool)])
+    return jax.lax.cummax(jnp.where(first, t, 0)), last
+
+
+def conv_rows(tail, x, w, slots, positions):
+    """The causal depthwise convolution of kernel ``K`` over a step's rows:
+    row ``t`` at position ``p`` takes its own input and the ``K - 1`` before
+    it in its sequence, from this step's rows of its slot where they are
+    here and from the slot's ``tail`` where they came in an earlier step,
+    and zeros before position 0. ``x`` ``[T, C]``, ``w`` ``[C, K]`` (tap
+    ``K - 1`` multiplies the row's own input), ``tail`` ``[slots, K - 1,
+    C]`` oldest first. Returns (y ``[T, C]`` float32, the tail with each
+    slot's last ``K - 1`` inputs, written at the row that ends its run; the
+    other rows' writes go to the last slot, the engine's spare)."""
+    taps = w.shape[-1] - 1
+    start, last = _segments(slots)
+    t = jnp.arange(x.shape[0], dtype=jnp.int32)
+    back = [x]                                  # back[j]: input at p - j
+    for j in range(1, taps + 1):
+        src = t - j
+        here = x[jnp.maximum(src, 0)]
+        before = tail[slots, jnp.clip(taps + src - start, 0, taps - 1)]
+        got = jnp.where((src >= start)[:, None], here, before)
+        back.append(jnp.where((positions >= j)[:, None], got,
+                              jnp.zeros((), x.dtype)))
+    wf = w.astype(jnp.float32)
+    y = sum(back[j].astype(jnp.float32) * wf[:, taps - j]
+            for j in range(taps + 1))
+    new = jnp.stack(back[taps - 1::-1], axis=1)    # p-taps+1 .. p
+    dest = jnp.where(last, slots, tail.shape[0] - 1)
+    return y, tail.at[dest].set(new.astype(tail.dtype))
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def state_attend(cache, q_rows, k_rows, v_rows, log_decay, beta, conv_w,
+                 slots, positions):
+    """The short-convolution gated delta rule of a linear-attention layer,
+    one row per query token like :func:`paged_attend`: the per-slot half of
+    the layer, which opens the layer's ``(state, tail)``.
+
+    ``q_rows``/``k_rows`` ``[T, H, d_k]`` and ``v_rows`` ``[T, H, d_v]`` are
+    the trunk's projections before the convolution; ``conv_w`` their
+    depthwise kernels ``(w_q, w_k, w_v)``, each ``[channels, K]``;
+    ``log_decay`` ``[T, H]`` is ``g`` (the decay is ``exp(g)``) and ``beta``
+    ``[T, H]`` the write strength, both float32; ``slots`` ``[T]`` is each
+    row's owner (the spare slot for padding rows) and ``positions`` ``[T]``
+    its position, 0 starting a sequence afresh. Each of q, k and v passes
+    the convolution (:func:`conv_rows`) and SiLU; q and k are L2-normalised
+    per head, q scaled by ``d_k ** -0.5``; then the state advances row by
+    row (ops/pallas/gated_delta.py). Returns (o ``[T, H, d_v]`` float32,
+    cache) with ``cache`` updated, in the pool's order."""
+    def fn(q, k, v, g, b, wq, wk, wv, sl, pos, state, tail):
+        T, H, dk = q.shape
+        sl = sl.astype(jnp.int32).reshape(-1)
+        pos = pos.astype(jnp.int32).reshape(-1)
+        x = jnp.concatenate([q.reshape(T, -1), k.reshape(T, -1),
+                             v.reshape(T, -1)], axis=-1)
+        y, tail = conv_rows(tail, x, jnp.concatenate([wq, wk, wv], axis=0),
+                            sl, pos)
+        y = jax.nn.silu(y)
+        nq = H * dk
+        qc = _l2norm(y[:, :nq].reshape(T, H, dk)) * (dk ** -0.5)
+        kc = _l2norm(y[:, nq:2 * nq].reshape(T, H, dk))
+        vc = y[:, 2 * nq:].reshape(T, H, -1)
+        o, state = gated_delta(state, qc, kc, vc, jnp.exp(g), b, sl, pos)
+        return o, state, tail
+
+    o, *cache = apply_op(
+        fn, [ensure_tensor(t) for t in (q_rows, k_rows, v_rows, log_decay,
+                                        beta, *conv_w, slots, positions,
+                                        *cache)],
+        name="state_attend")
+    return o, tuple(cache)

@@ -87,10 +87,14 @@ COUNTERS: Dict[str, Tuple[str, ...]] = {
     # keys that exist to be read once — all of the step the call landed;
     # landed: tokens landed; ahead: 1 where the call launched a step while
     # the previous one was still in flight; discarded: rows thrown away
-    # because their sequence had ended before they landed
+    # because their sequence had ended before they landed; state_rows:
+    # rows that advanced a per-slot recurrent state (0 for a pool of pages
+    # alone), state_chunk_rows: those of prompt chunks, state_segments: the
+    # slots' runs of rows that carried a state through the step
     "step": ("rows", "bucket", "decode_rows", "chunk_rows", "draft_rows",
              "seqs", "kv_walked", "kv_held", "landed", "ahead",
-             "discarded"),
+             "discarded", "state_rows", "state_chunk_rows",
+             "state_segments"),
     # fresh: 1 where the build paid a trace and an XLA compile, 0 where a
     # cache held the program (paddle_tpu_jit_compiles_total's source)
     "step.build": ("fresh",),

@@ -47,7 +47,8 @@ from .api import CompletionAPI
 from .engine import ServingEngine
 from .grammar import GrammarFSM, ToyTokenizer, schema_to_regex, toy_tokenizer
 from .kv_cache import (HostPageStore, PagedKVCachePool, PrefixCache,
-                       normalize_kv_dtype, page_bytes, pages_for_hbm_budget)
+                       SlotState, normalize_kv_dtype, page_bytes,
+                       pages_for_hbm_budget)
 from .overload import (AdmissionShedError, DrainEstimator, OverloadConfig,
                        OverloadController, RetryBudget)
 from .router import EngineHandle, NoHealthyEngineError, Router
@@ -63,7 +64,7 @@ __all__ = [
     "Request", "RequestOutput", "CompletionAPI",
     "BackpressureError", "Router", "EngineHandle", "NoHealthyEngineError",
     "NGramDrafter", "page_bytes", "pages_for_hbm_budget",
-    "HostPageStore", "normalize_kv_dtype",
+    "HostPageStore", "SlotState", "normalize_kv_dtype",
     "AdapterStore", "random_adapter", "GrammarFSM", "ToyTokenizer",
     "toy_tokenizer", "schema_to_regex",
     "RequestTracer", "TTFT_BUCKETS", "attribute_ttft", "get_tracer",

@@ -48,9 +48,9 @@ class AdapterStore:
                 f"zero-delta identity), got {capacity}")
         if rank < 1:
             raise ValueError(f"rank must be >= 1, got {rank}")
+        # a trunk may offer no site: its steps carry no adapter arrays and
+        # register() refuses
         self.sites = tuple((str(n), int(i), int(o)) for n, i, o in sites)
-        if not self.sites:
-            raise ValueError("at least one projection site is required")
         self.num_layers = int(num_layers)
         self.rank = int(rank)
         self.capacity = int(capacity)
@@ -90,6 +90,9 @@ class AdapterStore:
         if name is None or name == "":
             raise ValueError("adapter name must be a non-empty string "
                              "(None means 'no adapter', slot 0)")
+        if not self.sites:
+            raise ValueError(f"adapter {name!r}: this model offers no LoRA "
+                             "projection site")
         missing = [s for s, _, _ in self.sites if s not in weights]
         if missing:
             raise ValueError(

@@ -93,6 +93,8 @@ _engine_counter = itertools.count()
 # the grid counters of a call that landed no step (tracing.COUNTERS["step"]
 # up to ``kv_held``)
 _NO_GRID = (0, 0, 0, 0, 0, 0, 0, 0)
+# the state counters of a step that advanced no per-slot state
+_NO_STATE = (0, 0, 0)
 
 faults.declare_point(
     "serving.step", "top of ServingEngine.step(), before the deadline "
@@ -214,8 +216,8 @@ class _Launch:
     with the ``_SeqState`` it was planned for, so a row whose sequence
     ended before it landed is recognised and thrown away."""
 
-    __slots__ = ("rows", "nxt", "fin", "counts", "t0", "t_prog", "fresh",
-                 "clips", "discarded")
+    __slots__ = ("rows", "nxt", "fin", "counts", "state_counts", "t0",
+                 "t_prog", "fresh", "clips", "discarded")
 
 
 class ServingEngine:
@@ -362,10 +364,37 @@ class ServingEngine:
         self.pages_per_seq = -(-self.max_model_len // self.page_size)
         if num_pages is None:
             num_pages = self.max_batch_slots * self.pages_per_seq + 1
+        # a trunk whose layers are not all paged K/V says what each keeps
+        # (kv_cache.py "Layer KINDS"); the state layers hold one row per
+        # slot and the spare that padding rows write
+        layout = getattr(model, "_cache_layout", None)
         self.pool = PagedKVCachePool(n_layers, num_pages, self.page_size,
                                      n_kv, head_dim, dtype=kv_dtype,
                                      engine_id=self.engine_id,
-                                     model_id=self.model_id)
+                                     model_id=self.model_id,
+                                     layer_kinds=(None if layout is None
+                                                  else layout()),
+                                     state_slots=self.max_batch_slots + 1)
+        if self.pool.has_state:
+            # a sequence's per-slot state follows it only by recompute
+            # from position 0; what would share or roll back its cache by
+            # pages alone is refused here, not found out mid-stream
+            for on, what in ((self.drafter is not None,
+                              "speculative decoding (spec_k > 0 or a "
+                              "drafter): rejected drafts roll back by "
+                              "truncating pages, and the state they "
+                              "advanced cannot be rolled back"),
+                             (prefix_cache, "prefix_cache=True: an adopted "
+                              "prefix brings its pages but not the state "
+                              "at its end (no snapshots at page "
+                              "boundaries yet)"),
+                             (host_offload, "host_offload=True: offload "
+                              "moves pages and would leave the state")):
+                if on:
+                    raise ValueError(
+                        f"{type(model).__name__} keeps per-slot recurrent "
+                        f"state beside its K/V pages; {what}. Pass "
+                        f"spec_k=0, prefix_cache=False, host_offload=False")
         # rows the paged kernel puts in one query block at this pool's
         # shapes: the walk counter (_pack) counts what the kernel walks
         self._query_rows = query_block_rows(
@@ -1125,7 +1154,9 @@ class ServingEngine:
             record_counter("serving.page_utilization",
                            self.stats["page_utilization"])
             counts = ((_NO_GRID if landed is None else landed.counts)
-                      + (tokens_this_step, ahead, discarded))
+                      + (tokens_this_step, ahead, discarded)
+                      + (_NO_STATE if landed is None
+                         else landed.state_counts))
         finally:
             # a step that raised still closes its spans (without counts):
             # an open one would adopt every later span as its child
@@ -1563,8 +1594,12 @@ class ServingEngine:
         - ``prev`` [B*S] — the samples of the previous call (its ``nxt``,
           the device array itself: neither transferred nor donated), or
           zeros where nothing is in flight,
-        - ``*rest`` — the stacked adapter (A, B) arrays per site, then
-          the paged KV pools, consumed and returned functionally.
+        - ``*rest`` — where the pool keeps per-slot state, first
+          ``tok_slot`` [T]: each row's OWNER slot (the spare slot B for
+          padding rows), which the state layers index their state by; then
+          the stacked adapter (A, B) arrays per site, then the pool's
+          arrays (paged K/V, and per-slot state where a layer keeps it),
+          consumed and returned functionally.
 
         Adapters and grammars are ALWAYS in the program — disabled is a
         VALUE (slot 0's zero weights add exactly 0.0; row 0's all-True
@@ -1590,15 +1625,20 @@ class ServingEngine:
         trunk, model, pool = self.trunk, self.model, self.pool
         site_names = [s for s, _, _ in self.adapters.sites]
         n_adp = 2 * len(site_names)
+        # a pool with per-slot state adds one grid after `prev`: tok_slot
+        n_own = int(pool.has_state)
 
         def step_fn(tok, tok_pos, tok_bt, tok_adp, sample_rows, sample_pos,
                     temps, seeds, fsm_state, tok_src, grammar_table, prev,
                     *rest):
             # the pool's operands, regrouped by the pool into one opaque
             # cache per layer: which arrays a layer keeps (int8 pages add
-            # their scales) is the pool's and ops/paged_cache.py's to know,
-            # and changes WHICH arrays ride as data, never the program count
+            # their scales, a state layer keeps its state) is the pool's
+            # and ops/paged_cache.py's to know, and changes WHICH arrays
+            # ride as data, never the program count
+            own, rest = rest[:n_own], rest[n_own:]
             adp_flat, caches = rest[:n_adp], pool.layer_caches(rest[n_adp:])
+            slots = {"slots": own[0]} if n_own else {}
             with no_grad():
                 # a row whose token the step in flight samples reads it
                 # from that step's output here, never through the host
@@ -1624,7 +1664,8 @@ class ServingEngine:
                         name="gather_adapter_b")
                     adapters[site] = (ga, gb)
                 hidden, ncs = trunk.forward_paged(tok, tok_pos, tok_bt,
-                                                  caches, adapters=adapters)
+                                                  caches, adapters=adapters,
+                                                  **slots)
                 # per-slot sample rows gathered BEFORE the vocab matmul:
                 # the grid carries up to token-budget rows but only
                 # max_batch_slots * (spec_k+1) of them sample
@@ -1712,14 +1753,17 @@ class ServingEngine:
             self.page_size, self.pages_per_seq, self._spec_rows,
             self.adapters.capacity, self.adapters.rank,
             self._grammar_cap, str(jnp.dtype(self.pool.dtype))))
+        if n_own:   # which layers keep state, and its shapes
+            extra += repr(pool.layer_kinds)
         # the step CONSUMES the pool: its operands (`pool.step_flat()`, the
         # trailing positional arguments, after the ten grids, the grammar
-        # table, the previous samples and the adapter stacks) are given up
+        # table, the previous samples, `tok_slot` where the pool keeps
+        # state and the adapter stacks) are given up
         # to the program, which writes this step's rows into the same buffers and hands them back in the same order —
         # `set_step_flat` swaps them in, and what the pool held before the
         # call is deleted. jax pairs a donated input with the first output
         # of equal aval in order, which that order (k0, v0, k1, ...) keeps.
-        first_pool = 12 + n_adp
+        first_pool = 12 + n_own + n_adp
         return jit.StaticFunction(
             step_fn, observe=[self.model], warmup=False, dy2static=False,
             cache_dir=self._compile_cache_dir, cache_key_extra=extra,
@@ -1961,6 +2005,8 @@ class ServingEngine:
         # entries stay 0 = the all-True identity row (mask is a no-op)
         fsm_state = np.zeros((B, S), np.int32)
         tok_src = np.full(T, -1, np.int32)
+        # owner slot per row; padding rows write the spare slot's state
+        tok_slot = np.full(T, B, np.int32)
         cur = 0
         kv_walked = kv_held = n_decode = n_draft = 0
         for i, st, toks, poss, is_chunk, d, src in rows:
@@ -1976,6 +2022,7 @@ class ServingEngine:
             table = self.pool.block_table(st.req.req_id)
             tok_bt[cur:cur + c, :len(table)] = table
             tok_adp[cur:cur + c] = st.adp_slot
+            tok_slot[cur:cur + c] = i
             if is_chunk:
                 sample_rows[i, 0] = cur + c - 1
                 sample_pos[i, 0] = int(poss[-1])
@@ -2013,6 +2060,13 @@ class ServingEngine:
                          n_draft, len(rows), kv_walked, kv_held)
         grids = [tok, tok_pos, tok_bt, tok_adp, sample_rows, sample_pos,
                  temps, seeds, fsm_state, tok_src]
+        # rows that advance a per-slot state (once a step, not per layer),
+        # those of prompt chunks, and the slots' runs of rows
+        launch.state_counts = _NO_STATE
+        if self.pool.has_state:
+            grids.append(tok_slot)
+            launch.state_counts = (total, total - n_decode - n_draft,
+                                   len(rows))
         prev = self._flight[-1].nxt if self._flight else None
         return launch, grids, prev
 
@@ -2036,12 +2090,12 @@ class ServingEngine:
                         self.max_batch_slots * self._spec_rows, np.int32)))
                 prev = self._no_prev
             launch.t_prog = time.perf_counter()
-            # the ten grids go up in one transfer call, not ten
+            # the grids go up in one transfer call, not one each
             sent = [Tensor(a) for a in jax.device_put(grids)]
         finally:
             trace.end(sp)
         res = self._step_prog(
-            *sent, self._grammar_device, prev,
+            *sent[:10], self._grammar_device, prev, *sent[10:],
             *self.adapters.arrays(), *self.pool.step_flat())
         launch.nxt, launch.fin, flat = res[0], res[1], res[2:]
         self.pool.set_step_flat(flat)

@@ -39,6 +39,18 @@ must be the array's own. The kv-head axis is second, so a later multi-chip servi
 can still shard ``n_kv_heads`` over 'mp' without touching the allocator
 or block tables (page ids are replicated host metadata).
 
+Layer KINDS (docs/SERVING.md "Adding a trunk"): a pool describes each of
+the trunk's layers by what it keeps. ``"kv"`` is paged K/V, everything
+above; a :class:`SlotState` is per-slot state beside the pages — a gated
+delta-rule state and a convolution tail for each batch slot and one spare
+(the slot the step's padding rows write), which a sequence resets at its
+position 0 and no page operation touches. Only the ``"kv"`` layers take
+pages, so ``num_layers`` counts those and a page costs their bytes alone.
+A pool that holds state refuses what would have to move a sequence's cache
+with it and cannot yet (prefix adoption, fork, ``truncate``, offload and
+prefetch): preemption and migration recompute from the tokens, and
+position 0 resets the state.
+
 Page TIERS (docs/SERVING.md "KV page tiers & quantization"):
 
 - **int8 pages** — ``PagedKVCachePool(dtype="int8")`` stores pages as
@@ -70,6 +82,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import faults, metrics
+from ..ops.paged_cache import SlotState
 from ..quantization.observers import (KV_SCALE_FLOOR, quantize_kv)
 from ..tensor import Tensor
 
@@ -78,7 +91,7 @@ faults.declare_point(
     "PagedKVCachePool._take_page, before a page leaves the free list — "
     "arm ResourceExhausted here to drill pool-exhaustion handling")
 
-__all__ = ["PagedKVCachePool", "PrefixCache", "HostPageStore",
+__all__ = ["PagedKVCachePool", "PrefixCache", "HostPageStore", "SlotState",
            "page_bytes", "pages_for_hbm_budget", "normalize_kv_dtype"]
 
 _KV_DTYPE_ALIASES = {
@@ -184,9 +197,25 @@ class PagedKVCachePool:
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  n_kv_heads: int, head_dim: int, dtype=jnp.float32,
-                 engine_id: str = "", model_id: str = ""):
+                 engine_id: str = "", model_id: str = "",
+                 layer_kinds: Optional[Sequence] = None,
+                 state_slots: int = 0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
+        # the trunk's layers by kind ("kv", or a SlotState); num_layers
+        # counts the "kv" ones, the layers that take pages
+        kinds = (["kv"] * int(num_layers) if layer_kinds is None
+                 else list(layer_kinds))
+        if sum(1 for k in kinds if k == "kv") != int(num_layers):
+            raise ValueError(
+                f"layer_kinds holds {sum(1 for k in kinds if k == 'kv')} "
+                f"paged layers, num_layers says {num_layers}")
+        self.layer_kinds = kinds
+        # trunk layer -> its index among the paged layers or the state ones
+        self._index, seen = [], {True: 0, False: 0}
+        for k in kinds:
+            self._index.append(seen[k == "kv"])
+            seen[k == "kv"] += 1
         # identity labels for the pool gauges: an engine passes its own
         # {engine_id, model_id} so N pools behind a Router stay N series
         # instead of last-writer-wins; a standalone pool reports under the
@@ -202,6 +231,8 @@ class PagedKVCachePool:
         # "Page TIERS"); every page-granular allocator path below mirrors
         # its byte operation onto the scale arrays
         self.quantized = jnp.dtype(self.dtype) == jnp.int8
+        # arrays each trunk layer contributes to the step's operands
+        self._strides = [self.step_stride if k == "kv" else 2 for k in kinds]
         shape = (self.num_pages, self.n_kv_heads, self.page_size,
                  self.head_dim)
         self.k_pools: List[Tensor] = [
@@ -221,6 +252,20 @@ class PagedKVCachePool:
         else:
             self.k_scales = None
             self.v_scales = None
+        # per-slot state of the SlotState layers: (state, tail) each, one
+        # row a batch slot and one for the spare
+        self.state_slots = int(state_slots)
+        self.states: List[tuple] = []
+        for kind in kinds:
+            if kind != "kv":
+                if self.state_slots < 2:
+                    raise ValueError("a pool with per-slot state needs "
+                                     "state_slots >= 2 (slots + the spare)")
+                st, tl = kind.shapes(self.state_slots)
+                self.states.append(
+                    (Tensor(jnp.zeros(st, jnp.float32), stop_gradient=True),
+                     Tensor(jnp.zeros(tl, kind.tail_dtype),
+                            stop_gradient=True)))
         # host offload tier: parked sequences' page bytes live here while
         # their HBM pages serve other tenants; _host_idx maps seq_id →
         # set of offloaded page indices (their table entries hold the
@@ -299,6 +344,28 @@ class PagedKVCachePool:
         self._m_tier_host.set(len(self.host_store))
 
     # ---------------------------------------------------------- accounting
+    @property
+    def has_state(self) -> bool:
+        """Whether some layer keeps per-slot state beside the pages."""
+        return bool(self.states)
+
+    def _refuse_with_state(self, op: str) -> None:
+        """What moves or shares a sequence's cache by its pages would leave
+        its per-slot state behind: a pool that holds state refuses it
+        (module docstring, "Layer KINDS")."""
+        if self.states:
+            raise ValueError(
+                f"{op}: this pool keeps per-slot recurrent state beside its "
+                f"pages, and {op} moves or shares pages alone — the state "
+                f"would not follow (it would need a snapshot at the page "
+                f"boundary, or a rollback); not supported for such a trunk")
+
+    @property
+    def state_bytes(self) -> int:
+        """Device bytes of the per-slot state of every SlotState layer."""
+        return sum(k.bytes_per_slot() * self.state_slots
+                   for k in self.layer_kinds if k != "kv")
+
     @property
     def usable_pages(self) -> int:
         return self.num_pages - 1
@@ -418,6 +485,8 @@ class PagedKVCachePool:
         BEFORE any fresh page is taken, so a mid-allocate eviction can
         never reclaim the very pages this sequence is adopting. Rollback
         (:meth:`free`) drops shared and fresh pages uniformly."""
+        if prefix_pages:
+            self._refuse_with_state("prefix adoption")
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id!r} already allocated")
         if prefix_tokens and int(prefix_tokens) % self.page_size:
@@ -504,6 +573,7 @@ class PagedKVCachePool:
         untouched — the rejected range was already made exclusively
         owned by the :meth:`extend_write` that reserved it, and a CoW'd
         page stays correctly owned for the retry."""
+        self._refuse_with_state("truncate")
         total = int(total_tokens)
         cur = self._lens[seq_id]
         if total < 0 or total > cur:
@@ -618,6 +688,7 @@ class PagedKVCachePool:
         that never diverges (parallel scoring, n-best over a shared
         prompt) costs zero KV bytes. The substrate for prefix caching /
         parallel sampling."""
+        self._refuse_with_state("fork")
         if dst_id in self._tables:
             raise ValueError(f"sequence {dst_id!r} already allocated")
         self._assert_resident(src_id, "fork")
@@ -702,6 +773,7 @@ class PagedKVCachePool:
         eviction order "offload before prefix-evict" follows because the
         engine parks victims BEFORE any allocation walks
         :meth:`_take_page`'s cache-eviction loop."""
+        self._refuse_with_state("offload")
         table = self._tables[seq_id]
         n = int(self._lens[seq_id])
         off = self._host_idx.setdefault(seq_id, set())
@@ -754,6 +826,7 @@ class PagedKVCachePool:
         the free list and the sequence stays parked. The engine calls
         this at UNPARK, before the slot re-enters the step grid — the
         compiled step itself never waits on a host→HBM copy."""
+        self._refuse_with_state("prefetch")
         off = self._host_idx.get(seq_id)
         if not off:
             # nothing on the host tier; still restore a journaled tail
@@ -928,6 +1001,7 @@ class PagedKVCachePool:
 
     # ---------------------------------------------------------- cache hooks
     def attach_prefix_cache(self, cache: "PrefixCache") -> None:
+        self._refuse_with_state("a prefix cache")
         if self.prefix_cache is not None and self.prefix_cache is not cache:
             raise ValueError("pool already has a prefix cache attached")
         self.prefix_cache = cache
@@ -954,46 +1028,58 @@ class PagedKVCachePool:
 
     @property
     def step_stride(self) -> int:
-        """Device arrays one layer contributes to the compiled step's
+        """Device arrays one paged layer contributes to the compiled step's
         flat cache operands: (k, v) or (k, v, k_scale, v_scale)."""
         return 4 if self.quantized else 2
 
     def step_arrays(self, li: int):
-        """Layer ``li``'s cache in step-operand order: what the trunk's
-        layer ``li`` is handed as its ``cache`` and what
-        ``ops/paged_cache.paged_attend`` opens."""
+        """Trunk layer ``li``'s cache in step-operand order: what the
+        trunk's layer ``li`` is handed as its ``cache`` and what
+        ``ops/paged_cache`` opens — ``paged_attend`` for a paged layer,
+        ``state_attend`` for one with per-slot state."""
+        pi = self._index[li]
+        if self.layer_kinds[li] != "kv":
+            return self.states[pi]
         if self.quantized:
-            return (self.k_pools[li], self.v_pools[li],
-                    self.k_scales[li], self.v_scales[li])
-        return (self.k_pools[li], self.v_pools[li])
+            return (self.k_pools[pi], self.v_pools[pi],
+                    self.k_scales[pi], self.v_scales[pi])
+        return (self.k_pools[pi], self.v_pools[pi])
 
     def step_flat(self, caches=None) -> list:
         """Per-layer caches (the pool's own :meth:`step_arrays` by default;
         inside the step program, the updated ones the trunk returned),
         concatenated: the operands the compiled step takes, gives up (they
         are donated) and returns updated, in this order (``k0, v0[, ks0,
-        vs0], k1, ...``)."""
+        vs0], k1, ...``; a state layer's ``state, tail`` in its place)."""
         if caches is None:
-            caches = map(self.step_arrays, range(self.num_layers))
+            caches = map(self.step_arrays, range(len(self.layer_kinds)))
         return [t for c in caches for t in c]
 
     def layer_caches(self, flat) -> list:
         """Regroup a :meth:`step_flat`-ordered sequence (arrays, or the
         tracers the step program sees in their place) into one ``cache``
         per layer: the inverse of the concatenation."""
-        s = self.step_stride
-        return [tuple(flat[s * li: s * (li + 1)])
-                for li in range(self.num_layers)]
+        out, at = [], 0
+        for n in self._strides:
+            out.append(tuple(flat[at:at + n]))
+            at += n
+        return out
 
     def set_step_flat(self, flat) -> None:
         """The way back: accept the compiled step's flat cache outputs
-        (:meth:`step_flat`'s order) and swap every array (and scale
-        array, when quantized) back in."""
+        (:meth:`step_flat`'s order) and swap every array (scale arrays,
+        when quantized; state arrays, for state layers) back in."""
         caches = self.layer_caches(flat)
+        paged = [c for c, k in zip(caches, self.layer_kinds) if k == "kv"]
         self.set_arrays(
-            [c[0] for c in caches], [c[1] for c in caches],
-            k_scales=[c[2] for c in caches] if self.quantized else None,
-            v_scales=[c[3] for c in caches] if self.quantized else None)
+            [c[0] for c in paged], [c[1] for c in paged],
+            k_scales=[c[2] for c in paged] if self.quantized else None,
+            v_scales=[c[3] for c in paged] if self.quantized else None)
+        if self.states:
+            self.states = [
+                tuple(t if isinstance(t, Tensor)
+                      else Tensor(t, stop_gradient=True) for t in c)
+                for c, k in zip(caches, self.layer_kinds) if k != "kv"]
 
     def write_prompt_kv(self, seq_id, layer_kv, start: int = 0) -> None:
         """Prefill's KV write hook: scatter a dense prompt cache into this

@@ -250,7 +250,8 @@ def test_the_seam_has_one_caller_per_trunk_and_the_engine_no_stride():
         assert not any(isinstance(n, ast.Name) and n.id == "paged_attend"
                        for top in outside for n in ast.walk(top)), path
     assert sorted(callers) == [("GPTAttention", "forward_paged"),
-                               ("LlamaAttention", "forward_paged")]
+                               ("LlamaAttention", "forward_paged"),
+                               ("OlmoHybridAttention", "forward_paged")]
     with open(os.path.join(REPO, "paddle_tpu", "serving", "engine.py")) as f:
         assert "step_stride" not in f.read()
 

@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import gated_delta as gd
 from paddle_tpu.ops.pallas import paged_attention as pa
 
 
@@ -161,3 +162,39 @@ def test_smem_limit_is_where_the_compiler_puts_it(one_chip):
             _paged_fn(pa.ragged_paged_attention, False),
             *_paged_shapes(one_chip, fits + 1, 16, 16, 128, 16, pages,
                            False))
+
+
+@pytest.mark.parametrize("T", [32, 256])
+def test_paged_attention_olmo_hybrid_compiles_for_v5e(one_chip, T):
+    """Olmo-Hybrid-7B's full layers: MHA 30 over 30 kv heads of 128, at the
+    serve-chat32 cell's slot grid and its widest bucket (token_budget)."""
+    text = _compiled_text(
+        _paged_fn(pa.ragged_paged_attention, False),
+        *_paged_shapes(one_chip, T, 30, 30, 128, 16, 128, False))
+    _assert_one_paged_kernel(text)
+
+
+@pytest.mark.parametrize("T", [32, 256])
+def test_gated_delta_compiles_for_v5e(one_chip, T):
+    """Olmo-Hybrid-7B's linear layers: 30 heads, d_k 96, d_v 192, 32 slots
+    and the spare; ONE kernel under the name the trace reduction keys on
+    (benchmarks/layer_metrics/gated_delta_roofline.py), and the donated
+    state updated where it lies: no state-sized copy."""
+    H, dk, dv, slots = 30, 96, 192, 33
+    g = gd.state_head_group(H, dv)
+    assert g == 2     # 384 lanes: whole vregs, no padding
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    state = (slots, H // g, dk, g * dv)
+    fn = jax.jit(lambda st, q, k, v, a, b, sl, ps: gd.gated_delta(
+        st, q, k, v, a, b, sl, ps, use_kernel=True), donate_argnums=(0,))
+    text = fn.lower(s(state), s((T, H, dk)), s((T, H, dk)), s((T, H, dv)),
+                    s((T, H)), s((T, H)), s((T,), jnp.int32),
+                    s((T,), jnp.int32)).compile().as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 1 and "gated_delta" in calls[0], calls
+    shape = "f32[" + ",".join(map(str, state)) + "]"
+    assert not [line for line in text.split("\n")
+                if " copy(" in line and shape in line.split(" copy(")[0]]
