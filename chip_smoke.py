@@ -19,7 +19,10 @@ from a seed) through the entry points a user calls:
   page leaks, and every served token is a dense-forward argmax to within a
   stated logit tolerance (exact equality is unsound under bf16 ties).
   Before it, *paged_kernel* holds the ragged kernel to
-  ``ref_paged_attention`` on seeded inputs at the same widths.
+  ``ref_paged_attention`` on seeded inputs at the same widths, and
+  *gated_delta* holds the delta-rule kernel to ``gated_delta_ref`` at
+  Olmo-Hybrid-7B's widths (decode rows, a prompt chunk that advances in
+  blocks on the MXU, padding rows on the spare slot).
 - ``--chips 4``: ONLY the dp2×mp2 train step (``fleet.init``) and the
   one-device run of the same batch it is compared with.
 
@@ -56,6 +59,11 @@ DENSE_RTOL = 2.0 ** -5
 PAGED_KERNEL_ATOL = 2.0 ** -5
 # per-step |loss(dp2×mp2) − loss(one device)| allowed, bf16 params
 SHARDED_LOSS_ATOL = 0.05
+# |Pallas gated delta − gated_delta_ref| allowed, float32 throughout, on
+# outputs and states of order 1: what float32 products leave (the block
+# path's MXU products at HIGHEST against the scan's elementwise sums); one
+# bf16 pass of a product would miss by about 2^-9 of an O(1) value
+GATED_DELTA_ATOL = 1e-4
 
 
 def _say(msg):
@@ -250,6 +258,65 @@ def paged_kernel_phase(nh: int, nkv: int, hd: int, page_size: int,
     _say(f"paged_kernel[{dev.device_kind}]: nh{nh} nkv{nkv} hd{hd} "
          f"page{page_size} pages{pages_per_seq} rows{rows}: "
          f"max|kernel - ref|={worst} (tol {PAGED_KERNEL_ATOL})")
+
+
+def gated_delta_phase(heads: int, key_dim: int, value_dim: int,
+                      slots: int, chunk: int, dev):
+    """The delta-rule kernel against ``gated_delta_ref`` on this device,
+    float32, seeded inputs: a decode row on each of ``slots - 2`` slots
+    mid-sequence, then a ``chunk``-row prompt chunk carried in from an
+    earlier step (it advances in blocks of the chunkwise form where it is
+    at least ``state_block`` rows long), then padding rows on the spare
+    slot up to the next power of two. Interpret mode computes every
+    product in float32 and cannot see a pass of the MXU below it; this
+    phase can."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import gated_delta as gd
+
+    rng = np.random.default_rng(3)
+    decode = slots - 2
+    rows = decode + chunk
+    pad = (1 << (rows - 1).bit_length()) - rows
+    sl = np.concatenate([np.arange(decode), [decode] * chunk,
+                         [slots - 1] * pad]).astype(np.int32)
+    pos = np.concatenate([np.full(decode, 100), np.arange(40, 40 + chunk),
+                          np.zeros(pad)]).astype(np.int32)
+    T = sl.size
+
+    def f(*shape):
+        return rng.standard_normal(shape).astype(np.float32)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    g = gd.state_head_group(heads, value_dim)
+    state = jnp.asarray(0.1 * f(slots, heads // g, key_dim, g * value_dim))
+    args = [jnp.asarray(x) for x in (
+        unit(f(T, heads, key_dim)), unit(f(T, heads, key_dim)),
+        f(T, heads, value_dim), -0.5 * np.abs(f(T, heads)),
+        2 / (1 + np.exp(-f(T, heads))))] + [sl, pos]
+    got = jax.jit(lambda st, *a: gd.gated_delta(st, *a, use_kernel=True))(
+        state, *args)
+    want = gd.gated_delta_ref(state, *args)
+    o, s, o_ref, s_ref = (np.asarray(x) for x in (*got, *want))
+    live = sl != slots - 1
+    _check(np.isfinite(o).all() and np.isfinite(s).all(),
+           "gated delta: non-finite output")
+    worst = {"o": float(np.abs(o[live] - o_ref[live]).max()),
+             "state": float(np.abs(s[:-1] - s_ref[:-1]).max())}
+    _check(max(worst.values()) <= GATED_DELTA_ATOL,
+           f"gated delta differs from gated_delta_ref by {worst} > "
+           f"{GATED_DELTA_ATOL}")
+    _check(not o[~live].any() and (s[-1] == np.asarray(state)[-1]).all(),
+           "gated delta: the spare slot's rows advanced something")
+    block = gd.state_block(heads, value_dim)
+    _say(f"gated_delta[{dev.device_kind}]: H{heads} dk{key_dim} "
+         f"dv{value_dim} slots{slots} rows {decode} decode + {chunk} chunk "
+         f"(block {block}: {gd.block_rows(chunk, block)} rows in blocks) + "
+         f"{pad} padding: max|kernel - ref|={worst} "
+         f"(tol {GATED_DELTA_ATOL})")
 
 
 def serve_phase(model, *, kv_pool_bytes: int, page_size: int,
@@ -496,6 +563,9 @@ def main(argv=None):
         paged_kernel_phase(cfg.num_heads, cfg.num_heads,
                            cfg.hidden_size // cfg.num_heads, page_size=16,
                            pages_per_seq=2048 // 16, dev=dev)
+        # Olmo-Hybrid-7B's linear layers: 30 heads of 96 x 192, 32 slots
+        # and the spare, the serve-chat32 cell's longest chunk
+        gated_delta_phase(30, 96, 192, slots=33, chunk=225, dev=dev)
         serve_phase(model, kv_pool_bytes=3 << 30, page_size=16,
                     max_model_len=2048, max_batch_slots=4, token_budget=64,
                     prompt_lens=SERVE_PROMPTS, new_tokens=SERVE_NEW, dev=dev)

@@ -183,8 +183,9 @@ def state_attend(cache, q_rows, k_rows, v_rows, log_decay, beta, conv_w,
     row's owner (the spare slot for padding rows) and ``positions`` ``[T]``
     its position, 0 starting a sequence afresh. Each of q, k and v passes
     the convolution (:func:`conv_rows`) and SiLU; q and k are L2-normalised
-    per head, q scaled by ``d_k ** -0.5``; then the state advances row by
-    row (ops/pallas/gated_delta.py). Returns (o ``[T, H, d_v]`` float32,
+    per head, q scaled by ``d_k ** -0.5``; then the state advances over
+    each slot's rows, a long run a block of rows at a time
+    (ops/pallas/gated_delta.py). Returns (o ``[T, H, d_v]`` float32,
     cache) with ``cache`` updated, in the pool's order."""
     def fn(q, k, v, g, b, wq, wk, wv, sl, pos, state, tail):
         T, H, dk = q.shape
@@ -199,7 +200,7 @@ def state_attend(cache, q_rows, k_rows, v_rows, log_decay, beta, conv_w,
         qc = _l2norm(y[:, :nq].reshape(T, H, dk)) * (dk ** -0.5)
         kc = _l2norm(y[:, nq:2 * nq].reshape(T, H, dk))
         vc = y[:, 2 * nq:].reshape(T, H, -1)
-        o, state = gated_delta(state, qc, kc, vc, jnp.exp(g), b, sl, pos)
+        o, state = gated_delta(state, qc, kc, vc, g, b, sl, pos)
         return o, state, tail
 
     o, *cache = apply_op(

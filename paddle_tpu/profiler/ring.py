@@ -90,11 +90,13 @@ COUNTERS: Dict[str, Tuple[str, ...]] = {
     # because their sequence had ended before they landed; state_rows:
     # rows that advanced a per-slot recurrent state (0 for a pool of pages
     # alone), state_chunk_rows: those of prompt chunks, state_segments: the
-    # slots' runs of rows that carried a state through the step
+    # slots' runs of rows that carried a state through the step,
+    # state_block_rows: the rows of those runs that the delta-rule kernel
+    # advanced a block at a time (ops/pallas/gated_delta.block_rows)
     "step": ("rows", "bucket", "decode_rows", "chunk_rows", "draft_rows",
              "seqs", "kv_walked", "kv_held", "landed", "ahead",
              "discarded", "state_rows", "state_chunk_rows",
-             "state_segments"),
+             "state_segments", "state_block_rows"),
     # fresh: 1 where the build paid a trace and an XLA compile, 0 where a
     # cache held the program (paddle_tpu_jit_compiles_total's source)
     "step.build": ("fresh",),

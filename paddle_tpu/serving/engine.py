@@ -77,6 +77,7 @@ import numpy as np
 from .. import faults, jit, metrics
 from ..autograd.engine import no_grad
 from ..ops._apply import apply_op, ensure_tensor
+from ..ops.pallas.gated_delta import block_rows, state_block
 from ..ops.pallas.paged_attention import query_block_rows, walked_keys
 from ..tensor import Tensor
 from . import tracing
@@ -94,7 +95,7 @@ _engine_counter = itertools.count()
 # up to ``kv_held``)
 _NO_GRID = (0, 0, 0, 0, 0, 0, 0, 0)
 # the state counters of a step that advanced no per-slot state
-_NO_STATE = (0, 0, 0)
+_NO_STATE = (0, 0, 0, 0)
 
 faults.declare_point(
     "serving.step", "top of ServingEngine.step(), before the deadline "
@@ -401,6 +402,12 @@ class ServingEngine:
             self.pages_per_seq, n_kv, self.page_size, head_dim,
             jnp.dtype(self.pool.dtype).itemsize,
             int(model.config.num_heads))
+        # rows of a run the delta-rule kernel advances a block at a time
+        # at this pool's state shapes: the block counter (_pack) counts
+        # the rows that take that form
+        kinds = [k for k in self.pool.layer_kinds if k != "kv"]
+        self._state_block = (state_block(kinds[0].heads, kinds[0].value_dim)
+                             if kinds else None)
         # host offload tier (docs/SERVING.md "KV page tiers &
         # quantization"): when armed, admission pressure parks cold
         # lower-urgency slots — their pages swap to the pool's
@@ -2008,7 +2015,7 @@ class ServingEngine:
         # owner slot per row; padding rows write the spare slot's state
         tok_slot = np.full(T, B, np.int32)
         cur = 0
-        kv_walked = kv_held = n_decode = n_draft = 0
+        kv_walked = kv_held = n_decode = n_draft = n_block = 0
         for i, st, toks, poss, is_chunk, d, src in rows:
             c = toks.size
             # the keys the kernel walks: a slot's c consecutive rows form
@@ -2017,6 +2024,7 @@ class ServingEngine:
             first, last = int(poss[0]), int(poss[-1])
             kv_walked += walked_keys(first + 1, c, self._query_rows)
             kv_held += last + 1
+            n_block += block_rows(c, self._state_block)
             tok[cur:cur + c, 0] = toks
             tok_pos[cur:cur + c] = poss
             table = self.pool.block_table(st.req.req_id)
@@ -2061,12 +2069,13 @@ class ServingEngine:
         grids = [tok, tok_pos, tok_bt, tok_adp, sample_rows, sample_pos,
                  temps, seeds, fsm_state, tok_src]
         # rows that advance a per-slot state (once a step, not per layer),
-        # those of prompt chunks, and the slots' runs of rows
+        # those of prompt chunks, the slots' runs of rows, and the rows
+        # the kernel advances a block at a time
         launch.state_counts = _NO_STATE
         if self.pool.has_state:
             grids.append(tok_slot)
             launch.state_counts = (total, total - n_decode - n_draft,
-                                   len(rows))
+                                   len(rows), n_block)
         prev = self._flight[-1].nxt if self._flight else None
         return launch, grids, prev
 

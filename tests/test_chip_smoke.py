@@ -98,6 +98,26 @@ def test_paged_kernel_phase_catches_a_wrong_kernel(smoke, interpret,
                                  dev=jax.devices()[0])
 
 
+def test_gated_delta_phase_tiny(smoke, interpret):
+    """4 heads of d_v 32: a 32-row block, so a 70-row chunk advances in
+    blocks beside the decode rows and the padding."""
+    smoke.gated_delta_phase(4, 16, 32, slots=6, chunk=70,
+                            dev=jax.devices()[0])
+
+
+def test_gated_delta_phase_catches_a_wrong_kernel(smoke, interpret,
+                                                  monkeypatch):
+    from paddle_tpu.ops.pallas import gated_delta as gd
+
+    real = gd.gated_delta_ref
+    monkeypatch.setattr(gd, "gated_delta_ref",
+                        lambda *a, **k: tuple(x * 1.001 for x in
+                                              real(*a, **k)))
+    with pytest.raises(AssertionError, match="differs from gated_delta_ref"):
+        smoke.gated_delta_phase(4, 16, 32, slots=6, chunk=70,
+                                dev=jax.devices()[0])
+
+
 def test_sharded_train_phase_tiny(smoke):
     """The --chips 4 path on the virtual CPU devices (dp4 x mp2 uses all
     eight): losses track the one-device run, parameters are spread."""

@@ -204,6 +204,38 @@ def test_the_step_counts_the_rows_that_advance_a_state(model):
         sum(c["seqs"] for c in counts)
 
 
+def test_the_step_counts_the_rows_the_kernel_advances_in_blocks(monkeypatch):
+    """4 linear heads of d_v 32 fill a 128-lane tile, so the kernel's block
+    is 32 rows: a 40-row prompt in one chunk advances in blocks, a 13-row
+    one and the decode rows do not. The ``step`` counter is the kernel
+    module's rule applied to each slot's run of packed rows."""
+    from paddle_tpu.serving import tracing
+
+    cfg = dict(CFG, linear_num_key_heads=4, linear_num_value_heads=4)
+    m = oh.OlmoHybridForCausalLM(fam.olmo_config(cfg, param_dtype="float32"))
+    fam.load_weights(m, cfg, SEED)
+    block = gd.state_block(4, cfg["linear_value_head_dim"])
+    assert block == 32
+    runs = []
+    pack = ServingEngine._pack
+
+    def packed(self, rows, t0):
+        runs.append([r[2].size for r in rows])
+        return pack(self, rows, t0)
+
+    monkeypatch.setattr(ServingEngine, "_pack", packed)
+    tr = tracing.get_tracer()
+    tr.reset()
+    eng = _engine(m, token_budget=64)
+    long = np.random.RandomState(3).randint(0, 128, (40,))
+    _serve(eng, [long, PROMPTS[0]], 4)
+    counts = [e["counts"] for e in tr.events()
+              if e["name"] == "step" and "counts" in e]
+    want = sum(gd.block_rows(c, block) for step in runs for c in step)
+    assert want == 40
+    assert sum(c["state_block_rows"] for c in counts) == want
+
+
 # ------------------------------------------------------------ the kernel
 def test_the_kernel_matches_the_plain_path_on_ragged_rows(monkeypatch):
     """Interpret mode: a decode singleton mid-sequence, a chunk carried in
@@ -221,15 +253,79 @@ def test_the_kernel_matches_the_plain_path_on_ragged_rows(monkeypatch):
 
     q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True)
             for x in (f(T, H, dk), f(T, H, dk)))
-    v, a = f(T, H, dv), jnp.exp(-jnp.abs(f(T, H)))
+    v, lg = f(T, H, dv), -jnp.abs(f(T, H))
     b = 2 * jax.nn.sigmoid(f(T, H))
     g = gd.state_head_group(H, dv)
     state = f(S, H // g, dk, g * dv)
-    want = gd.gated_delta(state, q, k, v, a, b, slots, pos, use_kernel=False)
-    got = gd.gated_delta(state, q, k, v, a, b, slots, pos, use_kernel=True)
+    want = gd.gated_delta(state, q, k, v, lg, b, slots, pos, use_kernel=False)
+    got = gd.gated_delta(state, q, k, v, lg, b, slots, pos, use_kernel=True)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(got[1][:4], want[1][:4], rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(got[1][3], state[3])
+
+
+# 4 heads of d_v 32 fill one 128-lane tile, so a block is 32 rows
+BLOCK_H, BLOCK_DK, BLOCK_DV, BLOCK_SLOTS = 4, 16, 32, 6
+C = gd.state_block(BLOCK_H, BLOCK_DV)
+
+
+@pytest.mark.parametrize("runs,inputs", [
+    ([(2, 5, C - 1)], "random"),       # one row short: the row path
+    ([(2, 0, C)], "random"),           # one whole block from position 0
+    ([(2, 5, C + 1)], "random"),       # a 1-row window, then a whole one
+    ([(2, 5, 2 * C + 5)], "random"),
+    ([(2, 5, C + 1), (4, 0, 2 * C + 5)], "random"),  # blocks back to back
+    ([(2, 5, 2 * C + 5)], "steep decay"),
+    ([(2, 5, 2 * C + 5)], "beta 2, one key"),
+], ids=["C-1", "C", "C+1", "2C+5", "two runs", "g -30", "beta 2"])
+def test_the_kernel_advances_a_long_run_in_blocks(monkeypatch, runs, inputs):
+    """Interpret mode, against the scan: decode singletons before and after
+    the long runs (which start at row 2, off the 8-row tiling), three
+    padding rows on the spare. "steep decay": head 1's ``g`` is about -30 a
+    row, so exp of a block's cumulative decay underflows and only log-space
+    ratios stay finite. "beta 2": every key of head 0 is one unit vector
+    plus 1e-3 noise and ``beta`` is 1.99, the UT transform's worst case
+    (``(I - beta k k^T)`` flips the key's component every row). Row path
+    and block path are both held to 1e-5; an untouched slot and the
+    spare stay bit-identical."""
+    monkeypatch.setenv("PADDLE_TPU_PALLAS_INTERPRET", "1")
+    H, dk, dv, S = BLOCK_H, BLOCK_DK, BLOCK_DV, BLOCK_SLOTS
+    rng = np.random.default_rng(len(runs) * 100 + runs[-1][2])
+    slots = [0, 1]
+    pos = [7, 9]
+    for slot, p0, n in runs:
+        slots += [slot] * n
+        pos += list(range(p0, p0 + n))
+    slots += [3, 5, 5, 5]
+    pos += [11, 0, 0, 0]
+    slots, pos = np.asarray(slots, np.int32), np.asarray(pos, np.int32)
+    T = slots.size
+
+    def f(*s):
+        return rng.standard_normal(s).astype(np.float32)
+
+    def unit(x):
+        return x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    q, k, v = unit(f(T, H, dk)), unit(f(T, H, dk)), f(T, H, dv)
+    lg = -np.abs(f(T, H)) * 0.5
+    b = 2 / (1 + np.exp(-f(T, H)))
+    if inputs == "steep decay":
+        lg[:, 1] = -30 + f(T)
+    if inputs == "beta 2":
+        k[:, 0] = unit(unit(f(dk)) + 1e-3 * f(T, dk))
+        b[:, 0] = 1.99
+    g = gd.state_head_group(H, dv)
+    state = jnp.asarray(f(S, H // g, dk, g * dv))
+    args = [jnp.asarray(x) for x in (q, k, v, lg, b)] + [slots, pos]
+    want = gd.gated_delta(state, *args, use_kernel=False)
+    got = gd.gated_delta(state, *args, use_kernel=True)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[1][5], state[5])
+    np.testing.assert_array_equal(got[0][-3:], 0.0)
+    if 4 not in slots:
+        np.testing.assert_array_equal(got[1][4], state[4])
 
 
 def test_the_state_layout_round_trips():

@@ -637,7 +637,7 @@ class TestSpans:
             "kv_walked": 22 + 33 + walked_keys(13, 5, eng._query_rows),
             "kv_held": 22 + 33 + 17, "landed": 3, "ahead": 1,
             "discarded": 0, "state_rows": 0, "state_chunk_rows": 0,
-            "state_segments": 0}
+            "state_segments": 0, "state_block_rows": 0}
         assert last["parent"] == 0     # no router, no sweep
         assert walked_keys(13, 5, eng._query_rows) == 17
 
